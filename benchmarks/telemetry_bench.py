@@ -59,14 +59,15 @@ def telemetry_accuracy_speed() -> List[Row]:
 
 
 def kernel_bench() -> List[Row]:
-    """isla_moments Pallas kernel (interpret on CPU) vs jnp reference —
+    """isla_moments Pallas kernel (interpret mode) vs jnp reference —
     derived = max abs rel error vs oracle."""
     from repro.kernels import ops, ref
     rng = np.random.default_rng(0)
     x = jnp.asarray(rng.normal(100, 20, size=(512, 128)), jnp.float32)
     bounds = jnp.asarray([60., 90., 110., 140.], jnp.float32)
-    got = ops.isla_moments(x, bounds, tm=64)
+    got = ops.isla_moments(x, bounds, tm=64, interpret=True)
     want = ref.isla_moments_ref(x, 60., 90., 110., 140.)
     rel = float(jnp.max(jnp.abs(got - want) / (jnp.abs(want) + 1e-9)))
-    t = _time_jit(lambda v: ops.isla_moments(v, bounds, tm=64), x, iters=5)
+    t = _time_jit(lambda v: ops.isla_moments(v, bounds, tm=64,
+                                               interpret=True), x, iters=5)
     return [("kernel/isla_moments_interp_us", t, rel)]

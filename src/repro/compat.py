@@ -1,63 +1,28 @@
-"""Version compatibility for the JAX surface the repo touches.
-
-The repo targets a range of JAX releases: newer ones expose
-``jax.sharding.AxisType`` / ``jax.shard_map`` and accept ``axis_types`` in
-mesh constructors; older ones (e.g. 0.4.x) do not.  Everything that varies is
-funneled through here so the rest of the codebase imports one spelling.
+"""One spelling for the JAX mesh surface the repo touches (jax 0.9).
 
 Exports
-  AxisType            — ``jax.sharding.AxisType`` or None when absent
-  shard_map           — ``jax.shard_map`` or the ``jax.experimental`` one
-  make_mesh           — ``jax.make_mesh`` passing axis_types only if supported
-  make_abstract_mesh  — ``AbstractMesh`` across both constructor signatures
+  AxisType            — ``jax.sharding.AxisType``
+  shard_map           — ``jax.shard_map``
+  make_mesh           — ``jax.make_mesh`` with every axis ``AxisType.Auto``
+  make_abstract_mesh  — device-free ``AbstractMesh``, axes ``Auto``
 """
 from __future__ import annotations
 
-from typing import Sequence, Tuple
+from typing import Sequence
 
 import jax
+from jax.sharding import AbstractMesh, AxisType
 
-try:  # jax >= 0.5: public AxisType enum
-    from jax.sharding import AxisType
-except ImportError:  # older jax.sharding has no AxisType
-    AxisType = None
-
-try:  # jax >= 0.5 promotes shard_map to the top level
-    shard_map = jax.shard_map
-except AttributeError:
-    from jax.experimental.shard_map import shard_map  # noqa: F401
-
-
-def _auto_axis_types(n: int):
-    if AxisType is None:
-        return None
-    return (AxisType.Auto,) * n
+shard_map = jax.shard_map
 
 
 def make_mesh(shape: Sequence[int], axes: Sequence[str]):
-    """``jax.make_mesh`` with Auto axis types when the installed JAX takes
-    them; silently without when it does not (the default is equivalent)."""
-    at = _auto_axis_types(len(axes))
-    if at is not None:
-        try:
-            return jax.make_mesh(tuple(shape), tuple(axes), axis_types=at)
-        except TypeError:  # jax.make_mesh predates axis_types
-            pass
-    return jax.make_mesh(tuple(shape), tuple(axes))
+    """``jax.make_mesh`` over the visible devices, every axis Auto."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_abstract_mesh(shape: Sequence[int], axes: Sequence[str]):
-    """Device-free mesh for sharding-rule checks, across both AbstractMesh
-    constructor generations:
-
-      new:  AbstractMesh(shape_tuple, axis_names, axis_types=(...))
-      old:  AbstractMesh((("data", 16), ("model", 16)))
-    """
-    from jax.sharding import AbstractMesh
-    at = _auto_axis_types(len(axes))
-    if at is not None:
-        try:
-            return AbstractMesh(tuple(shape), tuple(axes), axis_types=at)
-        except TypeError:
-            pass
-    return AbstractMesh(tuple(zip(axes, shape)))
+    """Device-free mesh for sharding-rule checks, every axis Auto."""
+    return AbstractMesh(tuple(shape), tuple(axes),
+                        axis_types=(AxisType.Auto,) * len(axes))

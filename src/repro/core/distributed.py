@@ -144,6 +144,12 @@ def phase2(mom_s: jnp.ndarray, mom_l: jnp.ndarray, sketch0: jnp.ndarray,
     u, v = mom_s[..., 0], mom_l[..., 0]
     q = choose_q(u / jnp.maximum(v, 1.0), params)
     k, c = theorem3_kc(mom_s, mom_l, q)
+    # One sample in each region: dev = 1, so q = 1, and Theorem 3's k is
+    # identically zero — the closed form cancels to (q x + y)/(q + 1) - c.
+    # In float64 the cancellation leaves noise far under the 1e-12 guard;
+    # in fp32 (and with a TPU's approximate division) it does not, so the
+    # zero is set exactly, as the host finds it.
+    k = jnp.where((u == 1.0) & (v == 1.0), 0.0, k)
     d0 = c - sketch0
     t = n_iterations(d0, thr, eta)
     total_shrink = (1.0 - eta ** t) * jnp.abs(d0)
@@ -185,8 +191,10 @@ def phase2(mom_s: jnp.ndarray, mom_l: jnp.ndarray, sketch0: jnp.ndarray,
     else:
         raise ValueError(f"unknown mode {mode}")
 
-    avg = jnp.where(jnp.abs(k) < 1e-12, c, avg)
+    # Fallback precedence as in the host engine: empty region > k ~ 0 >
+    # the faithful balanced case.
     avg = jnp.where(balanced, sketch0, avg)
+    avg = jnp.where(jnp.abs(k) < 1e-12, c, avg)
     avg = jnp.where((u < params.min_region_count) |
                     (v < params.min_region_count), sketch0, avg)
     return avg
@@ -255,14 +263,8 @@ def stage_trace(name: str):
     """Profiler stage marker for the pipelined tick: wraps a stage (h2d
     staging, the fused launch dispatch, readback) in a
     ``jax.profiler.TraceAnnotation`` + ``jax.named_scope`` so device
-    traces show pipeline stage names.  No-ops on runtimes without the
-    profiler hooks."""
-    with contextlib.ExitStack() as es:
-        try:
-            es.enter_context(jax.profiler.TraceAnnotation(name))
-            es.enter_context(jax.named_scope(name))
-        except (AttributeError, TypeError, ValueError):
-            pass
+    traces show pipeline stage names."""
+    with jax.profiler.TraceAnnotation(name), jax.named_scope(name):
         yield
 
 
@@ -475,7 +477,10 @@ def _dense_core(mom_s: jnp.ndarray, mom_l: jnp.ndarray,
     tail).  The 11 weight columns (S/L region moments + plain totals)
     contract against a per-key (group one-hot x predicate) matrix in one
     ``dot_general`` over the quota axis — the MXU-shaped form of Alg. 1,
-    and ~4x faster than the scatter on CPU XLA too.  The delta is added
+    and ~4x faster than the scatter on CPU XLA too.  The contraction runs
+    at ``Precision.HIGHEST``: at default precision a TPU rounds f32
+    operands to bf16 (~3 significant digits), and the v, v^2, v^3 weight
+    columns feed the moment sums directly.  The delta is added
     onto the donated resident moments (plain vector add, not the
     bit-exact carry fold — the float64 bit-parity contract belongs to
     the tagged ``fused_tick``; this is the fp32 serving hot path).
@@ -545,7 +550,9 @@ def _dense_core(mom_s: jnp.ndarray, mom_l: jnp.ndarray,
              else w_for(i) * valid_panes[vslot][..., None]
              for i, vslot in members], axis=2)          # (B, q, 11k)
         blk = jax.lax.dot_general(
-            w_cat, oh, (((1,), (1,)), ((0,), (0,))))    # (B, 11k, G)
+            w_cat, oh, (((1,), (1,)), ((0,), (0,))),
+            precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=dt)                  # (B, 11k, G)
         for j, (i, _) in enumerate(members):
             sub = blk[:, 11 * j:11 * (j + 1), :]
             parts[i] = jnp.transpose(sub, (2, 0, 1)).reshape(g * n_b, 11)
@@ -667,6 +674,17 @@ def _sketch_fold(regs: jnp.ndarray, n_groups_list) -> jnp.ndarray:
         out.append(regs[o:o + g * n_b].reshape(g, n_b, -1).max(axis=1))
         o += g * n_b
     return jnp.concatenate(out) if len(out) > 1 else out[0]
+
+
+def _mesh_fold_max(regs: jnp.ndarray, n_groups_list, ax) -> jnp.ndarray:
+    """The shard's ``_sketch_fold`` rows, max-reduced across the mesh.
+
+    The collective runs on int32: a TPU packs four uint8 rows into each
+    32-bit sublane word, and its all-reduce maxes those whole words — on
+    a v5e a uint8 ``pmax`` kept only the top row of every four correct.
+    """
+    folded = _sketch_fold(regs, n_groups_list).astype(jnp.int32)
+    return jax.lax.pmax(folded, ax).astype(jnp.uint8)
 
 
 @functools.partial(
@@ -834,22 +852,6 @@ def mesh_h2d(mesh, x, spec, dtype=None) -> jnp.ndarray:
                               NamedSharding(mesh, spec))
 
 
-def _mesh_shard_map(f, mesh, in_specs, out_specs):
-    """``compat.shard_map`` across the ``check_rep`` signature change.
-
-    Replication of the psum'd rows output is guaranteed by construction,
-    so the check is disabled where the installed jax still takes the
-    flag (0.4.x) and simply omitted where it does not.
-    """
-    from ..compat import shard_map
-    try:
-        return shard_map(f, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_rep=False)
-    except TypeError:
-        return shard_map(f, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs)
-
-
 @functools.lru_cache(maxsize=64)
 def mesh_tick_fn(mesh, params: IslaParams, mode: str, geometry,
                  n_groups_list, per_cell_bounds: bool):
@@ -885,8 +887,8 @@ def mesh_tick_fn(mesh, params: IslaParams, mode: str, geometry,
             geometry=geometry, n_groups_list=n_groups_list)
         return mom_s, mom_l, totals, ns, partials, jax.lax.psum(rows, ax)
 
-    sharded = _mesh_shard_map(
-        body, mesh,
+    sharded = jax.shard_map(
+        body, mesh=mesh,
         in_specs=(row, row, row, vec, rep, rep, vec, bspec, vec, vec,
                   vec),
         out_specs=(row, row, row, vec, vec, P(None, None)))
@@ -937,8 +939,8 @@ def mesh_tick_dense_fn(mesh, params: IslaParams, mode: str, geometry,
              P(None, None), vec, vec, vec)
     if compacted:
         specs = specs + ((vec, vec),)
-    sharded = _mesh_shard_map(
-        body, mesh,
+    sharded = jax.shard_map(
+        body, mesh=mesh,
         in_specs=specs,
         out_specs=(row, row, row, vec, vec, P(None, None)))
     return jax.jit(sharded, donate_argnums=(0, 1, 2, 3))
@@ -965,8 +967,8 @@ def mesh_solve_fn(mesh, params: IslaParams, mode: str, geometry,
                                float(params.min_region_count))
         return partials, jax.lax.psum(rows, ax)
 
-    sharded = _mesh_shard_map(
-        body, mesh,
+    sharded = jax.shard_map(
+        body, mesh=mesh,
         in_specs=(row, row, row, vec, vec, vec, vec),
         out_specs=(vec, P(None, None)))
     return jax.jit(sharded)
@@ -1007,12 +1009,12 @@ def mesh_tick_sketch_fn(mesh, params: IslaParams, mode: str, geometry,
             geometry=geometry, n_groups_list=n_groups_list)
         j, rho = _sketch_encode(hash_hi, hash_lo)
         regs = regs.at[lseg, j].max(rho, mode="drop")
-        group_regs = jax.lax.pmax(_sketch_fold(regs, n_groups_list), ax)
+        group_regs = _mesh_fold_max(regs, n_groups_list, ax)
         return (mom_s, mom_l, totals, ns, regs, partials,
                 jax.lax.psum(rows, ax), group_regs)
 
-    sharded = _mesh_shard_map(
-        body, mesh,
+    sharded = jax.shard_map(
+        body, mesh=mesh,
         in_specs=(row, row, row, vec, row, rep, rep, rep, rep, vec,
                   bspec, vec, vec, vec),
         out_specs=(row, row, row, vec, row, vec, P(None, None),
@@ -1052,7 +1054,7 @@ def mesh_tick_dense_sketch_fn(mesh, params: IslaParams, mode: str,
             valid_panes, n_groups_list=n_groups_list,
             gid_slots=gid_slots, valid_slots=valid_slots,
             cell_idx=None if active_cells is None else active_cells[0])
-        group_regs = jax.lax.pmax(_sketch_fold(regs, n_groups_list), ax)
+        group_regs = _mesh_fold_max(regs, n_groups_list, ax)
         return (mom_s, mom_l, totals, ns, regs, partials,
                 jax.lax.psum(rows, ax), group_regs)
 
@@ -1061,8 +1063,8 @@ def mesh_tick_dense_sketch_fn(mesh, params: IslaParams, mode: str,
              P(None, None), vec, vec, vec)
     if compacted:
         specs = specs + ((vec, vec),)
-    sharded = _mesh_shard_map(
-        body, mesh,
+    sharded = jax.shard_map(
+        body, mesh=mesh,
         in_specs=specs,
         out_specs=(row, row, row, vec, row, vec, P(None, None),
                    P(None, None)))
@@ -1086,11 +1088,11 @@ def mesh_solve_sketch_fn(mesh, params: IslaParams, mode: str, geometry,
         rows = group_row_stats(mom_s, mom_l, totals, partials, ns,
                                sizes, n_groups_list,
                                float(params.min_region_count))
-        group_regs = jax.lax.pmax(_sketch_fold(regs, n_groups_list), ax)
+        group_regs = _mesh_fold_max(regs, n_groups_list, ax)
         return partials, jax.lax.psum(rows, ax), group_regs
 
-    sharded = _mesh_shard_map(
-        body, mesh,
+    sharded = jax.shard_map(
+        body, mesh=mesh,
         in_specs=(row, row, row, vec, row, vec, vec, vec),
         out_specs=(vec, P(None, None), P(None, None)))
     return jax.jit(sharded)
@@ -1136,12 +1138,13 @@ def collective_footprint(hlo_text: str):
 def pilot_stats_device(values) -> Tuple[float, float, float]:
     """Pre-estimation moment accumulation on device: ``(sketch0, sigma,
     min)`` of a host pilot array via the same jnp reduction path Phase 2
-    runs on (``run_pilot``'s ``stats_fn`` hook for ``route="device"``).
+    runs on (a ``stats_fn`` for ``preestimation.run_pilot``).
 
     fp32-safe by the usual lever: values are pre-scaled by a host-side
     normalizer (the pilot's max |value|) so the device sums are O(n), and
     the three statistics are exactly scale-equivariant.  sigma uses ddof=1
-    to match the host pilot.
+    to match the host pilot.  The served routes do not use it: their
+    anchors come from the float64 host pilot (``MultiQueryExecutor``).
     """
     v_host = np.asarray(values, dtype=np.float64).reshape(-1)
     if v_host.size == 0:

@@ -1062,10 +1062,16 @@ class MultiQueryExecutor:
 
     def _run_pilot(self, queries: Sequence[IslaQuery],
                    rng: np.random.Generator, params: IslaParams,
-                   sigma_guess: Optional[float], stats_fn
+                   sigma_guess: Optional[float]
                    ) -> Tuple["object", Mapping[str, np.ndarray]]:
         """Pilot over the measure column; the full pilot rows are captured
-        so the planner can estimate predicate selectivities from them."""
+        so the planner can estimate predicate selectivities from them.
+
+        Every route reduces the pilot on the host in float64: the pilot
+        fixes the frozen anchor (region cuts, sketch0), and an anchor
+        that moved with the route's arithmetic would re-classify samples
+        near a cut — device and host answers would then differ by more
+        than the fp32 tolerance of the tick itself."""
         captured = []
 
         def capture(sampler):
@@ -1088,7 +1094,7 @@ class MultiQueryExecutor:
                 sigma_guess = 1e-9
         pilot = run_pilot([capture(s) for s in self.block_samplers],
                           self.block_sizes, params, rng,
-                          sigma_guess=sigma_guess, stats_fn=stats_fn)
+                          sigma_guess=sigma_guess)
         if captured:
             keys = set(captured[0])
             columns = {k: np.concatenate([r[k] for r in captured if k in r])
@@ -1108,22 +1114,6 @@ class MultiQueryExecutor:
             from .. import compat
             self.mesh = compat.make_mesh((jax.device_count(),), ("cells",))
         return self.mesh
-
-    def _pilot_stats_fn(self, route: str):
-        """Device-route pilot: the jnp moment accumulation with a host
-        fallback (returning None keeps run_pilot on the host reduction)."""
-        if route not in ("device", "mesh"):
-            return None
-
-        def stats(pilot_values):
-            try:
-                from .distributed import pilot_stats_device
-                return pilot_stats_device(pilot_values)
-            except (ImportError, RuntimeError):
-                # jax / the backend is unavailable: fall back to the host
-                # reduction.  Anything else is a real bug and must surface.
-                return None
-        return stats
 
     def plan(self, queries: Sequence[IslaQuery], rng: np.random.Generator,
              mode: str = "calibrated", route: str = "host",
@@ -1150,9 +1140,8 @@ class MultiQueryExecutor:
         params = self.params.replace(e=min(q.e for q in sampled),
                                      beta=max(q.beta for q in sampled))
         if pilot is None:
-            pilot, pilot_columns = self._run_pilot(
-                queries, rng, params, sigma_guess,
-                self._pilot_stats_fn(route))
+            pilot, pilot_columns = self._run_pilot(queries, rng, params,
+                                                   sigma_guess)
         elif pilot_columns is None:
             pilot_columns = {}
         global_anchor = Anchor.from_pilot(pilot, params)

@@ -159,9 +159,11 @@ def splitmix64_graph(hi, lo):
     return hi ^ thi, lo ^ tlo
 
 
-def encode_graph(hi, lo):
+def encode_graph(hi, lo, rho_dtype=None):
     """In-graph ``hash -> (j, rho)``: rank via ``lax.clz`` over the limb
-    pair (``clz(0) == 32`` makes the all-zero remainder land on 53)."""
+    pair (``clz(0) == 32`` makes the all-zero remainder land on 53).
+    ``rho`` is uint8 (the register dtype) unless ``rho_dtype`` asks for
+    another — the Pallas kernel keeps ranks in int32 vector registers."""
     import jax
     import jax.numpy as jnp
 
@@ -170,7 +172,7 @@ def encode_graph(hi, lo):
     lz = jnp.where(rem_hi != 0,
                    jax.lax.clz(rem_hi) - 12,
                    20 + jax.lax.clz(lo))
-    rho = (lz + 1).astype(jnp.uint8)
+    rho = (lz + 1).astype(jnp.uint8 if rho_dtype is None else rho_dtype)
     return j, rho
 
 

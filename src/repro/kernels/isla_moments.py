@@ -1,10 +1,14 @@
-"""Pallas TPU kernel for ISLA Phase 1: classify + masked moment reduction.
+"""Pallas TPU kernels for ISLA Phase 1: classify + masked moment reduction.
 
 The paper's Alg. 1 is a scalar loop over samples; the TPU-native version is a
 tiled, vectorized reduction: each grid step streams one (TM, 128) tile
-HBM -> VMEM, computes the S/L masks on the VPU, and accumulates the eight
-moment scalars into a single (2, 4) output block that every grid step maps to
-(sequential-grid accumulation — the standard TPU reduction idiom).
+HBM -> VMEM, computes the S/L masks on the VPU, and folds the tile's
+sublane axis into a lane-dense (8, 128) accumulator block — one row per
+moment (S: count, s1, s2, s3; L: the same), one partial sum per lane —
+that every tile step of a cell maps to (sequential-grid accumulation, the
+standard TPU reduction idiom).  The 128 lane partials are summed outside
+the kernel.  Lane-dense rows keep every store a full-width vector store;
+the region cuts are scalars and live in SMEM.
 
 The *strided* variant is the fused "sample while reducing" path: the input
 index_map selects every ``stride``-th tile, so HBM traffic is cut by the
@@ -16,6 +20,10 @@ rate; see DESIGN.md §3.
 Padding contract: callers pad the tail with any value strictly inside the N
 region ((s_hi + l_lo)/2 is always safe) — N-region values contribute to
 neither S nor L, so no validity mask is needed.
+
+Every kernel takes ``interpret``: ``True`` runs the same body through the
+Pallas interpreter (any backend, the CPU test tier), ``False`` compiles it
+with Mosaic for the TPU.
 """
 from __future__ import annotations
 
@@ -24,38 +32,46 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 LANE = 128          # TPU vector lane width
 DEFAULT_TM = 512    # rows per tile -> tile = 512*128*4B = 256 KiB VMEM
+MOMENT_ROWS = 8     # (S, L) x (count, s1, s2, s3): one accumulator row each
 
 
 def _moments_kernel(bounds_ref, prior_ref, x_ref, o_ref):
-    """One grid step: accumulate tile moments into o_ref (2, 4).
+    """Grid (cell, tile): fold one tile into the cell's (1, 8, 128)
+    lane-partial moment block.
 
     The accumulator is seeded from ``prior_ref`` instead of zeros — the
     online continuation (§VII-A): passing a previous round's moments as the
     prior operand merges the rounds on device without a second pass.
+    ``bounds_ref`` is the cell's (1, 1, 4) row of region cuts in SMEM
+    (one shared row, or the cell's own row of a per-cell table).
     """
-    @pl.when(pl.program_id(0) == 0)
+    @pl.when(pl.program_id(1) == 0)
     def _init():
-        o_ref[...] = prior_ref[...].astype(jnp.float32)
+        o_ref[...] = prior_ref[...]
 
-    x = x_ref[...].astype(jnp.float32)
-    s_lo, s_hi = bounds_ref[0], bounds_ref[1]
-    l_lo, l_hi = bounds_ref[2], bounds_ref[3]
-
+    x = x_ref[0].astype(jnp.float32)                      # (tm, 128)
+    s_lo, s_hi = bounds_ref[0, 0, 0], bounds_ref[0, 0, 1]
+    l_lo, l_hi = bounds_ref[0, 0, 2], bounds_ref[0, 0, 3]
     ms = ((x > s_lo) & (x < s_hi)).astype(jnp.float32)
     ml = ((x > l_lo) & (x < l_hi)).astype(jnp.float32)
     xs = x * ms
     xl = x * ml
-    # rows: (S, L); cols: (count, s1, s2, s3)
-    tile = jnp.stack([
-        jnp.stack([jnp.sum(ms), jnp.sum(xs), jnp.sum(xs * x),
-                   jnp.sum(xs * x * x)]),
-        jnp.stack([jnp.sum(ml), jnp.sum(xl), jnp.sum(xl * x),
-                   jnp.sum(xl * x * x)]),
-    ])
-    o_ref[...] += tile
+    # rows: S (count, s1, s2, s3), then L (count, s1, s2, s3)
+    for k, col in enumerate((ms, xs, xs * x, xs * x * x,
+                             ml, xl, xl * x, xl * x * x)):
+        o_ref[0, k:k + 1, :] += jnp.sum(col, axis=0, keepdims=True)
+
+
+def _lane_prior(prior: jnp.ndarray) -> jnp.ndarray:
+    """(n, 2, 4) moments -> the kernel's (n, 8, 128) lane-partial layout
+    (each moment in lane 0, zeros elsewhere)."""
+    n = prior.shape[0]
+    return jnp.zeros((n, MOMENT_ROWS, LANE), jnp.float32).at[:, :, 0].set(
+        prior.reshape(n, MOMENT_ROWS).astype(jnp.float32))
 
 
 @functools.partial(jax.jit,
@@ -70,91 +86,16 @@ def isla_moments_pallas(values2d: jnp.ndarray, bounds: jnp.ndarray,
     (s_lo, s_hi, l_lo, l_hi).  stride > 1 reads every stride-th tile only.
     ``prior`` optionally seeds the accumulator with a previous round's
     (2, 4) moments (the §VII-A continuation merged in the same launch).
-    Returns (2, 4) fp32 moments.
+    Returns (2, 4) fp32 moments — the one-cell case of
+    ``isla_moments_batched_pallas``.
     """
-    rows, lane = values2d.shape
-    if lane != LANE:
-        raise ValueError(f"last dim must be {LANE}, got {lane}")
-    if rows % tm != 0:
-        raise ValueError(f"rows {rows} not a multiple of tile rows {tm}")
-    n_tiles = rows // tm
-    n_sel = max(1, n_tiles // stride) if stride > 1 else n_tiles
-    if prior is None:
-        prior = jnp.zeros((2, 4), jnp.float32)
-    if prior.shape != (2, 4):
+    if values2d.ndim != 2:
+        raise ValueError(f"need (rows, {LANE}), got shape {values2d.shape}")
+    if prior is not None and prior.shape != (2, 4):
         raise ValueError(f"prior must be (2, 4), got {prior.shape}")
-
-    grid_spec = pl.GridSpec(
-        grid=(n_sel,),
-        in_specs=[
-            pl.BlockSpec(memory_space=pl.ANY),  # bounds: tiny, replicated
-            pl.BlockSpec((2, 4), lambda i: (0, 0)),  # prior accumulator
-            pl.BlockSpec((tm, LANE), lambda i: (i * stride, 0)),
-        ],
-        out_specs=pl.BlockSpec((2, 4), lambda i: (0, 0)),
-    )
-    return pl.pallas_call(
-        _moments_kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((2, 4), jnp.float32),
-        interpret=interpret,
-    )(bounds.astype(jnp.float32), prior.astype(jnp.float32), values2d)
-
-
-def _moments_batched_kernel(bounds_ref, prior_ref, x_ref, o_ref):
-    """Grid (block, tile): accumulate one block's tile into o_ref (1, 2, 4).
-
-    Same body as ``_moments_kernel`` with a leading block axis: the output
-    block is indexed by grid dim 0, so each block owns its (2, 4) moment
-    cell and the tile axis accumulates sequentially within it — seeded from
-    that block's ``prior_ref`` cell (zeros on a fresh round).
-    """
-    @pl.when(pl.program_id(1) == 0)
-    def _init():
-        o_ref[...] = prior_ref[...].astype(jnp.float32)
-
-    x = x_ref[...].astype(jnp.float32)
-    s_lo, s_hi = bounds_ref[0], bounds_ref[1]
-    l_lo, l_hi = bounds_ref[2], bounds_ref[3]
-
-    ms = ((x > s_lo) & (x < s_hi)).astype(jnp.float32)
-    ml = ((x > l_lo) & (x < l_hi)).astype(jnp.float32)
-    xs = x * ms
-    xl = x * ml
-    tile = jnp.stack([
-        jnp.stack([jnp.sum(ms), jnp.sum(xs), jnp.sum(xs * x),
-                   jnp.sum(xs * x * x)]),
-        jnp.stack([jnp.sum(ml), jnp.sum(xl), jnp.sum(xl * x),
-                   jnp.sum(xl * x * x)]),
-    ])
-    o_ref[...] += tile[None]
-
-
-def _moments_cellbounds_kernel(bounds_ref, prior_ref, x_ref, o_ref):
-    """``_moments_batched_kernel`` with PER-CELL region cuts: grid dim 0
-    additionally indexes a (1, 4) row of the stacked anchor-bounds table,
-    so cells classifying under different (per-key refined) anchors ride
-    one launch.  The cuts arrive pre-scaled into each cell's own frame
-    (the anchor-scale vector contract of ``distributed.fused_tick``)."""
-    @pl.when(pl.program_id(1) == 0)
-    def _init():
-        o_ref[...] = prior_ref[...].astype(jnp.float32)
-
-    x = x_ref[...].astype(jnp.float32)
-    s_lo, s_hi = bounds_ref[0, 0], bounds_ref[0, 1]
-    l_lo, l_hi = bounds_ref[0, 2], bounds_ref[0, 3]
-
-    ms = ((x > s_lo) & (x < s_hi)).astype(jnp.float32)
-    ml = ((x > l_lo) & (x < l_hi)).astype(jnp.float32)
-    xs = x * ms
-    xl = x * ml
-    tile = jnp.stack([
-        jnp.stack([jnp.sum(ms), jnp.sum(xs), jnp.sum(xs * x),
-                   jnp.sum(xs * x * x)]),
-        jnp.stack([jnp.sum(ml), jnp.sum(xl), jnp.sum(xl * x),
-                   jnp.sum(xl * x * x)]),
-    ])
-    o_ref[...] += tile[None]
+    return isla_moments_batched_pallas(
+        values2d[None], bounds, tm=tm, stride=stride, interpret=interpret,
+        prior=None if prior is None else prior[None])[0]
 
 
 @functools.partial(jax.jit,
@@ -197,21 +138,28 @@ def isla_moments_batched_pallas(values3d: jnp.ndarray, bounds: jnp.ndarray,
     grid_spec = pl.GridSpec(
         grid=(n_blocks, n_sel),
         in_specs=[
-            # bounds: tiny and replicated when shared; a (1, 4) row
-            # indexed by the block axis when per-cell.
-            (pl.BlockSpec((1, 4), lambda b, i: (b, 0)) if per_cell
-             else pl.BlockSpec(memory_space=pl.ANY)),
-            pl.BlockSpec((1, 2, 4), lambda b, i: (b, 0, 0)),  # prior cells
+            # region cuts: one SMEM row per cell — the shared row for
+            # every cell, or the cell's own row of a per-cell table (a
+            # (1, 1, 4) block: its trailing dims equal the array's, which
+            # satisfies the TPU block-tiling rule).
+            pl.BlockSpec((1, 1, 4), ((lambda b, i: (b, 0, 0)) if per_cell
+                                     else (lambda b, i: (0, 0, 0))),
+                         memory_space=pltpu.SMEM),
+            pl.BlockSpec((1, MOMENT_ROWS, LANE), lambda b, i: (b, 0, 0)),
             pl.BlockSpec((1, tm, LANE), lambda b, i: (b, i * stride, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 2, 4), lambda b, i: (b, 0, 0)),
+        out_specs=pl.BlockSpec((1, MOMENT_ROWS, LANE),
+                               lambda b, i: (b, 0, 0)),
     )
-    return pl.pallas_call(
-        _moments_cellbounds_kernel if per_cell else _moments_batched_kernel,
+    acc = pl.pallas_call(
+        _moments_kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((n_blocks, 2, 4), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((n_blocks, MOMENT_ROWS, LANE),
+                                       jnp.float32),
         interpret=interpret,
-    )(bounds.astype(jnp.float32), prior.astype(jnp.float32), values3d)
+    )(bounds.astype(jnp.float32).reshape(-1, 1, 4), _lane_prior(prior),
+      values3d)
+    return acc.sum(axis=-1).reshape(n_blocks, 2, 4)
 
 
 def isla_moments_grouped_pallas(values4d: jnp.ndarray, bounds: jnp.ndarray,
@@ -320,42 +268,64 @@ REG_ROWS = 32       # HLL register block: 4096 registers = (32, 128) tile
                     # registers are one native uint8 VMEM block.
 
 
-def _sketch_kernel(hi_ref, lo_ref, valid_ref, prior_ref, o_ref):
-    """One grid step: merge one (tm, 128) hash-limb tile into the cell's
-    (1, 32, 128) HLL register block (elementwise max accumulation).
+def _sketch_kernel(hi_ref, lo_ref, valid_ref, prior_ref, o_ref, key_ref,
+                   regs_ref):
+    """Grid (cell, tile): merge one (tm, 128) hash-limb tile into the
+    cell's HLL registers (elementwise max accumulation).
 
     The hash and the (j, rho) encoding are the shared in-graph uint32-limb
     twins from ``repro.core.sketch`` — the SAME traced arithmetic the
     fused jnp tick runs, so the Pallas route is bit-identical by
-    construction.  The scatter is realized as the TPU-native one-hot
-    lane-max: for each of the 32 register sublane rows, samples landing
-    on that row one-hot against the 128 lanes and max-reduce over the
-    tile (a dense VPU reduction instead of a data-dependent scatter).
+    construction.  The scatter is realized as a one-hot lane-max on the
+    VPU: for register row ``rr`` and each sample row of the tile, a
+    (128 target lanes, 128 samples) one-hot of the samples landing on
+    that row is max-folded into a register-resident (128, 128) int32
+    accumulator; one transpose and a sublane max turn it into the row's
+    128 lanes.  The live intermediate is one (128, 128) block, whatever
+    ``tm`` is.
 
-    ``valid_ref`` masks pad lanes to rho = 0 — the merge's neutral
-    element — because unlike the moments' in-N padding contract a pad
-    value's hash would otherwise hit a real register.
+    Registers stay int32 in the ``regs_ref`` scratch across the cell's
+    tiles (seeded from ``prior_ref``) and are cast to uint8 once, at the
+    store after the cell's last tile.  ``valid_ref`` masks pad lanes to
+    rho = 0 — the merge's neutral element — because unlike the moments'
+    in-N padding contract a pad value's hash would otherwise hit a real
+    register.
     """
     from repro.core import sketch as _sk
 
-    @pl.when(pl.program_id(1) == 0)
-    def _init():
-        o_ref[...] = prior_ref[...]
+    tile = pl.program_id(1)
 
-    hi, lo = hi_ref[0], lo_ref[0]                         # (tm, 128)
-    j, rho = _sk.encode_graph(*_sk.splitmix64_graph(hi, lo))
-    rho = jnp.where(valid_ref[0] != 0, rho.astype(jnp.int32), 0)
-    lane = (j & (LANE - 1))[..., None]                    # (tm, 128, 1)
-    lane_ids = jax.lax.broadcasted_iota(
-        jnp.int32, lane.shape[:-1] + (LANE,), len(lane.shape) - 1)
-    rows = []
-    for rr in range(REG_ROWS):
-        rho_r = jnp.where(j >> 7 == rr, rho, 0)[..., None]
-        # (tm, 128, 128) one-hot contributions -> (128,) lane max
-        rows.append(jnp.max(jnp.where(lane_ids == lane, rho_r, 0),
-                            axis=(0, 1)))
-    tile = jnp.stack(rows).astype(jnp.uint8)              # (32, 128)
-    o_ref[...] = jnp.maximum(o_ref[...], tile[None])
+    @pl.when(tile == 0)
+    def _init():
+        regs_ref[...] = prior_ref[0].astype(jnp.int32)
+
+    j, rho = _sk.encode_graph(*_sk.splitmix64_graph(hi_ref[0], lo_ref[0]),
+                              rho_dtype=jnp.int32)
+    rho = jnp.where(valid_ref[0] != 0, rho, 0)
+    key_ref[...] = (j << 8) | rho              # j < 2^12, rho < 2^6
+    target = jax.lax.broadcasted_iota(jnp.int32, (LANE, LANE), 0)
+
+    def reg_row(rr, carry):
+        def sample_row(r, acc):
+            key = key_ref[pl.ds(r, 1), :]      # (1, 128) samples
+            jj = key >> 8
+            lane = jnp.where((jj >> 7) == rr, jj & (LANE - 1), -1)
+            hit = target == jnp.broadcast_to(lane, (LANE, LANE))
+            return jnp.maximum(acc, jnp.where(
+                hit, jnp.broadcast_to(key & 0xFF, (LANE, LANE)), 0))
+
+        acc = jax.lax.fori_loop(0, key_ref.shape[0], sample_row,
+                                jnp.zeros((LANE, LANE), jnp.int32))
+        row = jnp.max(acc.T, axis=0, keepdims=True)          # (1, 128)
+        regs_ref[pl.ds(rr, 1), :] = jnp.maximum(
+            regs_ref[pl.ds(rr, 1), :], row)
+        return carry
+
+    jax.lax.fori_loop(0, REG_ROWS, reg_row, 0)
+
+    @pl.when(tile == pl.num_programs(1) - 1)
+    def _store():
+        o_ref[0] = regs_ref[...].astype(jnp.uint8)
 
 
 @functools.partial(jax.jit, static_argnames=("tm", "interpret"))
@@ -396,6 +366,8 @@ def isla_sketch_pallas(hash_hi3d: jnp.ndarray, hash_lo3d: jnp.ndarray,
             pl.BlockSpec((1, REG_ROWS, LANE), lambda c, i: (c, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, REG_ROWS, LANE), lambda c, i: (c, 0, 0)),
+        scratch_shapes=[pltpu.VMEM((tm, LANE), jnp.int32),
+                        pltpu.VMEM((REG_ROWS, LANE), jnp.int32)],
     )
     return pl.pallas_call(
         _sketch_kernel,
@@ -446,17 +418,20 @@ def isla_fused_sketch_pallas(values3d: jnp.ndarray, bounds: jnp.ndarray,
 
 
 def _pilot_kernel(x_ref, o_ref):
+    """Grid (tile,): fold one tile into the (4, 128) lane-partial
+    (count, sum, sumsq, min) block."""
     x = x_ref[...].astype(jnp.float32)
 
     @pl.when(pl.program_id(0) == 0)
     def _init():
-        o_ref[...] = jnp.zeros_like(o_ref)
-        o_ref[3] = jnp.min(x)  # seed min with the first tile's min
+        o_ref[0:3, :] = jnp.zeros((3, LANE), jnp.float32)
+        o_ref[3:4, :] = jnp.full((1, LANE), jnp.inf, jnp.float32)
 
-    o_ref[0] += jnp.float32(x.size)
-    o_ref[1] += jnp.sum(x)
-    o_ref[2] += jnp.sum(x * x)
-    o_ref[3] = jnp.minimum(o_ref[3], jnp.min(x))
+    o_ref[0:1, :] += jnp.full((1, LANE), x.shape[0], jnp.float32)
+    o_ref[1:2, :] += jnp.sum(x, axis=0, keepdims=True)
+    o_ref[2:3, :] += jnp.sum(x * x, axis=0, keepdims=True)
+    o_ref[3:4, :] = jnp.minimum(o_ref[3:4, :],
+                                jnp.min(x, axis=0, keepdims=True))
 
 
 @functools.partial(jax.jit, static_argnames=("tm", "interpret"))
@@ -472,11 +447,12 @@ def pilot_stats_pallas(values2d: jnp.ndarray, tm: int = DEFAULT_TM,
     grid_spec = pl.GridSpec(
         grid=(n_tiles,),
         in_specs=[pl.BlockSpec((tm, LANE), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((4,), lambda i: (0,)),
+        out_specs=pl.BlockSpec((4, LANE), lambda i: (0, 0)),
     )
-    return pl.pallas_call(
+    acc = pl.pallas_call(
         _pilot_kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((4,), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((4, LANE), jnp.float32),
         interpret=interpret,
     )(values2d)
+    return jnp.concatenate([acc[:3].sum(axis=-1), acc[3:].min(axis=-1)])

@@ -2,9 +2,10 @@
 
 Handles: flattening/padding to the (rows, 128) tile layout, the N-region
 padding trick (pad values land strictly inside N so they are invisible to
-both masks), backend dispatch (compiled Pallas on TPU, interpret=True
-elsewhere — same kernel body, executed by the Pallas interpreter), and a
-pure-jnp fallback for shapes too small to tile.
+both masks), and a pure-jnp path for shapes too small to tile.  The caller
+says how the kernel runs: ``interpret=False`` compiles it for the TPU,
+``interpret=True`` executes the same body in the Pallas interpreter (the
+CPU test tier).  Nothing here looks at the backend.
 """
 from __future__ import annotations
 
@@ -19,10 +20,6 @@ from .isla_moments import (DEFAULT_TM, LANE, isla_moments_pallas,
                            pilot_stats_pallas)
 
 
-def _use_interpret() -> bool:
-    return jax.default_backend() != "tpu"
-
-
 def _pad_to_tiles(v: jnp.ndarray, tm: int, pad_value) -> jnp.ndarray:
     """Flatten and pad to (k * tm, 128)."""
     flat = v.reshape(-1)
@@ -33,9 +30,10 @@ def _pad_to_tiles(v: jnp.ndarray, tm: int, pad_value) -> jnp.ndarray:
     return flat.reshape(-1, LANE)
 
 
-@functools.partial(jax.jit, static_argnames=("tm", "stride"))
+@functools.partial(jax.jit, static_argnames=("tm", "stride", "interpret"))
 def isla_moments(values: jnp.ndarray, bounds: jnp.ndarray,
-                 tm: int = DEFAULT_TM, stride: int = 1) -> jnp.ndarray:
+                 tm: int = DEFAULT_TM, stride: int = 1, *,
+                 interpret: bool) -> jnp.ndarray:
     """ISLA Phase-1 moments of an arbitrary-shaped value tensor.
 
     bounds: (4,) = (s_lo, s_hi, l_lo, l_hi).  Returns (2, 4) fp32:
@@ -51,11 +49,12 @@ def isla_moments(values: jnp.ndarray, bounds: jnp.ndarray,
     pad = (bounds[1] + bounds[2]) * 0.5  # strictly inside N
     v2d = _pad_to_tiles(values, tm, pad)
     return isla_moments_pallas(v2d, bounds, tm=tm, stride=stride,
-                               interpret=_use_interpret())
+                               interpret=interpret)
 
 
-@functools.partial(jax.jit, static_argnames=("tm",))
-def pilot_stats(values: jnp.ndarray, tm: int = DEFAULT_TM) -> jnp.ndarray:
+@functools.partial(jax.jit, static_argnames=("tm", "interpret"))
+def pilot_stats(values: jnp.ndarray, tm: int = DEFAULT_TM, *,
+                interpret: bool) -> jnp.ndarray:
     """(count, sum, sumsq, min) of a value tensor (fp32).
 
     NOTE: padding uses the first element so min() stays honest; count/sum are
@@ -76,7 +75,7 @@ def pilot_stats(values: jnp.ndarray, tm: int = DEFAULT_TM) -> jnp.ndarray:
         jnp.where(
             (jnp.arange(padded).reshape(-1, LANE) < n), v2d,
             first.astype(v2d.dtype)),
-        tm=tm, interpret=_use_interpret())
+        tm=tm, interpret=interpret)
     # count includes pads (they were counted as elements): subtract; sum/sumsq
     # include n_pad copies of `first`: subtract.
     f32 = jnp.float32

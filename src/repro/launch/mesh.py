@@ -4,16 +4,12 @@ Functions (never module-level constants) so importing this module never
 touches jax device state.  The dry-run entry point (dryrun.py) sets
 XLA_FLAGS=--xla_force_host_platform_device_count=512 BEFORE importing jax;
 everything here just consumes whatever devices exist.
-
-``AxisType`` is imported defensively via ``repro.compat`` — older
-``jax.sharding`` modules don't expose it, in which case meshes are built
-without explicit axis types (the default is equivalent).
 """
 from __future__ import annotations
 
 from typing import Tuple
 
-from ..compat import AxisType, make_mesh  # noqa: F401  (AxisType re-exported)
+from ..compat import make_mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False):

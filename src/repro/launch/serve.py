@@ -534,13 +534,44 @@ def _describe_answer(t: IslaTicket) -> str:
     return line
 
 
-def serve_isla(args) -> None:
-    from repro.core import IslaParams
+ROWS_PER_BLOCK = 20000    # rows of each synthetic block's table
+BLOCK_SIZE = 10 ** 7      # catalog rows each block stands for
+
+
+def build_isla_loop(samplers, tables, n_groups: int, e: float, seed: int,
+                    route: str = "host", zone_map: bool = True,
+                    **loop_kw) -> IslaAdmissionLoop:
+    """The ISLA serving tier as ``serve_isla`` runs it over the synthetic
+    blocks: a ``ZoneMap`` over ``tables`` (unless ``zone_map=False``), a
+    ``MultiQueryExecutor`` over ``samplers`` at ``BLOCK_SIZE`` rows per
+    block, and the admission loop around it (auto mode, RNG seeded from
+    ``seed + 1``).  ``loop_kw`` passes through to ``IslaAdmissionLoop``."""
+    from repro.core import IslaParams, ZoneMap
     from repro.core.multiquery import MultiQueryExecutor
 
+    ex = MultiQueryExecutor(
+        samplers, [BLOCK_SIZE] * len(samplers), params=IslaParams(e=e),
+        group_domains={"region": n_groups},
+        zone_map=(ZoneMap.from_tables(tables, measure="value")
+                  if zone_map else None))
+    return IslaAdmissionLoop(ex, np.random.default_rng(seed + 1),
+                             mode="auto", route=route, **loop_kw)
+
+
+def random_tick_queries(qrng: np.random.Generator, n: int, e: float,
+                        n_days: Optional[int] = None,
+                        weights=(1.0,), tenants: int = 1) -> list:
+    """One tick's worth of ``_random_query`` traffic: ``n`` queries
+    round-robined over ``tenants``, each carrying its tenant's weight."""
+    return [_random_query(qrng, e, n_days=n_days,
+                          priority=weights[(j % tenants) % len(weights)])
+            for j in range(n)]
+
+
+def serve_isla(args) -> None:
     n_blocks = 8 if args.smoke else args.blocks
     n_groups = 3 if args.smoke else args.groups
-    rows = 2000 if args.smoke else 20000
+    rows = 2000 if args.smoke else ROWS_PER_BLOCK
     ticks = 2 if args.smoke else args.ticks
     qpt = 3 if args.smoke else args.queries_per_tick
     e = 1.0 if args.smoke else args.precision
@@ -548,41 +579,30 @@ def serve_isla(args) -> None:
     samplers, tables = _synthetic_grouped_blocks(n_blocks, n_groups, rows,
                                                  args.seed,
                                                  with_tables=True)
-    sizes = [10 ** 7] * n_blocks
-    zone_map = None
-    if not args.no_zone_map:
-        from repro.core import ZoneMap
-        zone_map = ZoneMap.from_tables(tables, measure="value")
-    ex = MultiQueryExecutor(samplers, sizes, params=IslaParams(e=e),
-                            group_domains={"region": n_groups},
-                            zone_map=zone_map)
     weights = [float(w) for w in args.priority.split(",")] \
         if args.priority else [1.0]
     if any(w <= 0 for w in weights):
         raise SystemExit("--priority weights must be > 0")
     tenants = max(int(args.tenants), 1)
-    loop = IslaAdmissionLoop(ex, np.random.default_rng(args.seed + 1),
-                             mode="auto", route=args.route,
-                             incremental=args.incremental,
-                             deadline_samples=args.deadline_samples,
-                             drift_check=args.drift_check,
-                             budget_floor=args.budget_floor,
-                             admission=(False if args.no_admission
-                                        else None),
-                             progressive=args.progressive,
-                             pipeline=args.pipeline)
-    n_days = max(n_blocks // 2, 1)
+    loop = build_isla_loop(samplers, tables, n_groups, e, args.seed,
+                           route=args.route,
+                           zone_map=not args.no_zone_map,
+                           incremental=args.incremental,
+                           deadline_samples=args.deadline_samples,
+                           drift_check=args.drift_check,
+                           budget_floor=args.budget_floor,
+                           admission=(False if args.no_admission
+                                      else None),
+                           progressive=args.progressive,
+                           pipeline=args.pipeline)
+    n_days = None if args.no_zone_map else max(n_blocks // 2, 1)
     qrng = np.random.default_rng(args.seed + 2)
     t0 = time.perf_counter()
     total = 0
     for _ in range(ticks):
-        for j in range(qpt):
-            # Round-robin tenants; each tenant's weight rides the query.
-            pri = weights[(j % tenants) % len(weights)]
-            loop.submit(_random_query(qrng, e,
-                                      n_days=None if args.no_zone_map
-                                      else n_days,
-                                      priority=pri))
+        for q in random_tick_queries(qrng, qpt, e, n_days=n_days,
+                                     weights=weights, tenants=tenants):
+            loop.submit(q)
         before = loop.stats
         done = loop.tick()
         total += len(done)
@@ -729,6 +749,8 @@ def main():
     if args.progressive and not args.incremental:
         ap.error("--progressive streams refinement across ticks via the "
                  "persistent stores; it requires --incremental")
+    from .compile_cache import configure_compile_cache
+    configure_compile_cache()
     if args.workload == "isla":
         serve_isla(args)
     else:

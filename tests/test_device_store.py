@@ -63,7 +63,7 @@ def test_device_store_bit_exact_x64(rng):
     """The float64 device store (tagged carry-prepend scatter) is
     BIT-IDENTICAL to the host bincount fold — moments, totals and the
     solved partials."""
-    from jax.experimental import enable_x64
+    from jax import enable_x64
 
     host, _ = _host_and_device()
     passes = [_tagged_pass(rng, 5, 3, 2000) for _ in range(2)]

@@ -41,6 +41,26 @@ def test_phase2_matches_host(mode, rng):
             f"trial {trial} (u={u}, v={v})"
 
 
+@pytest.mark.parametrize("mode", ["faithful", "calibrated"])
+def test_phase2_single_sample_regions_match_host(mode, rng):
+    """One sample in S and one in L: k is identically zero, so the host
+    answers c = (x + y) / 2 — faithful's balanced case included, since
+    the k guard takes precedence.  The fp32 device must agree on every
+    such cell, not only where its rounding noise happens to cancel."""
+    x = rng.uniform(0.5, 0.9, size=400)
+    y = rng.uniform(1.1, 1.5, size=400)
+    ms = np.stack([np.ones_like(x), x, x * x, x * x * x], axis=1)
+    ml = np.stack([np.ones_like(y), y, y * y, y * y * y], axis=1)
+    from repro.core.engine import phase2_iteration_batch
+    host = phase2_iteration_batch(ms, ml, 1.0, P,
+                                  mode="faithful_cf" if mode == "faithful"
+                                  else mode).avg
+    np.testing.assert_allclose(host, (x + y) / 2, rtol=1e-12)
+    dev = phase2(jnp.asarray(ms, jnp.float32), jnp.asarray(ml, jnp.float32),
+                 jnp.float32(1.0), P, mode=mode)
+    np.testing.assert_allclose(np.asarray(dev), host, rtol=1e-6)
+
+
 def test_choose_q_matches_host():
     for dev_val in [0.5, 0.95, 0.98, 1.0, 1.02, 1.05, 2.0]:
         got = float(choose_q(jnp.float32(dev_val), P))

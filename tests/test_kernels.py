@@ -40,7 +40,7 @@ def test_moments_kernel_strided(stride, rng):
 def test_ops_isla_moments_any_shape(n, rng):
     """ops wrapper: arbitrary sizes via N-region padding; == oracle."""
     x = jnp.asarray(rng.normal(100, 20, size=(n,)), jnp.float32)
-    got = ops.isla_moments(x, BOUNDS_ARR, tm=64)
+    got = ops.isla_moments(x, BOUNDS_ARR, tm=64, interpret=True)
     want = ref.isla_moments_ref(x, *BOUNDS)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-4)
 
@@ -164,7 +164,7 @@ def test_pilot_stats_kernel(rng):
 @pytest.mark.parametrize("n", [500, 64 * 128 * 3 + 5])
 def test_ops_pilot_stats_padding_correction(n, rng):
     x = jnp.asarray(rng.normal(-5, 3, size=(n,)), jnp.float32)
-    got = ops.pilot_stats(x, tm=64)
+    got = ops.pilot_stats(x, tm=64, interpret=True)
     want = ref.pilot_stats_ref(x)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-4, atol=1e-2)
@@ -179,7 +179,7 @@ def test_kernel_feeds_phase2(rng):
     params = IslaParams()
     vals = rng.normal(100, 20, size=(64 * 128 * 4,))
     x = jnp.asarray(vals, jnp.float32)
-    mom = ops.isla_moments(x, BOUNDS_ARR, tm=64)
+    mom = ops.isla_moments(x, BOUNDS_ARR, tm=64, interpret=True)
     dev_avg = float(phase2(mom[0], mom[1], jnp.float32(100.0), params,
                            mode="calibrated"))
     b = Boundaries(*BOUNDS)

@@ -166,7 +166,7 @@ def test_mesh_x64_state_and_partials_bit_exact(rng):
     accumulation order).  The psum'd stat rows are only allclose — the
     cross-shard reduction order is the one thing that legitimately
     differs."""
-    from jax.experimental import enable_x64
+    from jax import enable_x64
 
     with enable_x64():
         single, msh, singles, meshes = _pair()

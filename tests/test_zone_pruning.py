@@ -301,7 +301,7 @@ def test_compacted_launch_bit_identical_x64(rng):
     scatter the delta) reproduces the full-axis launch BIT-IDENTICALLY
     on the resident x64 state — active cells see the same adds in the
     same order, pruned cells are never addressed."""
-    from jax.experimental import enable_x64
+    from jax import enable_x64
 
     with enable_x64():
         n_blocks, n_groups = 24, 3
@@ -324,7 +324,7 @@ def test_pruned_cells_stay_resident_and_reactivate_warm(rng):
     ticks and re-activate warm: drawing block 5 after rounds that never
     touched it merges onto block 5's ORIGINAL state, bit-identically to
     the never-compacted stack (x64)."""
-    from jax.experimental import enable_x64
+    from jax import enable_x64
 
     with enable_x64():
         n_blocks, n_groups = 24, 3
