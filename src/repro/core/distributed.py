@@ -19,6 +19,8 @@ from __future__ import annotations
 
 import contextlib
 import functools
+import threading
+import time
 from typing import Optional, Tuple
 
 import jax
@@ -211,9 +213,12 @@ def h2d(x, dtype=None) -> jnp.ndarray:
     serving path.  Every array the steady-state tick ships to the device
     (fresh sample values and their segment tags — never moments) goes
     through here, so tests can count crossings and wrap the rest of the
-    tick in a ``jax.transfer_guard("disallow")``."""
+    tick in a ``jax.transfer_guard("disallow")``.  Its bytes are booked
+    under the ``h2d_bytes`` counter of the open stage's run (``count``)."""
     with jax.transfer_guard("allow"):
-        return jnp.asarray(x, dtype=dtype)
+        out = jnp.asarray(x, dtype=dtype)
+    count("h2d_bytes", out.nbytes)
+    return out
 
 
 def d2h_async(x):
@@ -258,14 +263,87 @@ def launch_pool():
     return _launch_pool
 
 
+class StageClock(dict):
+    """One run's stage clock: stage key -> seconds (the mapping itself),
+    plus ``counts``, the run's counters, which ``count`` books while one
+    of the run's stages is open on the calling thread."""
+
+    def __init__(self, stages=(), counters=()):
+        super().__init__(dict.fromkeys(stages, 0.0))
+        self.counts = dict.fromkeys(counters, 0)
+
+
+class _OpenStage:
+    __slots__ = ("timings", "part", "nested")
+
+    def __init__(self, timings, part: bool) -> None:
+        self.timings = timings
+        self.part = part
+        self.nested = 0.0   # seconds of top-level stages nested inside
+
+
+_open_stages = threading.local()
+_book_lock = threading.Lock()   # the pipelined worker books concurrently
+
+
+def _stage_frames() -> list:
+    frames = getattr(_open_stages, "frames", None)
+    if frames is None:
+        frames = _open_stages.frames = []
+    return frames
+
+
 @contextlib.contextmanager
-def stage_trace(name: str):
-    """Profiler stage marker for the pipelined tick: wraps a stage (h2d
-    staging, the fused launch dispatch, readback) in a
-    ``jax.profiler.TraceAnnotation`` + ``jax.named_scope`` so device
-    traces show pipeline stage names."""
-    with jax.profiler.TraceAnnotation(name), jax.named_scope(name):
-        yield
+def stage_trace(name: str, timings=None, key: Optional[str] = None):
+    """One stage of the served tick, on the profiler's clock and on the
+    stage clock at once.
+
+    Opens ``jax.profiler.TraceAnnotation(f"isla:{name}")`` — a host span
+    on the clock the device trace shares — and books the same interval
+    into ``timings[key or name]``:
+
+     * a top-level stage (``draw``) books its SELF time: the interval
+       minus the top-level stages nested in it on this thread;
+     * a part (a dotted name, ``draw.sample``) books its inclusive
+       interval and is not subtracted from its parent.
+
+    Nesting is tracked per thread, so a stage the pipelined launch worker
+    runs is never subtracted from the main thread's: the stage sum then
+    exceeds the wall clock by exactly the overlap.  ``timings=None``
+    emits the span and books nothing."""
+    frames = _stage_frames()
+    frame = _OpenStage(timings, "." in name)
+    frames.append(frame)
+    with jax.profiler.TraceAnnotation("isla:" + name):
+        t0 = time.perf_counter()
+        try:
+            yield
+        finally:
+            spent = time.perf_counter() - t0
+            frames.pop()
+    if not frame.part:
+        for outer in reversed(frames):
+            if not outer.part:
+                outer.nested += spent
+                break
+        spent = max(spent - frame.nested, 0.0)
+    if timings is not None:
+        key = name if key is None else key
+        with _book_lock:
+            timings[key] = timings.get(key, 0.0) + spent
+
+
+def count(name: str, n: int) -> None:
+    """Add ``n`` to counter ``name`` of the run whose stage is innermost
+    on this thread (dropped when that stage's clock keeps no counts, or
+    no stage is open)."""
+    for frame in reversed(_stage_frames()):
+        if frame.timings is not None:
+            counts = getattr(frame.timings, "counts", None)
+            if counts is not None:
+                with _book_lock:
+                    counts[name] = counts.get(name, 0) + int(n)
+            return
 
 
 def _segment_carry_sum(prior: jnp.ndarray, cols, seg: jnp.ndarray,
@@ -844,12 +922,15 @@ def mesh_h2d(mesh, x, spec, dtype=None) -> jnp.ndarray:
     ``spec`` is the ``PartitionSpec`` placing the array — ``P(ax, ...)``
     for cell-sharded operands, ``P()`` for the replicated sample stream.
     Everything the steady-state mesh tick ships to devices goes through
-    here so tests can wrap the rest in ``jax.transfer_guard``.
+    here so tests can wrap the rest in ``jax.transfer_guard``.  The
+    array's global bytes are booked like ``h2d``'s.
     """
     from jax.sharding import NamedSharding
     with jax.transfer_guard("allow"):
-        return jax.device_put(jnp.asarray(x, dtype=dtype),
-                              NamedSharding(mesh, spec))
+        out = jax.device_put(jnp.asarray(x, dtype=dtype),
+                             NamedSharding(mesh, spec))
+    count("h2d_bytes", out.nbytes)
+    return out
 
 
 @functools.lru_cache(maxsize=64)

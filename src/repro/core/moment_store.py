@@ -43,7 +43,6 @@ from __future__ import annotations
 import collections
 import dataclasses
 import math
-import time
 from typing import Optional, Sequence
 
 import numpy as np
@@ -72,7 +71,8 @@ class DrawChunk:
 
 def iter_chunked_draws(block_samplers: Sequence[Sampler],
                        quotas: np.ndarray, rng: np.random.Generator,
-                       chunk_blocks: Optional[int] = None):
+                       chunk_blocks: Optional[int] = None,
+                       assemble=None, timings=None):
     """THE chunked draw loop: the RNG-order / quota-padding / round-count
     contract shared by ``multiquery._draw_and_ingest`` (row samplers
     fanning into several stores) and ``MomentStore.continue_rounds``
@@ -91,7 +91,14 @@ def iter_chunked_draws(block_samplers: Sequence[Sampler],
        (the first chunk that draws anything), so callers count one logical
        round per pass regardless of chunking; an all-zero pass yields
        nothing and counts no round.
+
+    Each chunk's sampler calls run inside one ``draw.sample`` stage
+    (``distributed.stage_trace``, booked into ``timings``), together with
+    ``assemble(chunk)`` when given — the caller's column assembly, whose
+    result is yielded in the chunk's place.
     """
+    from . import distributed as D
+
     n_b = len(block_samplers)
     quotas = np.asarray(quotas, dtype=np.int64).reshape(-1)
     if quotas.shape != (n_b,):
@@ -105,11 +112,14 @@ def iter_chunked_draws(block_samplers: Sequence[Sampler],
         idx = [j for j in range(start, end) if quotas[j] > 0]
         if not idx:
             continue
-        raws = [block_samplers[j](int(quotas[j]), rng) for j in idx]
-        chunk_quotas = np.zeros(n_b, dtype=np.int64)
-        chunk_quotas[start:end] = quotas[start:end]
-        yield DrawChunk(start=start, end=end, idx=idx, raws=raws,
-                       chunk_quotas=chunk_quotas, first=first)
+        with D.stage_trace("draw.sample", timings):
+            raws = [block_samplers[j](int(quotas[j]), rng) for j in idx]
+            chunk_quotas = np.zeros(n_b, dtype=np.int64)
+            chunk_quotas[start:end] = quotas[start:end]
+            chunk = DrawChunk(start=start, end=end, idx=idx, raws=raws,
+                              chunk_quotas=chunk_quotas, first=first)
+            out = chunk if assemble is None else assemble(chunk)
+        yield out
         first = False
 
 
@@ -447,6 +457,70 @@ def _dense_panes(values: np.ndarray, quotas: np.ndarray):
     return v2d, pad, vmask
 
 
+def _key_panes(vmask: np.ndarray, key_gids, key_valids):
+    """Each stacked key's GROUP BY code and predicate-mask panes for the
+    dense tick, deduped by host-array identity: ``(gid panes, gid slots,
+    mask panes, mask slots)``, slot -1 where a key has none.  One pane per
+    distinct array, and the STATIC slot indices let the fused program
+    batch keys that share a GROUP BY pane into one contraction
+    (traced-operand identity is invisible inside jit)."""
+    gid_panes, valid_panes = [], []
+    gid_slots, valid_slots = [], []
+    seen_g, seen_v = {}, {}
+    for gids, valid in zip(key_gids, key_valids):
+        if gids is None:
+            gid_slots.append(-1)
+        elif id(gids) in seen_g:
+            gid_slots.append(seen_g[id(gids)])
+        else:
+            g2d = np.zeros(vmask.shape, dtype=np.int32)
+            g2d[vmask] = np.asarray(gids).reshape(-1)
+            seen_g[id(gids)] = len(gid_panes)
+            gid_slots.append(len(gid_panes))
+            gid_panes.append(g2d)
+        if valid is None:
+            valid_slots.append(-1)
+        elif id(valid) in seen_v:
+            valid_slots.append(seen_v[id(valid)])
+        else:
+            m2d = np.zeros(vmask.shape, dtype=np.float64)
+            m2d[vmask] = np.asarray(valid, dtype=np.float64).reshape(-1)
+            seen_v[id(valid)] = len(valid_panes)
+            valid_slots.append(len(valid_panes))
+            valid_panes.append(m2d)
+    return gid_panes, tuple(gid_slots), valid_panes, tuple(valid_slots)
+
+
+def _hash_panes(values: np.ndarray, vmask: np.ndarray):
+    """The dense tick's (hi, lo) register-hash panes of the RAW values."""
+    hhi, hlo = _sketch.value_limbs(values)
+    hi2d = np.zeros(vmask.shape, dtype=np.uint32)
+    lo2d = np.zeros(vmask.shape, dtype=np.uint32)
+    hi2d[vmask] = hhi
+    lo2d[vmask] = hlo
+    return hi2d, lo2d
+
+
+def _tagged_panes(values: np.ndarray, seg: np.ndarray, drop: int,
+                  hash_limbs=None):
+    """The tagged tick's bucket-padded value and cell-id streams (pad
+    slots carry the ``drop`` id) and, given ``hash_limbs``, its padded
+    (hi, lo) hash streams (else None)."""
+    m = values.size
+    bucket = _bucket(m)
+    v_pad = np.zeros(bucket, dtype=np.float64)
+    v_pad[:m] = values
+    s_pad = np.full(bucket, drop, dtype=np.int32)
+    s_pad[:m] = seg
+    h_pads = None
+    if hash_limbs is not None:
+        h_pads = (np.zeros(bucket, dtype=np.uint32),
+                  np.zeros(bucket, dtype=np.uint32))
+        h_pads[0][:m] = hash_limbs[0]
+        h_pads[1][:m] = hash_limbs[1]
+    return v_pad, s_pad, h_pads
+
+
 class _LazyRows:
     """Deferred stat-row readout of ONE fused launch, shared by every
     store of its stack.
@@ -458,8 +532,8 @@ class _LazyRows:
     is kept (its d2h already started via ``distributed.d2h_async``), and
     the ONE blocking ``np.asarray`` happens the first time any consumer
     — the composer, a ledger read, next tick's budget split — actually
-    needs the numbers.  ``timings`` (optional MutableMapping) accumulates
-    the blocking remainder under ``"readback"`` seconds."""
+    needs the numbers.  ``timings`` (optional MutableMapping) books
+    the blocking remainder as a ``readback`` stage."""
 
     __slots__ = ("_dev", "_np", "_timings", "_dtype")
 
@@ -471,12 +545,10 @@ class _LazyRows:
 
     def resolve(self) -> np.ndarray:
         if self._np is None:
-            t0 = time.perf_counter()
-            self._np = np.asarray(self._dev, dtype=self._dtype)  # d2h sync
-            if self._timings is not None:
-                self._timings["readback"] = (
-                    self._timings.get("readback", 0.0)
-                    + time.perf_counter() - t0)
+            from . import distributed as D
+
+            with D.stage_trace("readback", self._timings):
+                self._np = np.asarray(self._dev, dtype=self._dtype)  # d2h
             self._dev = None
         return self._np
 
@@ -1144,11 +1216,8 @@ class DeviceStack:
             while len(self._inflight) > 2:  # double-buffer depth
                 self._inflight.popleft().block_until_ready()
         else:
-            t0 = time.perf_counter()
-            rows_np = np.asarray(rows, dtype=np.float64)  # d2h: stats
-            if timings is not None:
-                timings["readback"] = (timings.get("readback", 0.0)
-                                       + time.perf_counter() - t0)
+            with D.stage_trace("readback", timings):
+                rows_np = np.asarray(rows, dtype=np.float64)  # d2h: stats
         gr_holder = None
         if group_regs is not None:
             gr_holder = _LazyRows(D.d2h_async(group_regs), timings,
@@ -1306,8 +1375,9 @@ class DeviceStack:
         shifted units (``DeviceMomentStore.partials_host`` / the
         executor's composer un-scale per store).
 
-        ``timings`` (optional dict) accumulates per-stage wall seconds
-        under ``"h2d"``/``"launch"``/``"readback"``.  ``defer_stats=True``
+        ``timings`` (optional dict) books the ``h2d`` stage (its parts
+        ``h2d.pane`` and ``h2d.put``), ``launch`` and ``readback``
+        (``distributed.stage_trace``).  ``defer_stats=True``
         is the pipelined route: the launch is dispatched but the stat-row
         readback only STARTS (async d2h) — the returned rows are lazy
         views that block on first access, letting the host stage the next
@@ -1335,9 +1405,8 @@ class DeviceStack:
                 return [(st._partials, st._rows_src)
                         for st in self.stores]
             mom_s, mom_l, totals, ns = self._state
-            t0 = time.perf_counter()
             group_regs = None
-            with D.stage_trace("isla:launch"):
+            with D.stage_trace("launch", timings):
                 if self.has_sketch:
                     partials, rows, group_regs = D.fused_solve_sketch(
                         mom_s, mom_l, totals, ns, self._regs_state,
@@ -1351,9 +1420,6 @@ class DeviceStack:
                         self._sizes, self._inv_scale, params=params,
                         mode=mode, geometry=geometry,
                         n_groups_list=self.n_groups_list)
-            if timings is not None:
-                timings["launch"] = (timings.get("launch", 0.0)
-                                     + time.perf_counter() - t0)
             return self._install_stats(partials, rows, cfg,
                                        defer=defer_stats, timings=timings,
                                        group_regs=group_regs)
@@ -1390,69 +1456,41 @@ class DeviceStack:
                 pane_quotas, _, active_cells = cp
             else:
                 pane_quotas, active_cells = quotas, None
-            t_h = time.perf_counter()
-            q_dev = D.h2d(pane_quotas.astype(np.float64), self.dtype)
-            v2d, pad, vmask = _dense_panes(pane_vals, pane_quotas)
-            # Dedupe shared panes by host-array identity into slot
-            # tuples: one upload per distinct pane, and the STATIC slot
-            # indices let the fused program batch keys that share a
-            # GROUP BY pane into one contraction (traced-operand
-            # identity is invisible inside jit).
-            gid_panes, valid_panes = [], []
-            gid_slots, valid_slots = [], []
-            seen_g, seen_v = {}, {}
-            for gids, valid in zip(key_gids, key_valids):
-                if gids is None:
-                    gid_slots.append(-1)
-                elif id(gids) in seen_g:
-                    gid_slots.append(seen_g[id(gids)])
-                else:
-                    g2d = np.zeros(v2d.shape, dtype=np.int32)
-                    g2d[vmask] = np.asarray(gids).reshape(-1)
-                    seen_g[id(gids)] = len(gid_panes)
-                    gid_slots.append(len(gid_panes))
-                    gid_panes.append(D.h2d(g2d, jnp.int32))
-                if valid is None:
-                    valid_slots.append(-1)
-                elif id(valid) in seen_v:
-                    valid_slots.append(seen_v[id(valid)])
-                else:
-                    m2d = np.zeros(v2d.shape, dtype=np.float64)
-                    m2d[vmask] = np.asarray(valid, dtype=np.float64
-                                            ).reshape(-1)
-                    seen_v[id(valid)] = len(valid_panes)
-                    valid_slots.append(len(valid_panes))
-                    valid_panes.append(D.h2d(m2d, self.dtype))
-            v_dev = D.h2d(v2d, self.dtype)
-            pad_dev = D.h2d(pad, self.dtype)
-            if self.has_sketch:
-                # Hash panes from the RAW dense stream (the pane itself
-                # is anchor-scaled; registers key on the raw bits).
-                hhi, hlo = _sketch.value_limbs(values)
-                hi2d = np.zeros(v2d.shape, dtype=np.uint32)
-                lo2d = np.zeros(v2d.shape, dtype=np.uint32)
-                hi2d[vmask] = hhi
-                lo2d[vmask] = hlo
-                hhi_dev = D.h2d(hi2d, jnp.uint32)
-                hlo_dev = D.h2d(lo2d, jnp.uint32)
-            if timings is not None:
-                timings["h2d"] = (timings.get("h2d", 0.0)
-                                  + time.perf_counter() - t_h)
-            t_l = time.perf_counter()
+            with D.stage_trace("h2d", timings):
+                with D.stage_trace("h2d.pane", timings):
+                    v2d, pad, vmask = _dense_panes(pane_vals, pane_quotas)
+                    D.count("pane_slots", v2d.size)
+                    gid_host, gid_slots, valid_host, valid_slots = \
+                        _key_panes(vmask, key_gids, key_valids)
+                    if self.has_sketch:
+                        # Hash panes from the RAW dense stream (the pane
+                        # is anchor-scaled; registers key on raw bits).
+                        hi2d, lo2d = _hash_panes(values, vmask)
+                with D.stage_trace("h2d.put", timings):
+                    q_dev = D.h2d(pane_quotas.astype(np.float64),
+                                  self.dtype)
+                    gid_panes = tuple(D.h2d(g, jnp.int32)
+                                      for g in gid_host)
+                    valid_panes = tuple(D.h2d(m, self.dtype)
+                                        for m in valid_host)
+                    v_dev = D.h2d(v2d, self.dtype)
+                    pad_dev = D.h2d(pad, self.dtype)
+                    if self.has_sketch:
+                        hhi_dev = D.h2d(hi2d, jnp.uint32)
+                        hlo_dev = D.h2d(lo2d, jnp.uint32)
             group_regs = None
-            with D.stage_trace("isla:launch"):
+            with D.stage_trace("launch", timings):
                 if self.has_sketch:
                     (mom_s, mom_l, totals, ns, regs, partials, rows,
                      group_regs) = D.fused_tick_dense_sketch(
                         mom_s, mom_l, totals, ns, self._regs_state,
                         v_dev, pad_dev, hhi_dev, hlo_dev, q_dev,
-                        tuple(gid_panes), tuple(valid_panes),
+                        gid_panes, valid_panes,
                         self._bound_rows, self._sketch0_cells(),
                         self._sizes, self._inv_scale, active_cells,
                         params=params, mode=mode, geometry=geometry,
                         n_groups_list=self.n_groups_list,
-                        gid_slots=tuple(gid_slots),
-                        valid_slots=tuple(valid_slots),
+                        gid_slots=gid_slots, valid_slots=valid_slots,
                         key_affine=key_affine,
                         bound_slots=self._bound_slots)
                     self._regs_state = regs
@@ -1460,51 +1498,38 @@ class DeviceStack:
                     mom_s, mom_l, totals, ns, partials, rows = \
                         D.fused_tick_dense(
                             mom_s, mom_l, totals, ns, v_dev,
-                            pad_dev, q_dev, tuple(gid_panes),
-                            tuple(valid_panes), self._bound_rows,
+                            pad_dev, q_dev, gid_panes, valid_panes,
+                            self._bound_rows,
                             self._sketch0_cells(), self._sizes,
                             self._inv_scale, active_cells,
                             params=params, mode=mode, geometry=geometry,
                             n_groups_list=self.n_groups_list,
-                            gid_slots=tuple(gid_slots),
-                            valid_slots=tuple(valid_slots),
+                            gid_slots=gid_slots, valid_slots=valid_slots,
                             key_affine=key_affine,
                             bound_slots=self._bound_slots)
-            if timings is not None:
-                timings["launch"] = (timings.get("launch", 0.0)
-                                     + time.perf_counter() - t_l)
         else:
             seg = np.asarray(seg, dtype=np.int32).reshape(-1)
             if values.shape != seg.shape:
                 raise ValueError("values and seg must align")
-            m = values.size
-            bucket = _bucket(m)
-            v_pad = np.zeros(bucket, dtype=np.float64)
-            v_pad[:m] = values
-            s_pad = np.full(bucket, self.n_cells, dtype=np.int32)  # drop
-            s_pad[:m] = seg
-            t_h = time.perf_counter()
-            q_dev = D.h2d(quotas.astype(np.float64), self.dtype)
-            v_dev = D.h2d(v_pad, self.dtype)
-            s_dev = D.h2d(s_pad, jnp.int32)
-            if self.has_sketch:
-                if hash_limbs is None:
-                    raise ValueError(
-                        "sketch stack tagged tick needs hash_limbs "
-                        "(sketch.value_limbs of the raw values)")
-                hhi, hlo = hash_limbs
-                hhi_pad = np.zeros(bucket, dtype=np.uint32)
-                hlo_pad = np.zeros(bucket, dtype=np.uint32)
-                hhi_pad[:m] = hhi
-                hlo_pad[:m] = hlo
-                hhi_dev = D.h2d(hhi_pad, jnp.uint32)
-                hlo_dev = D.h2d(hlo_pad, jnp.uint32)
-            if timings is not None:
-                timings["h2d"] = (timings.get("h2d", 0.0)
-                                  + time.perf_counter() - t_h)
-            t_l = time.perf_counter()
+            if self.has_sketch and hash_limbs is None:
+                raise ValueError(
+                    "sketch stack tagged tick needs hash_limbs "
+                    "(sketch.value_limbs of the raw values)")
+            with D.stage_trace("h2d", timings):
+                with D.stage_trace("h2d.pane", timings):
+                    v_pad, s_pad, h_pads = _tagged_panes(
+                        values, seg, self.n_cells,
+                        hash_limbs if self.has_sketch else None)
+                    D.count("pane_slots", v_pad.size)
+                with D.stage_trace("h2d.put", timings):
+                    q_dev = D.h2d(quotas.astype(np.float64), self.dtype)
+                    v_dev = D.h2d(v_pad, self.dtype)
+                    s_dev = D.h2d(s_pad, jnp.int32)
+                    if self.has_sketch:
+                        hhi_dev = D.h2d(h_pads[0], jnp.uint32)
+                        hlo_dev = D.h2d(h_pads[1], jnp.uint32)
             group_regs = None
-            with D.stage_trace("isla:launch"):
+            with D.stage_trace("launch", timings):
                 if self.has_sketch:
                     (mom_s, mom_l, totals, ns, regs, partials, rows,
                      group_regs) = D.fused_tick_sketch(
@@ -1524,9 +1549,6 @@ class DeviceStack:
                             self._inv_scale,
                             params=params, mode=mode, geometry=geometry,
                             n_groups_list=self.n_groups_list)
-            if timings is not None:
-                timings["launch"] = (timings.get("launch", 0.0)
-                                     + time.perf_counter() - t_l)
         self._state = (mom_s, mom_l, totals, ns)
         for st in self.stores:
             st.n_sampled = st.n_sampled + quotas
@@ -1740,11 +1762,8 @@ class MeshDeviceStack(DeviceStack):
             while len(self._inflight) > 2:  # double-buffer depth
                 self._inflight.popleft().block_until_ready()
         else:
-            t0 = time.perf_counter()
-            rows_np = np.asarray(rows, dtype=np.float64)  # d2h: stats only
-            if timings is not None:
-                timings["readback"] = (timings.get("readback", 0.0)
-                                       + time.perf_counter() - t0)
+            with D.stage_trace("readback", timings):
+                rows_np = np.asarray(rows, dtype=np.float64)  # d2h: stats
         gr_holder = None
         if group_regs is not None:
             gr_holder = _LazyRows(D.d2h_async(group_regs), timings,
@@ -1857,9 +1876,8 @@ class MeshDeviceStack(DeviceStack):
                    for st in self.stores):
                 return [(st._partials, st._rows_src)
                         for st in self.stores]
-            t0 = time.perf_counter()
             group_regs = None
-            with D.stage_trace("isla:launch"):
+            with D.stage_trace("launch", timings):
                 if self.has_sketch:
                     solve = D.mesh_solve_sketch_fn(
                         self.mesh, params, mode, geometry,
@@ -1874,9 +1892,6 @@ class MeshDeviceStack(DeviceStack):
                     partials, rows = solve(*self._state,
                                            self._sketch0_cells(),
                                            self._sizes, self._inv_scale)
-            if timings is not None:
-                timings["launch"] = (timings.get("launch", 0.0)
-                                     + time.perf_counter() - t0)
             return self._install_stats(partials, rows, cfg,
                                        defer=defer_stats, timings=timings,
                                        group_regs=group_regs)
@@ -1905,130 +1920,84 @@ class MeshDeviceStack(DeviceStack):
                 pane_quotas, _, active_cells = cp
             else:
                 pane_quotas, active_cells = quotas, None
-            t_h = time.perf_counter()
-            v2d, pad, vmask = _dense_panes(pane_vals, pane_quotas)
-            pane_rows = (S * bl) if active_cells is None else v2d.shape[0]
-            q_pad = np.zeros(pane_rows, dtype=np.float64)
-            q_pad[:pane_quotas.size] = pane_quotas
-            q_dev = D.mesh_h2d(self.mesh, q_pad, vec, self.dtype)
+            with D.stage_trace("h2d", timings):
+                with D.stage_trace("h2d.pane", timings):
+                    v2d, pad, vmask = _dense_panes(pane_vals, pane_quotas)
+                    pane_rows = ((S * bl) if active_cells is None
+                                 else v2d.shape[0])
+                    q_pad = np.zeros(pane_rows, dtype=np.float64)
+                    q_pad[:pane_quotas.size] = pane_quotas
 
-            def block_pad(a):
-                if a.shape[0] == pane_rows:
-                    return a
-                out = np.zeros((pane_rows, a.shape[1]), dtype=a.dtype)
-                out[:a.shape[0]] = a
-                return out
+                    def block_pad(a):
+                        if a.shape[0] == pane_rows:
+                            return a
+                        out = np.zeros((pane_rows, a.shape[1]),
+                                       dtype=a.dtype)
+                        out[:a.shape[0]] = a
+                        return out
 
-            gid_panes, valid_panes = [], []
-            gid_slots, valid_slots = [], []
-            seen_g, seen_v = {}, {}
-            for gids, valid in zip(key_gids, key_valids):
-                if gids is None:
-                    gid_slots.append(-1)
-                elif id(gids) in seen_g:
-                    gid_slots.append(seen_g[id(gids)])
-                else:
-                    g2d = np.zeros(v2d.shape, dtype=np.int32)
-                    g2d[vmask] = np.asarray(gids).reshape(-1)
-                    seen_g[id(gids)] = len(gid_panes)
-                    gid_slots.append(len(gid_panes))
-                    gid_panes.append(D.mesh_h2d(
-                        self.mesh, block_pad(g2d), row, jnp.int32))
-                if valid is None:
-                    valid_slots.append(-1)
-                elif id(valid) in seen_v:
-                    valid_slots.append(seen_v[id(valid)])
-                else:
-                    m2d = np.zeros(v2d.shape, dtype=np.float64)
-                    m2d[vmask] = np.asarray(valid, dtype=np.float64
-                                            ).reshape(-1)
-                    seen_v[id(valid)] = len(valid_panes)
-                    valid_slots.append(len(valid_panes))
-                    valid_panes.append(D.mesh_h2d(
-                        self.mesh, block_pad(m2d), row, self.dtype))
-            if self.has_sketch:
-                hhi, hlo = _sketch.value_limbs(values)
-                hi2d = np.zeros(v2d.shape, dtype=np.uint32)
-                lo2d = np.zeros(v2d.shape, dtype=np.uint32)
-                hi2d[vmask] = hhi
-                lo2d[vmask] = hlo
-                fn = D.mesh_tick_dense_sketch_fn(
+                    gid_host, gid_slots, valid_host, valid_slots = \
+                        _key_panes(vmask, key_gids, key_valids)
+                    gid_host = [block_pad(g) for g in gid_host]
+                    valid_host = [block_pad(m) for m in valid_host]
+                    panes = [block_pad(v2d), block_pad(pad)]
+                    if self.has_sketch:
+                        panes += map(block_pad, _hash_panes(values, vmask))
+                    D.count("pane_slots", panes[0].size)
+                with D.stage_trace("h2d.put", timings):
+                    q_dev = D.mesh_h2d(self.mesh, q_pad, vec, self.dtype)
+                    gid_panes = tuple(
+                        D.mesh_h2d(self.mesh, g, row, jnp.int32)
+                        for g in gid_host)
+                    valid_panes = tuple(
+                        D.mesh_h2d(self.mesh, m, row, self.dtype)
+                        for m in valid_host)
+                    pane_devs = [
+                        D.mesh_h2d(self.mesh, p, row,
+                                   self.dtype if i < 2 else jnp.uint32)
+                        for i, p in enumerate(panes)]
+                fn = (D.mesh_tick_dense_sketch_fn if self.has_sketch
+                      else D.mesh_tick_dense_fn)(
                     self.mesh, params, mode, geometry, self.n_groups_list,
-                    tuple(gid_slots), tuple(valid_slots), key_affine,
+                    gid_slots, valid_slots, key_affine,
                     self._bound_slots, len(gid_panes), len(valid_panes),
                     compacted=active_cells is not None)
-                args = (*self._state, self._regs_state,
-                        D.mesh_h2d(self.mesh, block_pad(v2d), row,
-                                   self.dtype),
-                        D.mesh_h2d(self.mesh, block_pad(pad), row,
-                                   self.dtype),
-                        D.mesh_h2d(self.mesh, block_pad(hi2d), row,
-                                   jnp.uint32),
-                        D.mesh_h2d(self.mesh, block_pad(lo2d), row,
-                                   jnp.uint32),
-                        q_dev, tuple(gid_panes), tuple(valid_panes),
-                        self._bound_rows, self._sketch0_cells(),
+                regs = (self._regs_state,) if self.has_sketch else ()
+                args = (*self._state, *regs, *pane_devs, q_dev, gid_panes,
+                        valid_panes, self._bound_rows, self._sketch0_cells(),
                         self._sizes, self._inv_scale)
-            else:
-                fn = D.mesh_tick_dense_fn(
-                    self.mesh, params, mode, geometry, self.n_groups_list,
-                    tuple(gid_slots), tuple(valid_slots), key_affine,
-                    self._bound_slots, len(gid_panes), len(valid_panes),
-                    compacted=active_cells is not None)
-                args = (*self._state,
-                        D.mesh_h2d(self.mesh, block_pad(v2d), row,
-                                   self.dtype),
-                        D.mesh_h2d(self.mesh, block_pad(pad), row,
-                                   self.dtype),
-                        q_dev, tuple(gid_panes), tuple(valid_panes),
-                        self._bound_rows, self._sketch0_cells(),
-                        self._sizes, self._inv_scale)
-            if active_cells is not None:
-                args = args + (active_cells,)
-            if timings is not None:
-                timings["h2d"] = (timings.get("h2d", 0.0)
-                                  + time.perf_counter() - t_h)
-            t_l = time.perf_counter()
-            with D.stage_trace("isla:launch"):
+                if active_cells is not None:
+                    args = args + (active_cells,)
+            with D.stage_trace("launch", timings):
                 out = fn(*args)
-            if timings is not None:
-                timings["launch"] = (timings.get("launch", 0.0)
-                                     + time.perf_counter() - t_l)
         else:
             seg = np.asarray(seg, dtype=np.int32).reshape(-1)
             if values.shape != seg.shape:
                 raise ValueError("values and seg must align")
-            m = values.size
-            bucket = _bucket(m)
-            v_pad = np.zeros(bucket, dtype=np.float64)
-            v_pad[:m] = values
-            # Pad/drop id: past every shard's window, so each shard
-            # retags it onto its local drop row.
-            s_pad = np.full(bucket, self.n_cells_mesh, dtype=np.int32)
-            s_pad[:m] = seg
-            t_h = time.perf_counter()
-            q_pad = np.zeros(S * bl, dtype=np.float64)
-            q_pad[:self.n_blocks] = quotas
-            q_dev = D.mesh_h2d(self.mesh, q_pad, vec, self.dtype)
-            v_dev = D.mesh_h2d(self.mesh, v_pad, rep, self.dtype)
-            s_dev = D.mesh_h2d(self.mesh, s_pad, rep, jnp.int32)
-            if self.has_sketch:
-                if hash_limbs is None:
-                    raise ValueError(
-                        "sketch stack tagged tick needs hash_limbs "
-                        "(sketch.value_limbs of the raw values)")
-                hhi, hlo = hash_limbs
-                hhi_pad = np.zeros(bucket, dtype=np.uint32)
-                hlo_pad = np.zeros(bucket, dtype=np.uint32)
-                hhi_pad[:m] = hhi
-                hlo_pad[:m] = hlo
-                hhi_dev = D.mesh_h2d(self.mesh, hhi_pad, rep, jnp.uint32)
-                hlo_dev = D.mesh_h2d(self.mesh, hlo_pad, rep, jnp.uint32)
-            if timings is not None:
-                timings["h2d"] = (timings.get("h2d", 0.0)
-                                  + time.perf_counter() - t_h)
-            t_l = time.perf_counter()
-            with D.stage_trace("isla:launch"):
+            if self.has_sketch and hash_limbs is None:
+                raise ValueError(
+                    "sketch stack tagged tick needs hash_limbs "
+                    "(sketch.value_limbs of the raw values)")
+            with D.stage_trace("h2d", timings):
+                with D.stage_trace("h2d.pane", timings):
+                    # Pad/drop id: past every shard's window, so each
+                    # shard retags it onto its local drop row.
+                    v_pad, s_pad, h_pads = _tagged_panes(
+                        values, seg, self.n_cells_mesh,
+                        hash_limbs if self.has_sketch else None)
+                    q_pad = np.zeros(S * bl, dtype=np.float64)
+                    q_pad[:self.n_blocks] = quotas
+                    D.count("pane_slots", v_pad.size)
+                with D.stage_trace("h2d.put", timings):
+                    q_dev = D.mesh_h2d(self.mesh, q_pad, vec, self.dtype)
+                    v_dev = D.mesh_h2d(self.mesh, v_pad, rep, self.dtype)
+                    s_dev = D.mesh_h2d(self.mesh, s_pad, rep, jnp.int32)
+                    if self.has_sketch:
+                        hhi_dev = D.mesh_h2d(self.mesh, h_pads[0], rep,
+                                             jnp.uint32)
+                        hlo_dev = D.mesh_h2d(self.mesh, h_pads[1], rep,
+                                             jnp.uint32)
+            with D.stage_trace("launch", timings):
                 if self.has_sketch:
                     fn = D.mesh_tick_sketch_fn(
                         self.mesh, params, mode, geometry,
@@ -2044,9 +2013,6 @@ class MeshDeviceStack(DeviceStack):
                     out = fn(*self._state, v_dev, s_dev,
                              q_dev, self._bounds, self._sketch0_cells(),
                              self._sizes, self._inv_scale)
-            if timings is not None:
-                timings["launch"] = (timings.get("launch", 0.0)
-                                     + time.perf_counter() - t_l)
         group_regs = None
         if self.has_sketch:
             (mom_s, mom_l, totals, ns, regs, partials, rows,

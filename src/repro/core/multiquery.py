@@ -73,13 +73,13 @@ from __future__ import annotations
 import copy
 import dataclasses
 import math
-import time
 import warnings
 from collections import OrderedDict
 from typing import Callable, Mapping, Optional, Sequence, Tuple, Union
 
 import numpy as np
 
+from . import distributed as D
 from . import sketch as _sketch
 from .engine import (AUTO_SKEW_THRESHOLD, MODES, IslaQuery, block_quotas,
                      phase2_iteration_batch, resolve_mode_and_geometry)
@@ -353,11 +353,18 @@ class _CachedAnswer:
     epoch: int = -1            # run epoch the stamp was last re-validated at
 
 
-# Pipelined-tick stage names, in execution order.  ``run(pipeline=True)``
-# and the device tier accumulate per-stage wall seconds under these keys
-# (``MultiQueryExecutor.last_stage_times``); serve's admission loop and
-# BENCH_pipeline.json report them.
-_STAGES = ("plan", "draw", "h2d", "launch", "readback", "compose")
+# The served tick's stage keys, in execution order: each is an
+# ``isla:<key>`` profiler span and a stage clock (``distributed.
+# stage_trace``).  A top-level key books self time (nested top-level
+# stages on the same thread subtracted); a dotted key is a part of its
+# parent and books inclusive time.  ``run`` books every key but
+# ``admit`` (the admission loop's own tick) into ``last_stage_times``.
+_STAGES = ("admit", "plan", "draw", "draw.stack", "draw.sample",
+           "draw.mask", "h2d", "h2d.pane", "h2d.put", "launch", "readback",
+           "compose")
+# Counters a run books beside its stage clock (``last_stage_times.
+# counts``): value-pane slots uploaded, and bytes handed to the uploads.
+_COUNTERS = ("pane_slots", "h2d_bytes")
 
 
 class _StagedGroup:
@@ -475,9 +482,9 @@ class MultiQueryExecutor:
         self.answers_cached = 0
         self.answers_subsumed = 0
         self._run_epoch = 0  # bumped per run(); gates ledger re-validation
-        # Pipelined-tick telemetry: per-stage wall seconds of the LAST
-        # run() (plan, draw, h2d, launch, readback, compose) — serve's
-        # admission loop accumulates these per tick.
+        # Stage telemetry of the LAST run(): seconds per ``_STAGES`` key,
+        # and the ``_COUNTERS`` under ``.counts`` — serve's admission
+        # loop accumulates both per tick.
         self.last_stage_times: "dict[str, float]" = {}
         self.plans_prefetched = 0  # cross-tick prefetch_plan() warm hits
 
@@ -664,7 +671,8 @@ class MultiQueryExecutor:
 
     def _draw_and_ingest(self, group_stores: Mapping[Tuple, MomentStore],
                          quotas: np.ndarray, rng: np.random.Generator,
-                         chunk_blocks: Optional[int] = None) -> None:
+                         chunk_blocks: Optional[int] = None,
+                         timings=None) -> None:
         """One tagged pass at explicit per-block quotas, folded into every
         key's store — each store receiving the stream translated by ITS
         OWN anchor shift (per-key anchors may shift differently).
@@ -678,7 +686,7 @@ class MultiQueryExecutor:
         """
         counted = set()       # one logical round per store per pass
         for chunk, columns, block_ids in self._iter_row_chunks(
-                quotas, rng, chunk_blocks):
+                quotas, rng, chunk_blocks, timings):
             raw = self._measure_of(columns)
             shifted = {}      # shift value -> translated stream (shared)
             for key, store in group_stores.items():
@@ -698,16 +706,19 @@ class MultiQueryExecutor:
 
     def _iter_row_chunks(self, quotas: np.ndarray,
                          rng: np.random.Generator,
-                         chunk_blocks: Optional[int]):
+                         chunk_blocks: Optional[int], timings=None):
         """Row-sampler adapter over the SHARED chunked draw loop
         (``moment_store.iter_chunked_draws`` — the same RNG-order /
         quota-padding / round-count contract ``MomentStore.
         continue_rounds`` obeys): yields ``(chunk, columns, block_ids)``
-        per chunk with cross-chunk column-agreement validation."""
+        per chunk with cross-chunk column-agreement validation.  The
+        sampler calls and the column assembly are one ``draw.sample``
+        stage per chunk."""
         quotas = np.asarray(quotas, dtype=np.int64).reshape(-1)
         expected_cols = None  # column agreement holds across the WHOLE pass
-        for chunk in iter_chunked_draws(self.block_samplers, quotas, rng,
-                                        chunk_blocks):
+
+        def assemble(chunk):
+            nonlocal expected_cols
             raws = [self._as_rows(r) for r in chunk.raws]
             for r in raws:
                 if expected_cols is None:
@@ -720,7 +731,11 @@ class MultiQueryExecutor:
                        for k in expected_cols}
             block_ids = np.repeat(np.asarray(chunk.idx, dtype=np.intp),
                                   [int(quotas[j]) for j in chunk.idx])
-            yield chunk, columns, block_ids
+            return chunk, columns, block_ids
+
+        yield from iter_chunked_draws(self.block_samplers, quotas, rng,
+                                      chunk_blocks, assemble=assemble,
+                                      timings=timings)
 
     def _zone_mask(self, where: Optional[Predicate],
                    columns: Mapping[str, np.ndarray],
@@ -1708,16 +1723,14 @@ class MultiQueryExecutor:
         dev_mode = self._device_mode(mg.mode)
         dense = stack.dtype != jnp.float64
 
-        def run_chunk(chunk, columns, block_ids):
-            raw = self._measure_of(columns)
-            if dense:
-                # Dense block-major payload: the full chunk stream once,
-                # plus each key's (m,) GROUP BY codes / predicate mask —
-                # one batched-contraction launch for the whole stack.
-                key_gids, key_valids = [], []
-                gid_cache, mask_cache = {}, {}  # shared panes dedupe
-                for key in keys:
-                    where, group_by = key
+        def key_tags(columns, block_ids):
+            # Each key's (m,) predicate mask and GROUP BY codes (None
+            # where it has none); keys sharing a WHERE or GROUP BY share
+            # one array, which the dense tick dedupes panes by.
+            key_gids, key_valids = [], []
+            gid_cache, mask_cache = {}, {}
+            with D.stage_trace("draw.mask", timings):
+                for where, group_by in keys:
                     if where is None:
                         key_valids.append(None)
                     else:
@@ -1732,6 +1745,15 @@ class MultiQueryExecutor:
                             gid_cache[group_by] = self._group_ids(
                                 group_by, columns)[0]
                         key_gids.append(gid_cache[group_by])
+            return key_gids, key_valids
+
+        def run_chunk(chunk, columns, block_ids):
+            raw = self._measure_of(columns)
+            key_gids, key_valids = key_tags(columns, block_ids)
+            if dense:
+                # Dense block-major payload: the full chunk stream once,
+                # plus each key's (m,) GROUP BY codes / predicate mask —
+                # one batched-contraction launch for the whole stack.
                 stack.tick(self.params, mode=dev_mode,
                            geometry=mg.geometry, values=raw,
                            quotas=chunk.chunk_quotas,
@@ -1747,15 +1769,12 @@ class MultiQueryExecutor:
                 hhi, hlo = _sketch.value_limbs(raw)
             shifted = {}  # (shift, scale) -> prepared stream (shared)
             for k_i, key in enumerate(keys):
-                where, group_by = key
                 dst = dstores[key]
                 fkey = (dst.shift, dst.scale)
                 if fkey not in shifted:
                     shifted[fkey] = (raw + dst.shift) / dst.scale
                 values = shifted[fkey]
-                mask = self._zone_mask(where, columns, block_ids)
-                gids = (self._group_ids(group_by, columns)[0]
-                        if group_by is not None else None)
+                mask, gids = key_valids[k_i], key_gids[k_i]
                 # key_seg is the stack's cell-placement contract: a
                 # plain stacked offset on one device, the block-run
                 # shard map on a mesh.
@@ -1777,7 +1796,7 @@ class MultiQueryExecutor:
 
         pending = []
         for chunk, columns, block_ids in self._iter_row_chunks(
-                draw, rng, chunk_blocks):
+                draw, rng, chunk_blocks, timings):
             if not launch_async:
                 run_chunk(chunk, columns, block_ids)
                 continue
@@ -2104,66 +2123,64 @@ class MultiQueryExecutor:
         is dispatched but its stat-row readback only STARTS — the
         returned :class:`_StagedGroup` can be composed later, while the
         device still computes and the host stages the next group."""
-        t0 = time.perf_counter()
-        h0 = timings.get("h2d", 0.0) if timings is not None else 0.0
-        l0 = timings.get("launch", 0.0) if timings is not None else 0.0
-        target = self._target_quotas(mg, deadline_samples)
-        group_stores, key_aggs = prebuilt
-        # Device-resident serving: persistent stores on route="device"
-        # (one device) or "mesh" (cell axis sharded over every device)
-        # keep their moments as jax arrays between ticks; the whole tick
-        # is one fused launch per mode-group and the host reads only
-        # scalar answers / group stats.
-        device_resident = bool(persistent and route in ("device", "mesh"))
-        keys = dstores = stack = None
-        if device_resident:
-            keys, dstores, stack = self._device_group(mg, group_stores,
-                                                      route)
-        covered = persistent
-        if persistent:
-            union = np.zeros(len(self.block_sizes), dtype=np.int64)
-            for key, st in group_stores.items():
-                led = dstores[key] if device_resident else st
-                union = np.maximum(union, led.deficit(target))
-            draw = union
-            if budget_alloc is not None:
-                draw = _scale_quotas(union, int(budget_alloc))
-                # A budget-truncated pass leaves deficit on the table: its
-                # answers refine next tick, so they must not enter the
-                # subsumption answer cache (a weaker ask served from one
-                # would skip the top-up the uncached route still draws).
-                covered = int(draw.sum()) == int(union.sum())
-        else:
-            draw = target
-        new_samples = int(draw.sum())
-        pending = []
-        if device_resident:
-            if new_samples:
-                pending = self._draw_and_tick_device(
-                    stack, keys, dstores, draw, rng, mg, chunk_blocks,
-                    timings=timings, defer_stats=defer_stats,
-                    launch_async=defer_stats)
+        # "draw" is the host side of this stage: RNG draws, masks, stack
+        # lookup, deficit math.  Its self time excludes the nested pane
+        # upload, dispatch and blocking readback of the serial route;
+        # with async launches those run on the worker thread,
+        # CONCURRENTLY with this thread's draws, and are not subtracted
+        # — the stage sum exceeding the wall clock is the overlap.
+        with D.stage_trace("draw", timings):
+            target = self._target_quotas(mg, deadline_samples)
+            group_stores, key_aggs = prebuilt
+            # Device-resident serving: persistent stores on
+            # route="device" (one device) or "mesh" (cell axis sharded
+            # over every device) keep their moments as jax arrays
+            # between ticks; the whole tick is one fused launch per
+            # mode-group and the host reads only scalar answers / group
+            # stats.
+            device_resident = bool(persistent and route in ("device",
+                                                            "mesh"))
+            keys = dstores = stack = None
+            if device_resident:
+                with D.stage_trace("draw.stack", timings):
+                    keys, dstores, stack = self._device_group(
+                        mg, group_stores, route)
+            covered = persistent
+            if persistent:
+                union = np.zeros(len(self.block_sizes), dtype=np.int64)
+                for key, st in group_stores.items():
+                    led = dstores[key] if device_resident else st
+                    union = np.maximum(union, led.deficit(target))
+                draw = union
+                if budget_alloc is not None:
+                    draw = _scale_quotas(union, int(budget_alloc))
+                    # A budget-truncated pass leaves deficit on the
+                    # table: its answers refine next tick, so they must
+                    # not enter the subsumption answer cache (a weaker
+                    # ask served from one would skip the top-up the
+                    # uncached route still draws).
+                    covered = int(draw.sum()) == int(union.sum())
             else:
-                # Warm repeat: re-solve resident moments (served from the
-                # stats cache when nothing changed — zero transfers).
-                stack.tick(self.params, mode=self._device_mode(mg.mode),
-                           geometry=mg.geometry, timings=timings,
-                           defer_stats=defer_stats)
-        elif new_samples:
-            self._draw_and_ingest(group_stores, draw, rng,
-                                  chunk_blocks=chunk_blocks)
-        if timings is not None:
-            # "draw" is the host-side remainder of this stage: everything
-            # that is not a pane upload or a fused dispatch (RNG draws,
-            # pane building, deficit math).  With async launches the
-            # worker's h2d/launch clocks run CONCURRENTLY with this
-            # thread's draws, so they are not subtracted — the stage sum
-            # exceeding the wall clock is exactly the measured overlap.
-            spent = time.perf_counter() - t0
-            if not pending:
-                spent -= ((timings.get("h2d", 0.0) - h0)
-                          + (timings.get("launch", 0.0) - l0))
-            timings["draw"] = timings.get("draw", 0.0) + max(spent, 0.0)
+                draw = target
+            new_samples = int(draw.sum())
+            pending = []
+            if device_resident:
+                if new_samples:
+                    pending = self._draw_and_tick_device(
+                        stack, keys, dstores, draw, rng, mg, chunk_blocks,
+                        timings=timings, defer_stats=defer_stats,
+                        launch_async=defer_stats)
+                else:
+                    # Warm repeat: re-solve resident moments (served from
+                    # the stats cache when nothing changed — zero
+                    # transfers).
+                    stack.tick(self.params, mode=self._device_mode(mg.mode),
+                               geometry=mg.geometry, timings=timings,
+                               defer_stats=defer_stats)
+            elif new_samples:
+                self._draw_and_ingest(group_stores, draw, rng,
+                                      chunk_blocks=chunk_blocks,
+                                      timings=timings)
         sg = _StagedGroup()
         sg.plan, sg.mg, sg.pass_id, sg.rng = plan, mg, pass_id, rng
         sg.route, sg.deadline_samples = route, deadline_samples
@@ -2204,14 +2221,10 @@ class MultiQueryExecutor:
             # Drain the group's async launches before anything reads (or
             # stales) its stores: the wait is the pipeline's exposed
             # device time, booked where the serial route exposed it.
-            t_w = time.perf_counter()
-            for f in sg.pending:
-                f.result()
+            with D.stage_trace("readback", sg.timings):
+                for f in sg.pending:
+                    f.result()
             sg.pending = []
-            if sg.timings is not None:
-                sg.timings["readback"] = (
-                    sg.timings.get("readback", 0.0)
-                    + time.perf_counter() - t_w)
         if self._group_stale(sg):
             # A drift reset dropped one of this group's keys after its
             # launch was staged.  The reset key's store went cold, so the
@@ -2230,56 +2243,51 @@ class MultiQueryExecutor:
         device_resident, dstores = sg.device_resident, sg.dstores
         covered, new_samples = sg.covered, sg.new_samples
         default_mode, timings = sg.default_mode, sg.timings
-        t0 = time.perf_counter()
-        r0 = timings.get("readback", 0.0) if timings is not None else 0.0
-        sp = None  # the plain pass is composed lazily: an all-relational
-        keyed = {}  # batch never pays for it
-        out = []
-        for i in mg.query_ids:
-            q = plan.queries[i]
-            key = _pass_key(q)
-            st = group_stores[key]
-            if key == (None, None) and q.agg != "count_distinct":
-                if sp is None:
-                    sp = (self._base_stats_device(plan, mg, dstores[key])
-                          if device_resident
-                          else self._base_stats(plan, mg, st, route))
-                ans = self._compose_plain(q, sp, mg, pass_id)
-            else:
-                if key not in keyed:
-                    need_distinct = "count_distinct" in key_aggs[key]
-                    keyed[key] = (
-                        self._keyed_stats_device(
-                            dstores[key], need_distinct=need_distinct)
-                        if device_resident
-                        else self._keyed_stats(
-                            plan, mg, st, route,
-                            need_mean=bool(key_aggs[key]
-                                           - {"COUNT", "count_distinct"}),
-                            need_distinct=need_distinct))
-                n_drawn = (dstores[key].total_sampled if device_resident
-                           else st.total_sampled)
-                shift_k = (dstores[key].shift if device_resident
-                           else st.shift)
-                ans = self._compose_keyed(
-                    q, keyed[key], mg, pass_id, shift_k, n_drawn)
-            ans.new_samples = new_samples
-            if covered and ans.error_bound is not None:
-                # Earned + fully-covered: eligible to serve dominated
-                # (weaker-(e, beta)) asks with zero new samples until the
-                # store's ledger moves.
-                stamp = (dstores[key].total_sampled if device_resident
-                         else st.total_sampled)
-                self._cache_answer(
-                    q, ans, StoreKey(where=key[0], group_by=key[1],
-                                     mode=mg.mode), stamp, default_mode)
-            out.append((i, ans))
-        if timings is not None:
-            # The blocking d2h a lazy row resolved during compose is
-            # already booked under "readback"; keep compose pure.
-            rb = timings.get("readback", 0.0) - r0
-            timings["compose"] = (timings.get("compose", 0.0)
-                                  + (time.perf_counter() - t0) - rb)
+        # The blocking d2h of a lazy row resolved here is a nested
+        # readback stage, so compose's self time stays pure.
+        with D.stage_trace("compose", timings):
+            sp = None  # the plain pass is composed lazily: an all-relational
+            keyed = {}  # batch never pays for it
+            out = []
+            for i in mg.query_ids:
+                q = plan.queries[i]
+                key = _pass_key(q)
+                st = group_stores[key]
+                if key == (None, None) and q.agg != "count_distinct":
+                    if sp is None:
+                        sp = (self._base_stats_device(plan, mg, dstores[key])
+                              if device_resident
+                              else self._base_stats(plan, mg, st, route))
+                    ans = self._compose_plain(q, sp, mg, pass_id)
+                else:
+                    if key not in keyed:
+                        need_distinct = "count_distinct" in key_aggs[key]
+                        keyed[key] = (
+                            self._keyed_stats_device(
+                                dstores[key], need_distinct=need_distinct)
+                            if device_resident
+                            else self._keyed_stats(
+                                plan, mg, st, route,
+                                need_mean=bool(key_aggs[key]
+                                               - {"COUNT", "count_distinct"}),
+                                need_distinct=need_distinct))
+                    n_drawn = (dstores[key].total_sampled if device_resident
+                               else st.total_sampled)
+                    shift_k = (dstores[key].shift if device_resident
+                               else st.shift)
+                    ans = self._compose_keyed(
+                        q, keyed[key], mg, pass_id, shift_k, n_drawn)
+                ans.new_samples = new_samples
+                if covered and ans.error_bound is not None:
+                    # Earned + fully-covered: eligible to serve dominated
+                    # (weaker-(e, beta)) asks with zero new samples until the
+                    # store's ledger moves.
+                    stamp = (dstores[key].total_sampled if device_resident
+                             else st.total_sampled)
+                    self._cache_answer(
+                        q, ans, StoreKey(where=key[0], group_by=key[1],
+                                         mode=mg.mode), stamp, default_mode)
+                out.append((i, ans))
         return out
 
     def _execute_group(self, plan: QueryPlan, mg: ModeGroup, pass_id: int,
@@ -2447,7 +2455,7 @@ class MultiQueryExecutor:
             RNG draw order and per-cell merge order are UNCHANGED (only
             *when* each stage executes moves; compose consumes no RNG),
             so answers are bit-identical (x64) to the serial route.
-            Per-stage wall times land in ``last_stage_times``.
+            Per-stage seconds land in ``last_stage_times``.
 
         Returns
         -------
@@ -2481,8 +2489,7 @@ class MultiQueryExecutor:
         exactly the ``"device"`` path.
         """
         self._run_epoch += 1  # store ledgers may move: lookups re-validate
-        times = self.last_stage_times = dict.fromkeys(_STAGES, 0.0)
-        t_plan = time.perf_counter()
+        times = self.last_stage_times = D.StageClock(_STAGES, _COUNTERS)
         if budget is not None and not incremental:
             raise ValueError(
                 "budget caps the incremental deficit top-up; without "
@@ -2496,38 +2503,38 @@ class MultiQueryExecutor:
             raise ValueError(
                 "drift_check probes the frozen incremental anchor; it "
                 "requires incremental=True")
-        if incremental and drift_check is not None \
-                and self._anchor is not None:
-            z = 6.0 if drift_check is True else float(drift_check)
-            probe = self._draw_probe(rng)
-            if self.check_drift(rng, z_thresh=z, probe_columns=probe):
-                self.reset_stores()
+        with D.stage_trace("plan", times):
+            if incremental and drift_check is not None \
+                    and self._anchor is not None:
+                z = 6.0 if drift_check is True else float(drift_check)
+                probe = self._draw_probe(rng)
+                if self.check_drift(rng, z_thresh=z, probe_columns=probe):
+                    self.reset_stores()
+                else:
+                    # Global anchor still holds: check each warm REFINED key
+                    # against its own anchor; a drifted predicate resets (and
+                    # re-anchors) only itself.
+                    for skey in self.drifted_keys(probe, z_thresh=z):
+                        self._reset_key(skey, probe_columns=probe)
+            if incremental and self._anchor is not None:
+                # Warm path: planning consumes no RNG against the frozen
+                # pilot, so a PlanCache hit and a fresh plan are stream-
+                # identical — a steady-state tick does zero Python planning.
+                plan = self._plan_cached(queries, rng, mode, route,
+                                         rate_override, sigma_guess)
             else:
-                # Global anchor still holds: check each warm REFINED key
-                # against its own anchor; a drifted predicate resets (and
-                # re-anchors) only itself.
-                for skey in self.drifted_keys(probe, z_thresh=z):
-                    self._reset_key(skey, probe_columns=probe)
-        if incremental and self._anchor is not None:
-            # Warm path: planning consumes no RNG against the frozen
-            # pilot, so a PlanCache hit and a fresh plan are stream-
-            # identical — a steady-state tick does zero Python planning.
-            plan = self._plan_cached(queries, rng, mode, route,
-                                     rate_override, sigma_guess)
-        else:
-            plan = self.plan(queries, rng, mode=mode, route=route,
-                             rate_override=rate_override,
-                             sigma_guess=sigma_guess)
-            if incremental:
-                self._anchor = (plan.pilot, plan.pilot_columns)
-        stores = self._stores if incremental else None
-        mg_stores = [self._group_stores(plan, mg, stores)
-                     for mg in plan.mode_groups]
-        alloc = (self._budget_allocations(plan, list(queries),
-                                          deadline_samples, budget,
-                                          mg_stores, budget_floor)
-                 if incremental else {})
-        times["plan"] = time.perf_counter() - t_plan
+                plan = self.plan(queries, rng, mode=mode, route=route,
+                                 rate_override=rate_override,
+                                 sigma_guess=sigma_guess)
+                if incremental:
+                    self._anchor = (plan.pilot, plan.pilot_columns)
+            stores = self._stores if incremental else None
+            mg_stores = [self._group_stores(plan, mg, stores)
+                         for mg in plan.mode_groups]
+            alloc = (self._budget_allocations(plan, list(queries),
+                                              deadline_samples, budget,
+                                              mg_stores, budget_floor)
+                     if incremental else {})
         answers = [None] * len(queries)
 
         def _collect(results):

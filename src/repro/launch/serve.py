@@ -233,9 +233,12 @@ class IslaAdmissionLoop:
         self.samples_drawn = 0  # cumulative NEW samples across ticks
         self.deduped = 0        # tickets fanned out from an exact duplicate
         self.subsumed = 0       # tickets served from the answer cache
-        # Per-stage wall seconds (plan, draw, h2d, launch, readback,
-        # compose), accumulated over every executed tick's run().
+        # Per-stage seconds: the loop's own ``admit`` (the self time of
+        # every tick) plus each executed tick's run() stage clock; and
+        # the run's counters (pane slots and bytes uploaded).
         self.stage_seconds: "dict[str, float]" = {}
+        self.pane_slots = 0
+        self.h2d_bytes = 0
 
     def submit(self, query) -> int:
         """Admit one query; returns its ticket id."""
@@ -271,6 +274,8 @@ class IslaAdmissionLoop:
             "plan_cache_evictions": getattr(ex, "plan_cache_evictions", 0),
             "answers_cached": getattr(ex, "answers_cached", 0),
             "plans_prefetched": getattr(ex, "plans_prefetched", 0),
+            "pane_slots": self.pane_slots,
+            "h2d_bytes": self.h2d_bytes,
             "stage_seconds": dict(self.stage_seconds),
         }
 
@@ -308,7 +313,19 @@ class IslaAdmissionLoop:
 
     def tick(self) -> "list[IslaTicket]":
         """Serve one admission round; returns the tickets COMPLETED now
-        (progressive tickets may stay in flight across ticks)."""
+        (progressive tickets may stay in flight across ticks).  The round
+        is the ``isla:tick`` span; its self time (the executor's stages
+        taken out) is booked as the ``admit`` stage."""
+        from repro.core.distributed import stage_trace
+
+        clock = {}
+        with stage_trace("tick", clock, key="admit"):
+            done = self._serve_round()
+        self.stage_seconds["admit"] = (self.stage_seconds.get("admit", 0.0)
+                                       + clock["admit"])
+        return done
+
+    def _serve_round(self) -> "list[IslaTicket]":
         self._tick += 1
         tickets = list(self._inflight)
         self._inflight = []
@@ -369,9 +386,12 @@ class IslaAdmissionLoop:
                 drift_check=self.drift_check,
                 budget_floor=self.budget_floor,
                 pipeline=self.pipeline)
-            for k, v in getattr(self.executor, "last_stage_times",
-                                {}).items():
+            times = getattr(self.executor, "last_stage_times", {})
+            for k, v in times.items():
                 self.stage_seconds[k] = self.stage_seconds.get(k, 0.0) + v
+            counts = getattr(times, "counts", {})
+            self.pane_slots += counts.get("pane_slots", 0)
+            self.h2d_bytes += counts.get("h2d_bytes", 0)
             seen_passes = set()
             for t, a in zip(execute, answers):
                 if a.new_samples is not None \
