@@ -221,6 +221,33 @@ def h2d(x, dtype=None) -> jnp.ndarray:
     return out
 
 
+@functools.partial(jax.jit, static_argnums=(1, 2, 3))
+def cold_store(consts: jnp.ndarray, n_cells: int, n_blocks: int,
+               has_sketch: bool):
+    """A cold device store's resident state and anchor constants, built
+    by ONE launch instead of an eager device op (or upload) apiece.
+
+    ``consts`` is the store's six anchor constants, already in its dtype:
+    the four cuts of ``Boundaries.as_tuple``, ``sketch0`` and 1, each
+    divided by the store's scale.  Returns ``(state, consts_out)``:
+    ``state`` is the zero ``(mom_s, mom_l, totals, n_sampled, regs)``
+    (``regs`` the uint8 HLL plane, None without a sketch plane), fresh
+    buffers the first fused tick donates; ``consts_out`` is ``(bounds,
+    bound_row, sketch0, sketch0_cells, inv_scale_cells)`` — the (4,) cuts
+    and their (1, 4) row, the scalar sketch and its per-cell vector, and
+    the per-cell inverse scale, which no launch donates."""
+    from . import sketch as SK
+
+    dt = consts.dtype
+    regs = jnp.zeros((n_cells, SK.M), jnp.uint8) if has_sketch else None
+    state = (jnp.zeros((n_cells, 4), dt), jnp.zeros((n_cells, 4), dt),
+             jnp.zeros((n_cells, 3), dt), jnp.zeros((n_blocks,), dt), regs)
+    bounds = consts[:4]
+    return state, (bounds, bounds.reshape(1, 4), consts[4],
+                   jnp.broadcast_to(consts[4], (n_cells,)),
+                   jnp.broadcast_to(consts[5], (n_cells,)))
+
+
 def d2h_async(x):
     """Launch/readback decoupling: start the device->host copy of ``x``
     (the tick's O(groups) stat rows) WITHOUT blocking, and return ``x``.

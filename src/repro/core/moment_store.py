@@ -628,14 +628,16 @@ class DeviceMomentStore:
                  sketch0: float, shift: float, scale: float,
                  block_sizes: Sequence[int], dtype,
                  anchor: Optional[Anchor] = None,
-                 has_sketch: bool = False) -> None:
-        import jax.numpy as jnp
-
+                 has_sketch: bool = False, sizes=None) -> None:
         from . import distributed as D
 
         if len(block_sizes) != n_blocks:
             raise ValueError(f"need {n_blocks} block sizes, got "
                              f"{len(block_sizes)}")
+        if sizes is not None and (sizes.shape != (n_blocks,)
+                                  or sizes.dtype != dtype):
+            raise ValueError(f"shared block sizes must be ({n_blocks},) "
+                             f"{dtype}, got {sizes.shape} {sizes.dtype}")
         self.n_blocks = int(n_blocks)
         self.n_groups = int(n_groups)
         self.boundaries = boundaries
@@ -651,26 +653,30 @@ class DeviceMomentStore:
         # store, after which the stacked tensors are authoritative and
         # these hold None (see the properties below).
         self._owner = None
-        self._mom_s = jnp.zeros((n_cells, 4), dtype)
-        self._mom_l = jnp.zeros((n_cells, 4), dtype)
-        self._totals = jnp.zeros((n_cells, 3), dtype)
-        self._ns_dev = jnp.zeros((self.n_blocks,), dtype)
-        # Sketch plane: resident uint8 HLL registers, same ownership
-        # dance as the moments (the plane is NOT scaled — registers are
-        # rank integers, identical across dtypes and routes).
-        self._regs = (jnp.zeros((n_cells, _sketch.M), jnp.uint8)
-                      if self.has_sketch else None)
+        # The zero state and the anchor constants come from one launch
+        # over one upload of six numbers (cold start — the steady-state
+        # tick never re-ships them).  The host casts the float64
+        # constants to the store's dtype, rounding as an on-device
+        # convert would.  Sketch plane: resident uint8 HLL registers,
+        # same ownership dance as the moments (the plane is NOT scaled —
+        # registers are rank integers, identical across dtypes and
+        # routes).
+        consts = (np.asarray(boundaries.as_tuple() + (self.sketch0, 1.0),
+                             dtype=np.float64) / self.scale).astype(dtype)
+        state, (self._bounds, self._bound_row, self._sketch0_dev,
+                self._sk_cells, self._inv_scale) = D.cold_store(
+            D.h2d(consts, dtype), n_cells, self.n_blocks, self.has_sketch)
+        (self._mom_s, self._mom_l, self._totals, self._ns_dev,
+         self._regs) = state
+        D.count("store_builds", 1)
         self._group_regs = None  # last launch's folded (n_groups, M) rows
         self.n_sampled = np.zeros(self.n_blocks, dtype=np.int64)
         self.rounds = 0
-        # Anchor constants, uploaded once at store creation (cold start —
-        # the steady-state tick never re-ships them).
-        self._bounds = D.h2d(
-            np.asarray(boundaries.as_tuple(), dtype=np.float64)
-            / self.scale, dtype)
-        self._sizes = D.h2d(np.asarray(self.block_sizes, dtype=np.float64),
-                            dtype)
-        self._sketch0_dev = D.h2d(self.sketch0 / self.scale, dtype)
+        # Block sizes: the caller's shared device copy (one per executor
+        # and dtype, never donated) or an upload of this store's own.
+        self._sizes = (sizes if sizes is not None else
+                       D.h2d(np.asarray(self.block_sizes, dtype=np.float64),
+                             dtype))
         # Per-tick stats cache (invalidated by any state change; keyed by
         # the solve configuration so a different mode re-solves).
         self._partials = None   # (n_cells,) device, scaled shifted units
@@ -780,20 +786,31 @@ class DeviceMomentStore:
                    abs(float(sketch0)), 1e-12)
 
     @staticmethod
+    def canonical_dtype(dtype=None):
+        """The dtype the backend will ACTUALLY allocate for ``dtype``
+        (None: ``default_dtype``): a float64 request without
+        jax_enable_x64 silently gives fp32, and the scale / bit-exactness
+        / headroom contracts must follow the real dtype, not the
+        requested one.  Allocates nothing on the device."""
+        import jax
+        if dtype is None:
+            dtype = DeviceMomentStore.default_dtype()
+        return jax.dtypes.canonicalize_dtype(dtype)
+
+    @staticmethod
     def fresh_device(n_blocks: int, boundaries: Boundaries, sketch0: float,
                      block_sizes: Sequence[int], shift: float = 0.0,
                      n_groups: int = 1, scale: Optional[float] = None,
                      dtype=None,
                      anchor: Optional[Anchor] = None,
-                     has_sketch: bool = False) -> "DeviceMomentStore":
+                     has_sketch: bool = False,
+                     sizes=None) -> "DeviceMomentStore":
+        """A cold store: zero state, this anchor's constants.  ``sizes``
+        is an optional shared device copy of ``block_sizes`` in the
+        store's dtype (``_sizes`` of an earlier store), which the store
+        then uses instead of uploading its own."""
         import jax.numpy as jnp
-        if dtype is None:
-            dtype = DeviceMomentStore.default_dtype()
-        # Canonicalize to what the backend will ACTUALLY allocate: a
-        # float64 request without jax_enable_x64 silently gives fp32, and
-        # the scale / bit-exactness / headroom contracts must follow the
-        # real dtype, not the requested one.
-        dtype = jnp.empty((0,), dtype).dtype
+        dtype = DeviceMomentStore.canonical_dtype(dtype)
         if scale is None:
             scale = (1.0 if dtype == jnp.float64
                      else DeviceMomentStore.anchor_scale(boundaries,
@@ -801,7 +818,7 @@ class DeviceMomentStore:
         return DeviceMomentStore(n_blocks, n_groups, boundaries,
                                  float(sketch0), float(shift), float(scale),
                                  block_sizes, dtype, anchor=anchor,
-                                 has_sketch=has_sketch)
+                                 has_sketch=has_sketch, sizes=sizes)
 
     @staticmethod
     def from_host(store: MomentStore, block_sizes: Sequence[int],
@@ -1055,8 +1072,7 @@ class DeviceStack:
             and st.scale == first.scale for st in self.stores)
         if self._uniform:
             # One (1, 4) bounds row — fused_tick broadcasts it.
-            self._bounds = first._bounds.reshape(1, 4)
-            self._bound_rows = first._bounds.reshape(1, 4)
+            self._bounds = self._bound_rows = first._bound_row
             self._bound_slots = (0,) * len(self.stores)
         else:
             # Tagged layout: per-cell cuts (+1 inert pad row for the
@@ -1079,16 +1095,19 @@ class DeviceStack:
             self._bound_slots = tuple(slots)
         # Per-cell inverse anchor scale: pre-scales the Phase 2 stopping
         # threshold (and the ISLA-E b0) into each cell's normalized frame.
-        self._inv_scale = D.h2d(np.concatenate(
-            [np.full(st.n_cells, 1.0 / st.scale) for st in self.stores]),
-            self.dtype)
+        # A one-store stack adopts its store's per-cell constants as built.
+        self._inv_scale = (first._inv_scale if len(self.stores) == 1 else
+                           D.h2d(np.concatenate(
+                               [np.full(st.n_cells, 1.0 / st.scale)
+                                for st in self.stores]), self.dtype))
         # Dense value affines: pane holds raw/ref values; key k recovers
         # its own frame as v * ratio_k + off_k inside the launch.
         self._ref_scale = max(st.scale for st in self.stores)
         self._key_affine = tuple(
             (self._ref_scale / st.scale, st.shift / st.scale)
             for st in self.stores)
-        self._sk_cells = None  # cached per-cell sketch vector (device)
+        # Per-cell sketch vector (device; built on first use when stacked).
+        self._sk_cells = first._sk_cells if len(self.stores) == 1 else None
         # Zone-map pruning: when a pruned plan zeroes whole blocks'
         # quotas, the dense tick launches over a COMPACTED active-block
         # axis (gather before the fused Phase 1+2, scatter the delta
@@ -1322,14 +1341,9 @@ class DeviceStack:
         # scalar h2d transfers.
         import jax.numpy as jnp
         if self._sk_cells is None:
-            if len(self.stores) == 1:
-                st = self.stores[0]
-                self._sk_cells = jnp.broadcast_to(st._sketch0_dev,
-                                                  (st.n_cells,))
-            else:
-                self._sk_cells = jnp.concatenate([
-                    jnp.broadcast_to(st._sketch0_dev, (st.n_cells,))
-                    for st in self.stores])
+            self._sk_cells = jnp.concatenate([
+                jnp.broadcast_to(st._sketch0_dev, (st.n_cells,))
+                for st in self.stores])
         return self._sk_cells
 
     # -- the tick ----------------------------------------------------------

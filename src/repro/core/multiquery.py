@@ -363,8 +363,9 @@ _STAGES = ("admit", "plan", "draw", "draw.stack", "draw.sample",
            "draw.mask", "h2d", "h2d.pane", "h2d.put", "launch", "readback",
            "compose")
 # Counters a run books beside its stage clock (``last_stage_times.
-# counts``): value-pane slots uploaded, and bytes handed to the uploads.
-_COUNTERS = ("pane_slots", "h2d_bytes")
+# counts``): value-pane slots uploaded, bytes handed to the uploads, and
+# device stores built (one launch each).
+_COUNTERS = ("pane_slots", "h2d_bytes", "store_builds")
 
 
 class _StagedGroup:
@@ -458,6 +459,9 @@ class MultiQueryExecutor:
         # them per mode-group.
         self._device_stores: "dict[StoreKey, DeviceMomentStore]" = {}
         self._device_stacks: dict = {}
+        self._stack_keys: dict = {}  # cached stack -> its cache key
+        self._device_sizes: dict = {}  # dtype -> block sizes every store
+        #                                shares (a constant, never donated)
         # route="mesh": the jax mesh the stacked cell axis shards over.
         # None auto-builds a 1-D mesh over every visible device on first
         # use (jax import deferred — a host-route executor never pays it).
@@ -499,6 +503,7 @@ class MultiQueryExecutor:
         self._key_anchors.clear()
         self._device_stores.clear()
         self._device_stacks.clear()
+        self._stack_keys.clear()
         self.plan_cache_evictions += len(self._plan_cache)
         self._plan_cache.clear()
         self._answer_cache.clear()
@@ -623,7 +628,7 @@ class MultiQueryExecutor:
         (self._stores if stores is None else stores).pop(skey, None)
         dst = self._device_stores.pop(skey, None)
         if dst is not None and dst._owner is not None:
-            dst._owner.release()
+            self._drop_stack(dst._owner)
         self._sigma_cache.pop((skey.group_by, skey.where), None)
         self._evict_where(skey.where)
 
@@ -1634,7 +1639,7 @@ class MultiQueryExecutor:
             # release it from its stack (survivors keep their state) and
             # rebuild from the fresh host store.
             if dst._owner is not None:
-                dst._owner.release()
+                self._drop_stack(dst._owner)
             self._device_stores.pop(skey, None)
             dst = None
         if dst is not None and dst.has_sketch != host_store.has_sketch:
@@ -1642,7 +1647,7 @@ class MultiQueryExecutor:
             # _group_stores rebuilt the host store cold): the old mirror
             # has no register history to keep — rebuild to match.
             if dst._owner is not None:
-                dst._owner.release()
+                self._drop_stack(dst._owner)
             self._device_stores.pop(skey, None)
             dst = None
         if dst is None:
@@ -1652,15 +1657,26 @@ class MultiQueryExecutor:
                 dst = DeviceMomentStore.from_host(host_store,
                                                   self.block_sizes)
             else:
+                dtype = DeviceMomentStore.canonical_dtype()
                 dst = DeviceMomentStore.fresh_device(
                     host_store.n_blocks, host_store.boundaries,
                     host_store.sketch0, self.block_sizes,
                     shift=host_store.shift,
-                    n_groups=host_store.n_groups,
+                    n_groups=host_store.n_groups, dtype=dtype,
                     anchor=host_store.anchor,
-                    has_sketch=host_store.has_sketch)
+                    has_sketch=host_store.has_sketch,
+                    sizes=self._device_sizes.get(dtype))
+                self._device_sizes.setdefault(dtype, dst._sizes)
             self._device_stores[skey] = dst
         return dst
+
+    def _drop_stack(self, stack: DeviceStack) -> None:
+        """Release ``stack`` (its members own their state again) and
+        take it out of the stack cache."""
+        stack.release()
+        ck = self._stack_keys.pop(stack, None)
+        if ck is not None:
+            del self._device_stacks[ck]
 
     def _device_group(self, mg: ModeGroup, group_stores: Mapping,
                       route: str = "device"
@@ -1682,14 +1698,20 @@ class MultiQueryExecutor:
                 or [id(s) for s in stack.stores]
                 != [id(dstores[k]) for k in keys]):
             members = [dstores[k] for k in keys]
+            # The stacks this build replaces: the one cached under ``ck``
+            # and every live stack the adoption releases (a member's
+            # owner).  Exactly those leave the cache (a key-set change
+            # must not keep dead stacks; a scan of every cached stack
+            # would grow with the key count).
+            gone = [st._owner for st in members if st._owner is not None]
+            if stack is not None:
+                gone.append(stack)
+            for old in gone:
+                self._drop_stack(old)
             stack = (MeshDeviceStack(members, self._active_mesh())
                      if route == "mesh" else DeviceStack(members))
-            # Evict entries the adoption released (a key-set change must
-            # not pin dead stacked-state copies in device memory).
-            self._device_stacks = {
-                k: s for k, s in self._device_stacks.items()
-                if not s._released}
             self._device_stacks[ck] = stack
+            self._stack_keys[stack] = ck
         return keys, dstores, stack
 
     def _draw_and_tick_device(self, stack: DeviceStack, keys: list,

@@ -235,10 +235,12 @@ class IslaAdmissionLoop:
         self.subsumed = 0       # tickets served from the answer cache
         # Per-stage seconds: the loop's own ``admit`` (the self time of
         # every tick) plus each executed tick's run() stage clock; and
-        # the run's counters (pane slots and bytes uploaded).
+        # the run's counters (pane slots and bytes uploaded, device
+        # stores built).
         self.stage_seconds: "dict[str, float]" = {}
         self.pane_slots = 0
         self.h2d_bytes = 0
+        self.store_builds = 0
 
     def submit(self, query) -> int:
         """Admit one query; returns its ticket id."""
@@ -276,6 +278,7 @@ class IslaAdmissionLoop:
             "plans_prefetched": getattr(ex, "plans_prefetched", 0),
             "pane_slots": self.pane_slots,
             "h2d_bytes": self.h2d_bytes,
+            "store_builds": self.store_builds,
             "stage_seconds": dict(self.stage_seconds),
         }
 
@@ -392,6 +395,7 @@ class IslaAdmissionLoop:
             counts = getattr(times, "counts", {})
             self.pane_slots += counts.get("pane_slots", 0)
             self.h2d_bytes += counts.get("h2d_bytes", 0)
+            self.store_builds += counts.get("store_builds", 0)
             seen_passes = set()
             for t, a in zip(execute, answers):
                 if a.new_samples is not None \

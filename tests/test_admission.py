@@ -249,6 +249,29 @@ def test_loop_stats_expose_admission_counters():
         assert key in s
 
 
+@pytest.mark.parametrize("route", ["device", "mesh"])
+def test_store_builds_count_cold_keys_only(route):
+    """One device store is built per cold key served; none for a warm
+    repeat on a known key, nor for an answer served from the cache."""
+    loop = _loop(incremental=True, route=route)
+    q = IslaQuery(e=0.5, agg="AVG", where=FLAG1)
+
+    def tick(query):
+        before = loop.stats["store_builds"]
+        loop.submit(query)
+        (done,) = loop.tick()
+        return done.answer, loop.stats["store_builds"] - before
+
+    answer, built = tick(q)
+    assert built == 1 and answer.served is None
+    answer, built = tick(dataclasses.replace(q, e=1.0, beta=0.90))
+    assert built == 0 and answer.served == "subsumed"
+    answer, built = tick(dataclasses.replace(q, e=0.3))
+    assert built == 0 and answer.served is None and answer.new_samples > 0
+    answer, built = tick(IslaQuery(e=0.5, agg="AVG", where=DAY0))
+    assert built == 1 and answer.served is None
+
+
 def test_admission_off_is_fifo():
     """``admission=False`` (and any non-incremental loop) is the plain
     FIFO route: no dedupe, no subsumption, every query executes."""

@@ -197,6 +197,138 @@ def test_stack_release_and_regroup(rng):
     assert stack2.stores[0] is dev_a
 
 
+def _bits(a):
+    a = np.asarray(a)
+    return a.shape, a.dtype, a.tobytes()
+
+
+@pytest.mark.parametrize("x64", [False, True])
+@pytest.mark.parametrize("has_sketch", [False, True])
+@pytest.mark.parametrize("n_groups", [1, 4])
+def test_cold_store_build_matches_eager_construction(monkeypatch, n_groups,
+                                                     has_sketch, x64):
+    """A cold store and its one-store stack come from one launch over one
+    upload of the six anchor constants, and hold the same bits as the
+    eager construction (an op or upload per array): zeros, cuts and their
+    row, block sizes, the sketch scalar and vector, the inverse scale."""
+    import contextlib
+
+    import jax
+    import jax.numpy as jnp
+
+    from repro.core import distributed as D
+    from repro.core import sketch as SK
+
+    n_blocks = 7
+    sizes = [122880 + 17 * b for b in range(n_blocks)]
+    bounds = make_boundaries(MU + 0.37, SIGMA + 0.11, PARAMS)
+    sketch0 = MU + 0.731
+    with jax.enable_x64() if x64 else contextlib.nullcontext():
+        first = DeviceMomentStore.fresh_device(
+            n_blocks, bounds, sketch0, sizes, n_groups=n_groups,
+            has_sketch=has_sketch)
+        uploads = []
+        real = D.h2d
+
+        def h2d(x, dtype=None):
+            uploads.append(np.asarray(x))
+            return real(x, dtype)
+        monkeypatch.setattr(D, "h2d", h2d)
+        st = DeviceMomentStore.fresh_device(
+            n_blocks, bounds, sketch0, sizes, n_groups=n_groups,
+            has_sketch=has_sketch, sizes=first._sizes)
+        monkeypatch.undo()
+        dtype = st.dtype
+        assert dtype == (jnp.float64 if x64 else jnp.float32)
+        assert (st.scale == 1.0) == x64
+        # One upload: the six constants, cast on the host.
+        packed = (np.asarray(bounds.as_tuple() + (sketch0, 1.0))
+                  / st.scale).astype(dtype)
+        assert len(uploads) == 1
+        assert _bits(uploads[0]) == _bits(packed)
+
+        n_cells = n_groups * n_blocks
+        ref_bounds = jnp.asarray(np.asarray(bounds.as_tuple()) / st.scale,
+                                 dtype)
+        ref_sketch0 = jnp.asarray(sketch0 / st.scale, dtype)
+        ref = {
+            "_mom_s": jnp.zeros((n_cells, 4), dtype),
+            "_mom_l": jnp.zeros((n_cells, 4), dtype),
+            "_totals": jnp.zeros((n_cells, 3), dtype),
+            "_ns_dev": jnp.zeros((n_blocks,), dtype),
+            "_bounds": ref_bounds,
+            "_bound_row": ref_bounds.reshape(1, 4),
+            "_sizes": jnp.asarray(np.asarray(sizes, dtype=np.float64),
+                                  dtype),
+            "_sketch0_dev": ref_sketch0,
+            "_sk_cells": jnp.broadcast_to(ref_sketch0, (n_cells,)),
+            "_inv_scale": jnp.asarray(np.full(n_cells, 1.0 / st.scale),
+                                      dtype),
+        }
+        for name, want in ref.items():
+            assert _bits(getattr(st, name)) == _bits(want), name
+        assert st._sizes is first._sizes
+        ref_regs = jnp.zeros((n_cells, SK.M), jnp.uint8)
+        if has_sketch:
+            assert _bits(st._regs) == _bits(ref_regs)
+        else:
+            assert st._regs is None
+
+        # The one-store stack adopts all of it and uploads nothing.
+        uploads.clear()
+        monkeypatch.setattr(D, "h2d", h2d)
+        stack = DeviceStack([st])
+        monkeypatch.undo()
+        assert uploads == []
+        for got, name in zip(stack._state,
+                             ("_mom_s", "_mom_l", "_totals", "_ns_dev")):
+            assert _bits(got) == _bits(ref[name]), name
+        assert _bits(stack._bounds) == _bits(ref["_bound_row"])
+        assert _bits(stack._bound_rows) == _bits(ref["_bound_row"])
+        assert _bits(stack._sizes) == _bits(ref["_sizes"])
+        assert _bits(stack._sketch0_cells()) == _bits(ref["_sk_cells"])
+        assert _bits(stack._inv_scale) == _bits(ref["_inv_scale"])
+        if has_sketch:
+            assert _bits(stack._regs_state) == _bits(ref_regs)
+
+
+def test_device_group_evicts_only_released_stacks():
+    """The executor's stack cache: a cold key's build releases no stack,
+    so every cached stack stays live; a batch that stacks a warm key
+    with a new one releases exactly the warm key's old stack, which
+    leaves the cache with its state freed, while an unrelated stack
+    stays."""
+    rng = np.random.default_rng(0)
+    tables = [{"value": rng.normal(MU, SIGMA, 3000),
+               "flag": rng.integers(0, 2, 3000).astype(np.float64)}
+              for _ in range(4)]
+    ex = MultiQueryExecutor([table_sampler(t) for t in tables],
+                            [10 ** 6] * 4, params=IslaParams(e=1.0))
+    q_a = IslaQuery(e=1.0, agg="AVG", where=Predicate(column="flag",
+                                                      eq=1.0))
+    q_b = IslaQuery(e=1.0, agg="AVG", where=Predicate(column="flag",
+                                                      eq=0.0))
+    q_c = IslaQuery(e=1.0, agg="AVG")
+
+    def run(*queries):
+        ex.run(list(queries), rng, incremental=True, route="device")
+        return list(ex._device_stacks.values())
+
+    (stack_a,) = run(q_a)
+    stacks = run(q_b)
+    assert len(stacks) == 2 and stack_a in stacks
+    assert not any(s._released for s in stacks)
+    (stack_b,) = [s for s in stacks if s is not stack_a]
+    stacks = run(q_a, q_c)
+    assert stack_a._released and stack_a._state is None
+    assert stack_a not in stacks
+    assert stack_b in stacks and not stack_b._released
+    assert len(stacks) == 2 and not any(s._released for s in stacks)
+    (stack_ac,) = [s for s in stacks if s is not stack_b]
+    assert stack_ac.stores[0] is stack_a.stores[0]
+    assert set(ex._stack_keys) == set(stacks)
+
+
 def test_multiquery_device_resident_matches_host(rng):
     """run(incremental=True, route='device'): answers match the host
     route within fp32 tolerance, identical draw ledgers, warm repeats

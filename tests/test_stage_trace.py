@@ -265,7 +265,7 @@ def test_upload_counters(route):
         assert slots >= drawn
         assert after["h2d_bytes"] - before["h2d_bytes"] >= 4 * slots
     counts = loop.executor.last_stage_times.counts
-    assert set(counts) == {"pane_slots", "h2d_bytes"}
+    assert set(counts) == {"pane_slots", "h2d_bytes", "store_builds"}
 
 
 def test_host_route_uploads_nothing():

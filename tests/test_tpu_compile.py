@@ -158,6 +158,16 @@ def test_fused_tick_dense_sketch_compiles_at_deployment_shape(one_chip):
     assert compiled.memory_analysis() is not None
 
 
+@pytest.mark.parametrize("has_sketch", [False, True])
+def test_cold_store_compiles_at_deployment_shape(one_chip, has_sketch):
+    """A cold key's store build, one launch: the zero state plus the
+    anchor constants, at the serving tier's 1000 blocks x 16 groups."""
+    consts = _spec((6,), one_chip)
+    compiled = D.cold_store.lower(consts, 16 * N_BLOCKS, N_BLOCKS,
+                                  has_sketch).compile()
+    assert compiled.memory_analysis() is not None
+
+
 def _mesh_dense_args(topo):
     """A 4-chip cell mesh and the dense mesh tick's sharded arguments."""
     from jax.sharding import Mesh, NamedSharding
