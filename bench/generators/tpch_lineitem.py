@@ -1,4 +1,5 @@
-"""TPC-H ``lineitem`` columns for the benchmark, generated from a seed.
+"""Generator ``tpch_lineitem``: TPC-H ``lineitem`` columns, generated from
+a seed.
 
 The rules are those of TPC-H v3 clause 4.2.3 (the ``dbgen`` population
 rules), vectorised with numpy:
@@ -27,50 +28,15 @@ configuration says ``"sort_by": "l_shipdate"``, sorted on the ship date.
 """
 from __future__ import annotations
 
-import dataclasses
-
 import numpy as np
+
+from isla_bench.deployment import Table, seed_rng
 
 ORDER_LAST_DAY = 2405       # 1998-08-02
 CURRENT_DAY = 1263          # 1995-06-17
-SHIP_DAYS = ORDER_LAST_DAY + 121 + 1   # day numbers 0..2526
 
 STREAM_DATA = 1
 DEFAULT_COLUMNS = ("l_extendedprice", "l_shipdate", "rf_ls")
-
-
-@dataclasses.dataclass
-class Table:
-    """A generated table cut into blocks of ``block_rows`` rows."""
-
-    columns: dict              # name -> (rows,) float64
-    block_rows: int
-
-    @property
-    def rows(self) -> int:
-        return int(next(iter(self.columns.values())).shape[0])
-
-    @property
-    def n_blocks(self) -> int:
-        return -(-self.rows // self.block_rows)
-
-    def block_slice(self, b: int) -> slice:
-        return slice(b * self.block_rows,
-                     min((b + 1) * self.block_rows, self.rows))
-
-    def blocks(self) -> list:
-        """Per-block column dicts (views, no copy)."""
-        return [{k: v[self.block_slice(b)] for k, v in self.columns.items()}
-                for b in range(self.n_blocks)]
-
-    def block_sizes(self) -> list:
-        return [self.block_slice(b).stop - self.block_slice(b).start
-                for b in range(self.n_blocks)]
-
-
-def seed_rng(seed: int, stream: int) -> np.random.Generator:
-    """A generator for one named stream of a run's seed (any integer)."""
-    return np.random.default_rng([int(seed) % (1 << 64), int(stream)])
 
 
 def _lines_per_order(rng: np.random.Generator, rows: int) -> np.ndarray:

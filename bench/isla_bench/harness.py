@@ -4,11 +4,13 @@
 
 The cell (``BENCHMARK.json``'s ``workloads``) names a configuration
 (``bench/configs/<config>.json``: data, precision, service options, check
-limits) and a traffic mix (``bench/traffic/<mix>.json``).  A run:
+limits) and a traffic mix (``bench/traffic/<mix>.json``).  The
+configuration's generator and the mix's query kind are files of their own,
+found by name (``deployment``).  A run:
 
 1. checks that JAX sees a TPU and as many chips as the cell asks for;
-2. generates the configuration's table from the seed, builds the exact
-   reference and the served path (``service.Service``);
+2. generates the configuration's table from the seed, builds the query
+   kind's exact reference and the served path (``service.Service``);
 3. warms up on queries of the cell's own mix (another stream of the same
    seed) until no new program compiles, and counts all that as set-up;
 4. drives the mix for ``--seconds`` seconds (a closed loop), then
@@ -26,9 +28,9 @@ from __future__ import annotations
 
 import argparse
 import dataclasses
+import functools
 import gc
 import heapq
-import importlib.util
 import itertools
 import json
 import math
@@ -41,11 +43,9 @@ from types import SimpleNamespace
 
 import numpy as np
 
-from . import reference, tpch, trace
+from . import deployment, reference, trace
 from .service import DrawLog, Service, answer_record
 from .traffic import Mix
-
-BENCH_DIR = Path(__file__).resolve().parents[1]
 
 STREAM_TRAFFIC = 3
 STREAM_WARMUP = 4
@@ -73,7 +73,7 @@ def load_cell(root: Path, workload: str) -> tuple:
     cell = cells[workload]
     configs = {c["name"]: c for c in bench["configs"]}
     cfg = json.loads((root / configs[cell["config"]]["file"]).read_text())
-    mix = json.loads((root / BENCH_DIR.name / "traffic"
+    mix = json.loads((root / "bench" / "traffic"
                       / f"{cell['traffic']}.json").read_text())
     return bench, cell, cfg, mix
 
@@ -86,12 +86,7 @@ def metric_entries(bench: dict, kind: str, workload: str) -> list:
 
 def load_reader(root: Path, name: str):
     """The ``read(ctx)`` function of ``bench/metrics/<name>.py``."""
-    path = root / BENCH_DIR.name / "metrics" / f"{name}.py"
-    spec = importlib.util.spec_from_file_location(
-        "bench_metric_" + name.replace(".", "_").replace("-", "_"), path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return deployment.load(root, "metrics", name).read
 
 
 # ---------------------------------------------------------------------------
@@ -220,14 +215,14 @@ class Clients:
                      for s in self._inflight.values())
         if record:
             self.log.active = []
-        before = self.svc.counters()["samples_drawn"]
+        before = self.svc.samples_drawn()
         if tracing:
             with jax.profiler.TraceAnnotation("bench:tick"):
                 done = self.svc.tick()
         else:
             done = self.svc.tick()
         t1 = time.perf_counter()
-        drawn = self.svc.counters()["samples_drawn"] - before
+        drawn = self.svc.samples_drawn() - before
         rows, self.log.active = self.log.active, None
         if self.log.first is not None:
             try:
@@ -332,7 +327,7 @@ def warm_up(svc: Service, mix: Mix, seed: int, log: DrawLog,
         state["seen"] = n
         return n >= qmax or (n >= qmin and state["since"] >= quiet)
 
-    rng = tpch.seed_rng(seed, STREAM_WARMUP)
+    rng = deployment.seed_rng(seed, STREAM_WARMUP)
     queries = mix.queries(rng)
     if spec.get("e_factors"):
         sweeps = [q for _ in range(int(spec.get("sweeps", 1)))
@@ -381,6 +376,10 @@ def metric_context(cl: Clients, cfg: dict, c0: dict, c1: dict,
     executed = sum(1 for t in cl.ticks if t.computed_keys)
 
     def delta(key):
+        """A counter's rise over the window; None where the program keeps
+        no such counter."""
+        if key not in c0 or key not in c1:
+            return None
         return c1[key] - c0[key]
 
     def stage_ms(name):
@@ -403,17 +402,20 @@ def metric_context(cl: Clients, cfg: dict, c0: dict, c1: dict,
 # Checks against the reference
 # ---------------------------------------------------------------------------
 
-def _match(spec, day: np.ndarray) -> np.ndarray:
-    m = np.ones(day.shape, bool)
-    if spec.lo is not None:
-        m &= day >= spec.lo
-    if spec.hi is not None:
-        m &= day < spec.hi
-    return m
+def _drawn_columns(rows: list, names: list) -> tuple:
+    """(block id per drawn row, column name -> values) of logged sampler
+    calls."""
+    bids = np.concatenate([np.zeros(0, np.int64)]
+                          + [np.full(len(r[names[0]]), b, np.int64)
+                             for b, r in rows])
+    cols = {k: np.concatenate([np.zeros(0)]
+                              + [np.asarray(r[k], np.float64)
+                                 for _, r in rows]) for k in names}
+    return bids, cols
 
 
-def check_answers(cl: Clients, cfg: dict, truth, block_sizes, pilot,
-                  control: bool = False) -> dict:
+def check_answers(cl: Clients, cfg: dict, kind, truth, names: list,
+                  block_sizes, pilot, control: bool = False) -> dict:
     """Every number compared, for the program's answers and, with
     ``control``, for the bfloat16 control put in the program's place.
 
@@ -422,17 +424,16 @@ def check_answers(cl: Clients, cfg: dict, truth, block_sizes, pilot,
     answered in the tick that drew for them: their counts, populations and
     plain moments (``sample_stats``) and, for AVG and SUM, the ISLA
     estimate (``calibrated_stats``, in the anchor the logged pilot rows
-    fix).  Every served answer is held to the answer it reuses."""
-    measure = cfg["measure"]
+    fix).  Every served answer is held to the answer it reuses.  The query
+    kind gives each spec's WHERE mask and the values aggregated; ``names``
+    are the table's columns."""
     est_cfg = cfg["estimator"]
-    day_col = cfg["data"]["day_column"]
-    grp_col = cfg["data"]["group_column"]
-    n_groups = int(cfg["group_domains"][grp_col])
+    values = functools.partial(kind.values, cfg)
     anchors = anchors_c = None
     if pilot is not None:
-        anchors = reference.Anchors(*pilot, measure, est_cfg)
+        anchors = reference.Anchors(*pilot, values, est_cfg)
         if control:
-            anchors_c = reference.Anchors(*pilot, measure, est_cfg,
+            anchors_c = reference.Anchors(*pilot, values, est_cfg,
                                           precision="bfloat16")
     flagged = [s for s in cl.sent if s.flagged and s.rows is not None
                and s.answer is not None]
@@ -443,22 +444,13 @@ def check_answers(cl: Clients, cfg: dict, truth, block_sizes, pilot,
     live_missed = unbacked = 0
     worst = None
     for s in flagged:
-        rows = s.rows
-
-        def column(name, dtype=np.float64):
-            return np.concatenate([np.zeros(0, dtype)]
-                                  + [np.asarray(r[name], dtype)
-                                     for _, r in rows])
-
-        bids = np.concatenate([np.zeros(0, np.int64)]
-                              + [np.full(len(r[measure]), b, np.int64)
-                                 for b, r in rows])
-        vals, day = column(measure), column(day_col)
-        grouped = s.spec.group_by is not None
-        gids = column(grp_col, np.int64) if grouped else None
-        match = _match(s.spec, day)
-        ng = n_groups if grouped else 1
         sp = s.spec
+        bids, cols = _drawn_columns(s.rows, names)
+        vals = values(cols)
+        match = kind.match(sp, cols)
+        grouped = sp.group_by is not None
+        gids = cols[sp.group_by].astype(np.int64) if grouped else None
+        ng = int(cfg["group_domains"][sp.group_by]) if grouped else 1
         st = reference.sample_stats(bids, vals, match, gids, ng, block_sizes)
         est = None
         if sp.agg in ("AVG", "SUM"):
@@ -468,13 +460,13 @@ def check_answers(cl: Clients, cfg: dict, truth, block_sizes, pilot,
             else:
                 est = reference.calibrated_stats(
                     bids, vals, match, gids, ng, block_sizes,
-                    anchors.for_where(sp.column, sp.lo, sp.hi), est_cfg)
+                    anchors.for_mask(kind.match(sp, anchors.rows)), est_cfg)
         want = reference.answer_fields(sp.agg, sp.beta, st, est)
         unbacked += reference.unbacked_bounds(sp.agg, sp.e, sp.beta, st,
                                               s.answer)
         got = {side: _answer_fields_of(s.answer) for side in gaps}
         if control and anchors_c is not None:
-            frame_c = anchors_c.for_where(sp.column, sp.lo, sp.hi)
+            frame_c = anchors_c.for_mask(kind.match(sp, anchors_c.rows))
             st_c = reference.sample_stats(bids, vals, match, gids, ng,
                                           block_sizes, precision="bfloat16")
             est_c = (reference.calibrated_stats(
@@ -493,19 +485,17 @@ def check_answers(cl: Clients, cfg: dict, truth, block_sizes, pilot,
                 if gap > g["moment_gap"]:
                     g["moment_gap"] = gap
                     if side == "program":
-                        worst = [sp.agg, k, st["n"], sp.hi - sp.lo]
+                        worst = [sp.agg, k, st["n"], sp.key]
             for k, r in want["ranges"].items():
                 gap = reference.range_gap(_num(f.get(k)), r)
                 if gap > g["estimate_gap"]:
                     g["estimate_gap"] = gap
                     if side == "program":
-                        worst = [sp.agg, k, st["n"], sp.hi - sp.lo]
-        if sp.column == day_col:
-            live = truth.live_blocks(
-                sp.lo if sp.lo is not None else 0,
-                sp.hi if sp.hi is not None else math.inf)
+                        worst = [sp.agg, k, st["n"], sp.key]
+        live = truth.live_blocks(sp)
+        if live is not None:
             live_missed += int(np.sum(st["drawn"][live] == 0))
-    misses, earned = bound_misses(cl, truth, day_col)
+    misses, earned = bound_misses(cl, truth)
     served = [s for s in cl.sent
               if s.answer is not None and s.answer["served"] is not None]
     return {"checked_answers": len(flagged), "gaps": gaps,
@@ -567,22 +557,20 @@ def served_gap(answer: dict, reused: dict | None) -> float:
                 gap = max(gap, reference.rel_gap(float(a[k]), float(r[k])))
     return gap
 
-def bound_misses(cl: Clients, truth, day_col: str) -> tuple:
+def bound_misses(cl: Clients, truth) -> tuple:
     """(rows outside their earned bound, rows with an earned bound) over
-    every answer of the window, against the exact answers."""
+    every answer of the window, against the query kind's exact answers."""
     misses = earned = 0
     for s in cl.sent:
         a = s.answer
-        if a is None or s.spec.column not in (None, day_col):
+        if a is None:
             continue
-        lo = s.spec.lo if s.spec.lo is not None else 0
-        hi = s.spec.hi if s.spec.hi is not None else math.inf
         rows = [(None, a)] + [(g["group"], g) for g in a["groups"] or []]
         for grp, r in rows:
             if r["bound"] is None:
                 continue
             earned += 1
-            exact = truth.answer(s.spec.agg, lo, hi, grp)
+            exact = truth.answer(s.spec, grp)
             if not (abs(r["value"] - exact)
                     <= r["bound"] + 1e-9 * abs(exact)):
                 misses += 1
@@ -610,17 +598,15 @@ def run_cell(root: Path, workload: str, seed: int, seconds: float,
         from .peaks import peaks
         peaks(kind)
     phases = {"start": time.perf_counter() - t_start}
-    table = tpch.generate(cfg["data"], seed)
+    generator = deployment.load(root, "generators", cfg["data"]["generator"])
+    mix = Mix(mix_spec, cfg["e"], cfg["beta"], root)
+    table = generator.generate(cfg["data"], seed)
     phases["data"] = time.perf_counter() - t_start
-    d = cfg["data"]
-    truth = reference.ExactTruth(table, cfg["measure"], d["day_column"],
-                                 d["group_column"],
-                                 cfg["group_domains"][d["group_column"]])
+    truth = mix.kind.truth(cfg, mix_spec["queries"], table)
     log = DrawLog()
-    svc = Service(cfg, table, seed, log)
+    svc = Service(cfg, table, seed, log, mix.kind.where)
     phases["service"] = time.perf_counter() - t_start
     block_sizes = svc.block_sizes
-    mix = Mix(mix_spec, cfg["e"], cfg["beta"])
     warm = warm_up(svc, mix, seed, log, mix_spec.get("warmup", {}))
     phases["warmup"] = time.perf_counter() - t_start
     compiles_setup = CompileWatch.count
@@ -628,11 +614,11 @@ def run_cell(root: Path, workload: str, seed: int, seconds: float,
     if check_share is not None:
         check_spec["share"] = check_share
     cl = Clients(svc, mix, log,
-                 check_rng=tpch.seed_rng(seed, STREAM_CHECK),
+                 check_rng=deployment.seed_rng(seed, STREAM_CHECK),
                  check_spec=check_spec)
     cl.seen_keys = set(warm["keys"])
     cl.computed = dict(warm["computed"])
-    queries = mix.queries(tpch.seed_rng(seed, STREAM_TRAFFIC))
+    queries = mix.queries(deployment.seed_rng(seed, STREAM_TRAFFIC))
     trace_dir = None
     if tracing:
         trace_dir = tempfile.mkdtemp(prefix="bench_trace_")
@@ -679,8 +665,8 @@ def run_cell(root: Path, workload: str, seed: int, seconds: float,
     svc.close()
     del svc
     gc.collect()
-    checks = check_answers(cl, cfg, truth, block_sizes, log.pilot,
-                           control=control)
+    checks = check_answers(cl, cfg, mix.kind, truth, list(table.columns),
+                           block_sizes, log.pilot, control=control)
     limits = cfg["checks"]
     earned, misses = checks["earned_rows"], checks["bound_misses"]
     beta = float(cfg["beta"])
@@ -774,7 +760,7 @@ def main(argv=None, root: Path | None = None, t_start: float | None = None,
          require_tpu: bool = True) -> int:
     t_start = time.perf_counter() if t_start is None else t_start
     args = parse_args(argv)
-    root = Path(root) if root is not None else BENCH_DIR.parent
+    root = Path(root) if root is not None else deployment.ROOT
     try:
         out = run_cell(root, args.workload, args.seed, args.seconds,
                        bool(args.trace), t_start, require_tpu=require_tpu)

@@ -1,10 +1,9 @@
 """The plain reference the benchmark holds the served answers to.
 
-Nothing here imports the program.  Three references:
+Nothing here imports the program.  Two references, whatever the query
+kind (the exact answers the ``(e, beta)`` guarantee is held against are the
+kind's own, ``bench/queries/<kind>.py``):
 
-* ``ExactTruth`` -- exact answers of the date-range templates from
-  per-(ship day, group) counts and sums built once from the table.  The
-  ``(e, beta)`` guarantee is held against it.
 * ``sample_stats`` -- what an answer computed from a given set of drawn
   rows has to say about those rows: how many matched, the matching
   population they estimate, their plain mean and spread.  The harness logs
@@ -28,8 +27,6 @@ from statistics import NormalDist
 
 import numpy as np
 
-from .tpch import SHIP_DAYS
-
 # A drawn value this close to a region cut (relative) may fall on either
 # side of it in float32 on the device; the reference takes both sides.
 CUT_TOLERANCE = 1e-6
@@ -43,67 +40,6 @@ K_TOLERANCE = 1e-6
 
 def z_score(beta: float) -> float:
     return float(NormalDist().inv_cdf((1.0 + beta) / 2.0))
-
-
-class ExactTruth:
-    """Exact COUNT / SUM / AVG over ``[lo, hi)`` ship-day ranges, overall and
-    per ``rf_ls`` group, and the blocks that hold matching rows."""
-
-    def __init__(self, table, measure: str, day_col: str, group_col: str,
-                 n_groups: int):
-        price = table.columns[measure]
-        day = table.columns[day_col].astype(np.int64)
-        grp = table.columns[group_col].astype(np.int64)
-        self.n_groups = int(n_groups)
-        key = day * self.n_groups + grp
-        size = SHIP_DAYS * self.n_groups
-        cnt = np.bincount(key, minlength=size).astype(np.float64)
-        s1 = np.bincount(key, weights=price, minlength=size)
-        del key
-        shape = (SHIP_DAYS, self.n_groups)
-        z = np.zeros((1, self.n_groups))
-        self._cnt = np.concatenate([z, np.cumsum(cnt.reshape(shape), 0)])
-        self._s1 = np.concatenate([z, np.cumsum(s1.reshape(shape), 0)])
-        # Per block: prefix counts over ship days, to tell which blocks hold
-        # a matching row.
-        hist = np.stack([np.bincount(day[table.block_slice(b)],
-                                     minlength=SHIP_DAYS)
-                         for b in range(table.n_blocks)])
-        self._block_prefix = np.concatenate(
-            [np.zeros((table.n_blocks, 1), np.int64),
-             np.cumsum(hist, axis=1)], axis=1)
-
-    @staticmethod
-    def _days(lo, hi):
-        lo = int(min(max(math.ceil(lo), 0), SHIP_DAYS))
-        hi = int(min(max(math.ceil(hi), 0), SHIP_DAYS))
-        return lo, max(hi, lo)
-
-    def groups(self, lo, hi):
-        """(count, sum) per group over ship days ``[lo, hi)``."""
-        a, b = self._days(lo, hi)
-        return self._cnt[b] - self._cnt[a], self._s1[b] - self._s1[a]
-
-    def answer(self, agg: str, lo, hi, group: int | None = None) -> float:
-        cnt, s1 = self.groups(lo, hi)
-        if group is None:
-            c, s = float(cnt.sum()), float(s1.sum())
-        else:
-            c, s = float(cnt[group]), float(s1[group])
-        if agg == "COUNT":
-            return c
-        if agg == "SUM":
-            return s
-        if agg == "AVG":
-            return s / c if c > 0 else float("nan")
-        raise ValueError(f"no exact reference for {agg!r}")
-
-    def live_blocks(self, lo, hi) -> np.ndarray:
-        """Indices of blocks that hold at least one row with ship day in
-        ``[lo, hi)``."""
-        a, b = self._days(lo, hi)
-        return np.flatnonzero(self._block_prefix[:, b]
-                              - self._block_prefix[:, a] > 0)
 
 
 def _to_bf16(x: np.ndarray) -> np.ndarray:
@@ -259,18 +195,18 @@ class Anchors:
     The global anchor: the pilot's mean and sample spread (ddof 1); values
     are shifted to be positive only where the pilot saw a value <= 0.  A
     key with a WHERE takes its own anchor from the pilot and bootstrap rows
-    that match it, where at least ``min_support`` match (and not all do).
-    Region cuts: S = (sketch - p2 sigma, sketch - p1 sigma), L = (sketch +
-    p1 sigma, sketch + p2 sigma)."""
+    (``rows``) that match it, where at least ``min_support`` match (and not
+    all do).  Region cuts: S = (sketch - p2 sigma, sketch - p1 sigma), L =
+    (sketch + p1 sigma, sketch + p2 sigma).  ``values`` maps a dict of
+    columns to the values aggregated."""
 
-    def __init__(self, boot_calls: list, pilot_calls: list, measure: str,
+    def __init__(self, boot_calls: list, pilot_calls: list, values,
                  est: dict, precision: str = "float64"):
-        self.measure = measure
         self.est = est
         self.precision = precision
-        pilot = _columns(pilot_calls)
         self.rows = _columns(boot_calls + pilot_calls)
-        self.global_ = self._frame(self._vals(pilot[measure]))
+        self._values = self._vals(values(self.rows))
+        self.global_ = self._frame(self._vals(values(_columns(pilot_calls))))
 
     def _vals(self, v: np.ndarray) -> np.ndarray:
         if self.precision == "bfloat16":
@@ -287,20 +223,14 @@ class Anchors:
                     cuts=(sk - p2 * sigma, sk - p1 * sigma,
                           sk + p1 * sigma, sk + p2 * sigma))
 
-    def for_where(self, column, lo, hi) -> dict:
-        if column is None or column not in self.rows:
-            return self.global_
-        col = self.rows[column]
-        m = np.ones(col.shape, bool)
-        if lo is not None:
-            m &= col >= lo
-        if hi is not None:
-            m &= col < hi
+    def for_mask(self, m: np.ndarray) -> dict:
+        """The anchor of a key whose WHERE picks the rows ``m`` of
+        ``rows``."""
         if m.size == 0 or bool(m.all()):
             return self.global_
         if int(m.sum()) < max(int(self.est["anchor_min_support"]), 2):
             return self.global_
-        vals = self._vals(self.rows[self.measure][m])
+        vals = self._values[m]
         sigma = float(np.std(vals, ddof=1))
         if not math.isfinite(sigma) or sigma <= 0:
             return self.global_

@@ -17,13 +17,17 @@ from typing import Optional
 
 import numpy as np
 
-from .tpch import seed_rng
+from .deployment import seed_rng
 
 STREAM_PROGRAM = 2
 # The estimator constants a configuration states (``"estimator"``) and the
 # service passes to the program.
 ISLA_PARAMS = ("p1", "p2", "eta", "thr", "te", "mild_lo", "mild_hi",
                "q_mild", "q_strong", "min_region_count")
+# The loop's cumulative counters the metric readers see, where the program
+# keeps them.
+COUNTERS = ("answered", "subsumed", "deduped", "samples_drawn", "pane_slots",
+            "h2d_bytes", "store_builds")
 
 
 class DrawLog:
@@ -57,9 +61,11 @@ def _logged(block: int, inner, log: DrawLog):
 
 
 class Service:
-    """The served path for one configuration, built from a table."""
+    """The served path for one configuration, built from a table; ``where``
+    maps a query spec to the fields of its ``Predicate`` (the query
+    kind's)."""
 
-    def __init__(self, cfg: dict, table, seed: int, log: DrawLog):
+    def __init__(self, cfg: dict, table, seed: int, log: DrawLog, where):
         import warnings
 
         from repro.core import IslaParams, ZoneMap
@@ -77,6 +83,7 @@ class Service:
         measure = cfg["measure"]
         blocks = table.blocks()
         self.block_sizes = np.asarray(table.block_sizes(), np.float64)
+        self.where = where
         samplers = [_logged(b, table_sampler(t), log)
                     for b, t in enumerate(blocks)]
         zone_map = (ZoneMap.from_tables(blocks, measure=measure)
@@ -98,9 +105,9 @@ class Service:
     def submit(self, spec) -> int:
         from repro.core import IslaQuery, Predicate
 
-        where = None
-        if spec.column is not None:
-            where = Predicate(column=spec.column, lo=spec.lo, hi=spec.hi)
+        where = self.where(spec)
+        if where is not None:
+            where = Predicate(**where)
         return self.loop.submit(IslaQuery(e=spec.e, beta=spec.beta,
                                           agg=spec.agg, where=where,
                                           group_by=spec.group_by))
@@ -110,12 +117,16 @@ class Service:
         tickets it completed."""
         return [(t.tid, t.answer) for t in self.loop.tick()]
 
+    def samples_drawn(self) -> int:
+        """The loop's cumulative new samples (read around every tick)."""
+        return self.loop.samples_drawn
+
     def counters(self) -> dict:
         """The loop's cumulative counters and stage seconds."""
         s = self.loop.stats
-        return {"answered": s["answered"], "subsumed": s["subsumed"],
-                "deduped": s["deduped"], "samples_drawn": s["samples_drawn"],
-                "stage_seconds": dict(s["stage_seconds"])}
+        out = {k: s[k] for k in COUNTERS if k in s}
+        out["stage_seconds"] = dict(s["stage_seconds"])
+        return out
 
     def close(self) -> None:
         """Drop the program's state, device arrays included."""

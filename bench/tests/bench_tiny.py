@@ -1,6 +1,7 @@
 """A tiny benchmark checkout for the CPU tests: the real configuration,
-mix and metric files, cut to a table a test can hold, under a temporary
-root laid out like the repository (``BENCHMARK.json`` + ``bench/...``)."""
+mix, generator, query-kind and metric files, cut to a table a test can
+hold, under a temporary root laid out like the repository
+(``BENCHMARK.json`` + ``bench/...``)."""
 from __future__ import annotations
 
 import json
@@ -14,9 +15,16 @@ for p in (str(BENCH), str(BENCH.parent / "src")):
     if p not in sys.path:
         sys.path.insert(0, p)
 
-from isla_bench import harness  # noqa: E402
+from isla_bench import deployment, harness  # noqa: E402
 
 TINY_DATA = {"rows": 240_000, "block_rows": 2_000, "scale_factor": 0.1}
+# The directories of files a deployment brings, copied into a tiny root.
+FILE_DIRS = ("metrics", "generators", "queries")
+
+
+def load(directory: str, name: str):
+    """The repository's ``bench/<directory>/<name>.py`` module."""
+    return deployment.load(BENCH.parent, directory, name)
 
 
 def tiny_config(name: str = "tpch_sf10", **data) -> dict:
@@ -38,12 +46,16 @@ def tiny_mix(name: str = "adhoc") -> dict:
 
 def make_root(tmp: Path, configs: dict | None = None,
               mixes: dict | None = None, metrics: dict | None = None,
-              cells: list | None = None, per_layer: list | None = None
+              cells: list | None = None, per_layer: list | None = None,
+              generators: dict | None = None, queries: dict | None = None
               ) -> Path:
-    """Write a benchmark root under ``tmp``: the real metric readers plus
-    the given configurations, mixes and extra metric files."""
+    """Write a benchmark root under ``tmp``: the real metric readers,
+    generators and query kinds, plus the given configurations, mixes and
+    extra metric, generator and query-kind files (name -> source)."""
     root = Path(tmp)
-    shutil.copytree(BENCH / "metrics", root / "bench" / "metrics")
+    for d in FILE_DIRS:
+        shutil.copytree(BENCH / d, root / "bench" / d,
+                        ignore=shutil.ignore_patterns("__pycache__"))
     (root / "bench" / "configs").mkdir(parents=True)
     (root / "bench" / "traffic").mkdir(parents=True)
     configs = configs or {"tiny": tiny_config()}
@@ -54,8 +66,9 @@ def make_root(tmp: Path, configs: dict | None = None,
     for name, mix in mixes.items():
         (root / "bench" / "traffic" / f"{name}.json").write_text(
             json.dumps(mix))
-    for name, src in (metrics or {}).items():
-        (root / "bench" / "metrics" / f"{name}.py").write_text(src)
+    for d, files in zip(FILE_DIRS, (metrics, generators, queries)):
+        for name, src in (files or {}).items():
+            (root / "bench" / d / f"{name}.py").write_text(src)
     real = json.loads((BENCH.parent / "BENCHMARK.json").read_text())
     cells = cells or [{"name": "tiny.adhoc", "config": "tiny",
                        "traffic": "adhoc", "chips": 1, "why": "test"}]

@@ -79,6 +79,19 @@ def test_added_files_make_a_new_cell(root):
     assert len(out["notes"]["lateness_ms"]) == 3
 
 
+def test_counter_readers_on_a_traced_run(root):
+    """The loop's pane, upload and store-build counters reach their
+    readers through ``Service.counters()``."""
+    out = bench_tiny.run(root, "tiny.adhoc", seed=2 ** 31 + 5, seconds=2.0,
+                         trace=True)
+    m = out["result"]["metrics"]
+    assert 0.0 < m["pane.fill_share"]["value"] <= 100.0
+    assert m["pane.fill_share"]["unit"] == "%"
+    assert m["h2d.mb_per_tick"]["value"] > 0.0
+    # Nearly every key of the mix is new: about one store a tick.
+    assert 0.5 < m["draw.store_builds_per_tick"]["value"] <= 1.0
+
+
 def test_metric_entries_respect_workloads():
     bench = {"per_layer": [{"name": "a"}, {"name": "b", "workloads": ["x"]}]}
     assert [m["name"] for m in harness.metric_entries(

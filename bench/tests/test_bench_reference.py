@@ -1,14 +1,22 @@
-"""The plain reference: prefix-sum exact answers and drawn-row statistics
-against brute-force numpy passes, and the bfloat16 control."""
+"""The plain reference: the range kind's prefix-sum exact answers and
+drawn-row statistics against brute-force numpy passes, and the bfloat16
+control."""
 import math
 
 import numpy as np
 import pytest
 
-import bench_tiny  # noqa: F401  (puts the benchmark on sys.path)
-from isla_bench import reference, tpch
+import bench_tiny
+from isla_bench import reference
+
+tpch = bench_tiny.load("generators", "tpch_lineitem")
+range_kind = bench_tiny.load("queries", "range")
 
 CFG = {"scale_factor": 0.1, "rows": 120_000, "block_rows": 10_000}
+
+
+def spec(agg, lo, hi, column="l_shipdate"):
+    return range_kind.QuerySpec(agg, column, lo, hi, None, 1.0, 0.95)
 
 
 @pytest.fixture(scope="module")
@@ -18,8 +26,9 @@ def table():
 
 @pytest.fixture(scope="module")
 def truth(table):
-    return reference.ExactTruth(table, "l_extendedprice", "l_shipdate",
-                                "rf_ls", 4)
+    return range_kind.truth(
+        {"measure": "l_extendedprice", "group_domains": {"rf_ls": 4}},
+        {"column": "l_shipdate", "group_by": "rf_ls"}, table)
 
 
 @pytest.mark.parametrize("lo,hi", [(0, 3000), (100, 107), (1263, 1628),
@@ -32,17 +41,20 @@ def test_exact_answers_match_brute_force(table, truth, lo, hi):
     for agg in ("COUNT", "SUM", "AVG"):
         want = {"COUNT": m.sum(), "SUM": price[m].sum(),
                 "AVG": price[m].mean() if m.any() else math.nan}[agg]
-        got = truth.answer(agg, lo, hi)
+        got = truth.answer(spec(agg, lo, hi))
         assert got == pytest.approx(want, rel=1e-12, nan_ok=True)
         for g in range(4):
             mg = m & (grp == g)
             want = {"COUNT": mg.sum(), "SUM": price[mg].sum(),
                     "AVG": price[mg].mean() if mg.any() else math.nan}[agg]
-            assert truth.answer(agg, lo, hi, g) == pytest.approx(
+            assert truth.answer(spec(agg, lo, hi), g) == pytest.approx(
                 want, rel=1e-12, nan_ok=True)
     live = [b for b in range(table.n_blocks)
             if np.any(m[table.block_slice(b)])]
-    assert truth.live_blocks(lo, hi).tolist() == live
+    assert truth.live_blocks(spec("COUNT", lo, hi)).tolist() == live
+    assert truth.live_blocks(spec("COUNT", None, None, None)) is None
+    assert truth.answer(spec("SUM", None, None, None)) == pytest.approx(
+        price.sum(), rel=1e-12)
 
 
 def _draw(table, n, seed):
@@ -170,7 +182,7 @@ def test_anchors_match_the_program():
 
     boot = [call(b, 64) for b in range(0, 12, 3)]
     pilot = [call(b, 9) for b in range(12)]
-    anchors = reference.Anchors(boot, pilot, "v", EST)
+    anchors = reference.Anchors(boot, pilot, lambda cols: cols["v"], EST)
     pv = np.concatenate([r["v"] for _, r in pilot])
     pr = PilotResult(sketch0=float(pv.mean()), sigma=float(pv.std(ddof=1)),
                      pilot_size=pv.size, shift=0.0, values=pv)
@@ -183,7 +195,8 @@ def test_anchors_match_the_program():
                                                       hi=hi),
                                       params, measure="v",
                                       min_support=EST["anchor_min_support"])
-        got = anchors.for_where("d", lo, hi)
+        got = anchors.for_mask(range_kind.match(
+            spec("AVG", lo, hi, column="d"), anchors.rows))
         assert got["sketch0"] == pytest.approx(want.sketch0, rel=1e-12)
         assert got["sigma"] == pytest.approx(want.sigma, rel=1e-12)
         assert got["cuts"] == pytest.approx(want.boundaries.as_tuple(),

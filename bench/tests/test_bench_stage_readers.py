@@ -1,6 +1,7 @@
-"""The readers of the program's stage spans on a hand-built context: each
-reads its stage's milliseconds per executed tick, and nothing from a
-program that books no such stage."""
+"""The readers of the program's stage spans and counters on a hand-built
+context: each reads its stage's milliseconds (or its counter's rate) per
+executed tick, and nothing from a program that books no such stage or
+keeps no such counter."""
 from types import SimpleNamespace
 
 import pytest
@@ -18,15 +19,16 @@ STAGE_OF = {
 }
 
 
-def _ctx(stage_seconds0: dict, stage_seconds1: dict, executed: int):
+def _ctx(stage_seconds0: dict, stage_seconds1: dict, executed: int,
+         counters0: dict | None = None, counters1: dict | None = None):
     counters = dict(answered=0, subsumed=0, deduped=0, samples_drawn=0)
     ticks = ([harness.TickRecord([("AVG",)], 100)] * executed
              + [harness.TickRecord([], 0)])
     clients = SimpleNamespace(sent=[], ticks=ticks)
     return harness.metric_context(
         clients, {"group_domains": {"rf_ls": 4}},
-        dict(counters, stage_seconds=stage_seconds0),
-        dict(counters, stage_seconds=stage_seconds1),
+        dict(counters, stage_seconds=stage_seconds0, **(counters0 or {})),
+        dict(counters, stage_seconds=stage_seconds1, **(counters1 or {})),
         (0.0, 1.0, 1.0), 0, None, 0.0, "cpu", 8)
 
 
@@ -44,3 +46,26 @@ def test_stage_reader(name, case):
         # A program that books no such stage (the parent of the change
         # that added it) reports nothing and does not raise.
         assert read(_ctx(other, other, executed=4)) is None
+
+
+COUNTER_READS = {
+    # name: (counters at the window's start, at its end, reading)
+    "pane.fill_share": ({"samples_drawn": 100, "pane_slots": 1000},
+                        {"samples_drawn": 400, "pane_slots": 1500}, 60.0),
+    "h2d.mb_per_tick": ({"h2d_bytes": 1_000_000}, {"h2d_bytes": 9_000_000},
+                        2.0),
+    "draw.store_builds_per_tick": ({"store_builds": 5}, {"store_builds": 8},
+                                   0.75),
+}
+
+
+@pytest.mark.parametrize("case", ["value", "absent"])
+@pytest.mark.parametrize("name", sorted(COUNTER_READS))
+def test_counter_reader(name, case):
+    read = harness.load_reader(bench_tiny.BENCH.parent, name)
+    c0, c1, want = COUNTER_READS[name]
+    if case == "value":
+        assert read(_ctx({}, {}, 4, c0, c1)) == pytest.approx(want)
+    else:
+        # A program that keeps no such counter reports nothing.
+        assert read(_ctx({}, {}, 4)) is None

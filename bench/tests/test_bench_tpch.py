@@ -3,8 +3,9 @@ deterministic in its seed."""
 import numpy as np
 import pytest
 
-import bench_tiny  # noqa: F401  (puts the benchmark on sys.path)
-from isla_bench import tpch
+import bench_tiny
+
+tpch = bench_tiny.load("generators", "tpch_lineitem")
 
 CFG = {"scale_factor": 0.1, "rows": 200_003, "block_rows": 20_000,
        "columns": ["l_extendedprice", "l_quantity", "l_shipdate", "rf_ls"]}

@@ -8,7 +8,7 @@ import numpy as np
 import pytest
 
 import bench_tiny
-from isla_bench import tpch
+from isla_bench.deployment import seed_rng
 from isla_bench.traffic import Mix
 
 TRAFFIC = bench_tiny.BENCH / "traffic"
@@ -19,7 +19,7 @@ def load(name):
 
 
 def take(mix, seed, n):
-    return list(itertools.islice(mix.queries(tpch.seed_rng(seed, 3)), n))
+    return list(itertools.islice(mix.queries(seed_rng(seed, 3)), n))
 
 
 @pytest.mark.parametrize("name", sorted(p.stem for p in TRAFFIC.glob("*.json")))
@@ -32,7 +32,7 @@ def test_every_mix_is_reproducible(name):
 def test_adhoc_deck_shares():
     spec = load("adhoc")
     mix = Mix(spec, e=764.0, beta=0.95)
-    deck = mix.deck()
+    deck = mix.kind.deck(mix.queries_spec)
     n_s = spec["queries"]["start_strata"]
     qs = take(mix, 77, len(deck) * n_s * 3)
     # Every combination once per deck.
@@ -58,9 +58,9 @@ def test_adhoc_deck_shares():
 def test_sweep_covers_deck_at_each_factor():
     mix = Mix(load("adhoc"), e=100.0, beta=0.95)
     qs = mix.sweep(np.random.default_rng(0), [0.5, 1.0])
-    assert len(qs) == 2 * len(mix.deck())
-    assert collections.Counter(q.e for q in qs) == {
-        50.0: len(mix.deck()), 100.0: len(mix.deck())}
+    n = len(mix.kind.deck(mix.queries_spec))
+    assert len(qs) == 2 * n
+    assert collections.Counter(q.e for q in qs) == {50.0: n, 100.0: n}
 
 
 def test_unbalanced_mix_keeps_its_shares():
