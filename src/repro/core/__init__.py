@@ -5,7 +5,8 @@ Device path (fp32, jit/shard_map-safe): distributed.isla_mean.
 Telemetry API for training loops: metrics.loss_stats etc.
 """
 from .types import (AggregateResult, Anchor, BlockResult, BlockResultsBatch,
-                    Boundaries, IslaParams, Predicate, RegionMoments,
+                    Boundaries, IslaParams, Measure, Predicate,
+                    RegionMoments,
                     StoreKey, ZoneMap, REGION_TS, REGION_S, REGION_N,
                     REGION_L, REGION_TL, ZONE_EMPTY, ZONE_FULL,
                     ZONE_PARTIAL, classify, classify_np, region_of)
@@ -41,7 +42,7 @@ from . import distributed, metrics
 __all__ = [
     "AggregateResult", "Anchor", "BlockResult", "BlockResultsBatch",
     "Boundaries",
-    "IslaParams", "IslaQuery", "Predicate", "flat_segments",
+    "IslaParams", "IslaQuery", "Measure", "Predicate", "flat_segments",
     "RegionMoments", "REGION_TS", "REGION_S", "REGION_N", "REGION_L",
     "REGION_TL", "ZoneMap", "ZONE_EMPTY", "ZONE_FULL", "ZONE_PARTIAL",
     "classify", "classify_np", "region_of", "choose_q",

@@ -596,7 +596,8 @@ class IslaQuery:
         ``repro.core.multiquery`` for how non-AVG aggregates compose from
         the leverage-based mean and the shared block moments.  Plain
         unpredicated COUNT is exact from catalog metadata; under WHERE /
-        GROUP BY it becomes an estimate with a normal-binomial bound.
+        GROUP BY it becomes an estimate with a binomial (Wilson score)
+        bound.
     where : Predicate, optional
         WHERE clause evaluated on the sampled rows.  Each distinct
         predicate gets its own moment store and — when the matching pilot

@@ -9,7 +9,8 @@ summarizable as a block, and the whole relational surface rides the one
 vectorized engine:
 
   planning    ``plan()`` parses each ``IslaQuery`` (``where: Predicate``,
-              ``group_by: key``, ``mode``), resolves per-query Phase 2
+              a conjunction of one-column terms; ``group_by: key``;
+              ``mode``), resolves per-query Phase 2
               modes (``auto`` from pilot skew), groups queries by resolved
               mode, and plans ONE shared sampling rate per mode-group —
               the strictest Eq. 1 rate among the group's queries, inflated
@@ -27,8 +28,8 @@ vectorized engine:
               SUM    est. group population * mean (plain M * mean when
                      unpredicated — absolute bound M * e)
               COUNT  exact from catalog metadata when unpredicated;
-                     estimated (M * match fraction) with a normal-binomial
-                     bound under WHERE / GROUP BY
+                     estimated (M * match fraction) with a binomial
+                     (Wilson score) bound under WHERE / GROUP BY
               VAR    E[X^2] - mean^2 per group from the pass's plain cell
                      moments and the leverage-corrected mean (best-effort)
               Bounds stay honest: a group's ``(e, beta)`` claim is reported
@@ -70,6 +71,7 @@ docs/ARCHITECTURE.md "Per-key leverage anchors".
 """
 from __future__ import annotations
 
+import contextlib
 import copy
 import dataclasses
 import math
@@ -91,8 +93,9 @@ from .preestimation import (required_sample_size, run_pilot, sampling_rate,
                             z_score)
 from .summarize import summarize
 from .types import (AggregateResult, Anchor, BlockResultsBatch,
-                    Boundaries, IslaParams, Predicate, StoreKey, ZoneMap,
-                    ZONE_EMPTY, ZONE_FULL, ZONE_PARTIAL, demand_dominates)
+                    Boundaries, IslaParams, Measure, Predicate, StoreKey,
+                    ZoneMap, ZONE_EMPTY, ZONE_FULL, ZONE_PARTIAL,
+                    demand_dominates)
 
 AGGREGATES = ("AVG", "SUM", "COUNT", "VAR", "count_distinct")
 # Aggregates served from the store's mergeable HLL register plane rather
@@ -128,7 +131,8 @@ class PlannedSelectivityFloorWarning(UserWarning):
     100x inflation, so the answer may not earn its requested (e, beta)
     and will report a best-effort bound."""
 
-# Rows are dicts of equal-length columns; bare arrays mean "measure only".
+# Rows are dicts of equal-length columns; bare arrays mean "measure only"
+# (a one-column measure).
 RowSampler = Callable[[int, np.random.Generator],
                       Union[np.ndarray, Mapping[str, np.ndarray]]]
 
@@ -359,9 +363,13 @@ class _CachedAnswer:
 # stages on the same thread subtracted); a dotted key is a part of its
 # parent and books inclusive time.  ``run`` books every key but
 # ``admit`` (the admission loop's own tick) into ``last_stage_times``.
-_STAGES = ("admit", "plan", "draw", "draw.stack", "draw.sample",
-           "draw.mask", "h2d", "h2d.pane", "h2d.put", "launch", "readback",
-           "compose")
+# ``plan.zone`` (zone verdicts and zone-bounded selectivity of a
+# conjunctive WHERE the zone map has not judged) and ``draw.measure`` (a
+# derived measure over a chunk's drawn rows) open only for those shapes:
+# a one-column WHERE and a one-column measure take no extra span.
+_STAGES = ("admit", "plan", "plan.zone", "draw", "draw.stack",
+           "draw.sample", "draw.measure", "draw.mask", "h2d", "h2d.pane",
+           "h2d.put", "launch", "readback", "compose")
 # Counters a run books beside its stage clock (``last_stage_times.
 # counts``): value-pane slots uploaded, bytes handed to the uploads, and
 # device stores built (one launch each).
@@ -394,8 +402,19 @@ class MultiQueryExecutor:
     carries at least its requested confidence wherever the estimated
     selectivity held.
 
-    ``measure`` names the aggregated column when samplers return row dicts
-    (bare-array samplers are treated as measure-only rows).
+    ``measure`` is the aggregated value (``types.Measure.of``): a column
+    name, or a sequence of names whose product is aggregated (a derived
+    measure such as ``["l_extendedprice", "l_discount"]``).  It is
+    evaluated in float64 on the host from the drawn rows, everywhere the
+    executor reads values (draw, pilot, anchors, drift probes), and
+    reaches the store as a raw column does; rows that lack one of its
+    columns raise ``KeyError``.  Bare-array samplers are treated as
+    measure-only rows (one-column measures only).
+    A ``where`` is a conjunction of one-column terms
+    (``types.Predicate``): the zone map judges each term (a block is
+    provably empty when any term is, provably full only when every term
+    is), and the draw evaluates the whole conjunction on the rows of the
+    blocks it could not decide.
     ``group_domains`` maps each legal ``group_by`` key to its cardinality —
     catalog metadata, exactly like block sizes.
     ``zone_map`` (a ``types.ZoneMap``) enables zone-map block pruning:
@@ -408,7 +427,7 @@ class MultiQueryExecutor:
     def __init__(self, block_samplers: Sequence[RowSampler],
                  block_sizes: Sequence[int],
                  params: Optional[IslaParams] = None,
-                 measure: str = "value",
+                 measure="value",
                  group_domains: Optional[Mapping[str, int]] = None,
                  refine_anchors: bool = True,
                  anchor_min_support: int = 64,
@@ -421,7 +440,7 @@ class MultiQueryExecutor:
         self.block_sizes = [int(b) for b in block_sizes]
         self.params = params if params is not None else IslaParams()
         self.data_size = int(sum(self.block_sizes))
-        self.measure = measure
+        self.measure = Measure.of(measure)
         self.group_domains = dict(group_domains or {})
         for key, card in self.group_domains.items():
             if int(card) < 1:
@@ -582,7 +601,7 @@ class MultiQueryExecutor:
                        else float(sigma_ratio))
         if probe_columns is None:
             probe_columns = self._draw_probe(rng, n)
-        probe = self._measure_of(probe_columns)
+        probe = self.measure(probe_columns)
         return self._stats_drifted(pilot.sketch0, pilot.sigma, probe,
                                    z_thresh, sigma_ratio,
                                    ref_support=pilot.pilot_size)
@@ -600,7 +619,7 @@ class MultiQueryExecutor:
         out = []
         warm = {**{k: s.anchor for k, s in self._stores.items()},
                 **{k: s.anchor for k, s in self._device_stores.items()}}
-        measure = (self._measure_of(probe_columns) if warm
+        measure = (self.measure(probe_columns) if warm
                    else np.zeros(0))
         for skey, anchor in warm.items():
             if anchor is None or anchor.source != "refined" \
@@ -666,13 +685,20 @@ class MultiQueryExecutor:
     def _as_rows(self, drawn) -> Mapping[str, np.ndarray]:
         if isinstance(drawn, Mapping):
             return {k: np.asarray(v) for k, v in drawn.items()}
-        return {self.measure: np.asarray(drawn)}
+        if self.measure.derived:
+            raise ValueError(f"the derived measure "
+                             f"{self.measure.describe()!r} needs samplers "
+                             f"that return row dicts, got a bare array")
+        return {self.measure.columns[0]: np.asarray(drawn)}
 
-    def _measure_of(self, rows: Mapping[str, np.ndarray]) -> np.ndarray:
-        if self.measure not in rows:
-            raise KeyError(f"measure column {self.measure!r} not in sampled "
-                           f"rows (have: {sorted(rows)})")
-        return np.asarray(rows[self.measure], dtype=np.float64)
+    def _drawn_measure(self, rows: Mapping[str, np.ndarray],
+                       timings=None) -> np.ndarray:
+        """The measure over one chunk's drawn rows; a derived measure's
+        evaluation is the ``draw.measure`` stage."""
+        span = (D.stage_trace("draw.measure", timings)
+                if self.measure.derived else contextlib.nullcontext())
+        with span:
+            return self.measure(rows)
 
     def _draw_and_ingest(self, group_stores: Mapping[Tuple, MomentStore],
                          quotas: np.ndarray, rng: np.random.Generator,
@@ -692,7 +718,7 @@ class MultiQueryExecutor:
         counted = set()       # one logical round per store per pass
         for chunk, columns, block_ids in self._iter_row_chunks(
                 quotas, rng, chunk_blocks, timings):
-            raw = self._measure_of(columns)
+            raw = self._drawn_measure(columns, timings)
             shifted = {}      # shift value -> translated stream (shared)
             for key, store in group_stores.items():
                 where, group_by = key
@@ -748,14 +774,15 @@ class MultiQueryExecutor:
         """Predicate match mask with zone short-cuts: rows of provably-full
         blocks are True and rows of provably-empty blocks are False WITHOUT
         evaluating the predicate; only residual-block rows pay the
-        comparison.  Bit-identical to ``where.mask`` — the zone verdicts
-        are proofs over exact data bounds, never estimates."""
+        comparison (of every term of a conjunction).  Bit-identical to
+        ``where.mask`` — the zone verdicts are proofs over exact data
+        bounds, never estimates."""
         if where is None:
             return None
         if self.zone_map is None:
             return where.mask(columns)
         status = self.zone_map.status(where)
-        if where.column not in columns:
+        if any(c not in columns for c in where.columns):
             where.mask(columns)  # raise the standard KeyError
         st = status[np.asarray(block_ids, dtype=np.intp)]
         out = np.empty(st.shape, dtype=bool)
@@ -763,8 +790,8 @@ class MultiQueryExecutor:
         out[st == ZONE_EMPTY] = False
         part = st == ZONE_PARTIAL
         if np.any(part):
-            col = np.asarray(columns[where.column])
-            out[part] = where.mask({where.column: col[part]})
+            out[part] = where.mask({c: np.asarray(columns[c])[part]
+                                    for c in where.columns})
         return out
 
     def _target_quotas(self, mg: ModeGroup,
@@ -819,8 +846,7 @@ class MultiQueryExecutor:
         fewer than two matching pilot rows are skipped — the pooled-sigma
         floor in ``_query_rate`` covers them."""
         key = q.group_by
-        if (key is None or not pilot_columns or key not in pilot_columns
-                or self.measure not in pilot_columns):
+        if key is None or not pilot_columns or key not in pilot_columns:
             return []
         # Warm incremental ticks re-plan against the SAME frozen pilot
         # (identity-checked), where these sigmas are immutable.
@@ -830,7 +856,7 @@ class MultiQueryExecutor:
         if cacheable and ckey in self._sigma_cache:
             return self._sigma_cache[ckey]
         col = np.asarray(pilot_columns[key])
-        vals = np.asarray(pilot_columns[self.measure], dtype=np.float64)
+        vals = self.measure(pilot_columns)
         m = (q.where.mask(pilot_columns) if q.where is not None
              else np.ones(col.shape, dtype=bool))
         card = int(self.group_domains[key])
@@ -1098,7 +1124,7 @@ class MultiQueryExecutor:
             def f(n, r):
                 rows = self._as_rows(sampler(n, r))
                 captured.append(rows)
-                return self._measure_of(rows)
+                return self.measure(rows)
             return f
 
         if sigma_guess is None:
@@ -1108,7 +1134,7 @@ class MultiQueryExecutor:
             for s in self.block_samplers[::stride]:
                 rows = self._as_rows(s(self._BOOTSTRAP_PER_BLOCK, rng))
                 captured.append(rows)
-                boot.append(self._measure_of(rows))
+                boot.append(self.measure(rows))
             sigma_guess = float(np.std(np.concatenate(boot)))
             if sigma_guess <= 0:
                 sigma_guess = 1e-9
@@ -1139,7 +1165,7 @@ class MultiQueryExecutor:
              mode: str = "calibrated", route: str = "host",
              rate_override: Optional[float] = None,
              sigma_guess: Optional[float] = None,
-             pilot=None, pilot_columns=None) -> QueryPlan:
+             pilot=None, pilot_columns=None, timings=None) -> QueryPlan:
         """Parse + plan a query batch: run the pilot, resolve each query's
         Phase 2 mode, group queries by resolved mode, and set one shared
         predicate-aware rate per mode-group.
@@ -1147,7 +1173,8 @@ class MultiQueryExecutor:
         Passing a cached ``pilot`` (+ its ``pilot_columns``) skips the
         pilot draw entirely — the warm incremental path, where the anchor
         (boundaries, sketch0, shift) must stay frozen so merged store
-        moments remain classifiable."""
+        moments remain classifiable.  ``timings`` is the run's stage
+        clock, which books the ``plan.zone`` part."""
         self.validate(queries)
         if route not in ROUTES:
             raise ValueError(f"unknown route {route!r}; expected one of "
@@ -1171,6 +1198,16 @@ class MultiQueryExecutor:
         for key in anchors:
             anchors[key] = self._key_anchor(key, global_anchor,
                                             pilot_columns, params)
+        if self.zone_map is not None:
+            fresh = {q.where for q in queries
+                     if q.where is not None and q.where.terms
+                     and not self.zone_map.judged(q.where)}
+            if fresh:
+                # The rates below read these verdicts from the zone map's
+                # cache; the selectivity is the one they bound.
+                with D.stage_trace("plan.zone", timings):
+                    for where in fresh:
+                        self.zone_selectivity(where, pilot_columns)
 
         # Resolve each distinct requested mode once (the "auto" heuristic
         # and the ISLA-E geometry fit live in resolve_mode_and_geometry).
@@ -1242,7 +1279,8 @@ class MultiQueryExecutor:
     def _plan_cached(self, queries: Sequence[IslaQuery],
                      rng: np.random.Generator, mode: str, route: str,
                      rate_override: Optional[float],
-                     sigma_guess: Optional[float]) -> QueryPlan:
+                     sigma_guess: Optional[float],
+                     timings=None) -> QueryPlan:
         """``plan()`` through the PlanCache — the warm incremental path,
         where planning consumes no RNG (frozen pilot) and the compiled
         artifacts (mode-group layout, block rate vectors, per-key
@@ -1267,7 +1305,7 @@ class MultiQueryExecutor:
         plan = self.plan(list(norm), rng, mode=mode, route=route,
                          rate_override=rate_override,
                          sigma_guess=sigma_guess, pilot=pilot,
-                         pilot_columns=pilot_columns)
+                         pilot_columns=pilot_columns, timings=timings)
         wheres = frozenset(q.where for q in norm)
         zver, zstat = None, {}
         if self.zone_map is not None:
@@ -1407,15 +1445,13 @@ class MultiQueryExecutor:
             return "calibrated", None
         where, _ = key
         vals = None
-        if pilot_columns and self.measure in pilot_columns \
-                and where is not None:
+        if pilot_columns and where is not None:
             try:
                 m = np.asarray(where.mask(pilot_columns), dtype=bool)
             except KeyError:
                 m = None
             if m is not None and m.any():
-                vals = np.asarray(pilot_columns[self.measure],
-                                  dtype=np.float64)[m]
+                vals = self.measure(pilot_columns)[m]
         if vals is None or vals.size < 2:
             return resolve_mode_and_geometry(pilot, params, "empirical")
         geometry = empirical_geometry(vals + anchor.shift, anchor.sketch0,
@@ -1770,7 +1806,7 @@ class MultiQueryExecutor:
             return key_gids, key_valids
 
         def run_chunk(chunk, columns, block_ids):
-            raw = self._measure_of(columns)
+            raw = self._drawn_measure(columns, timings)
             key_gids, key_valids = key_tags(columns, block_ids)
             if dense:
                 # Dense block-major payload: the full chunk stream once,
@@ -1918,18 +1954,27 @@ class MultiQueryExecutor:
 
     def _count_bound(self, w: float, n_drawn: int,
                      beta_z: float) -> Optional[float]:
-        """Normal-binomial half-width for an estimated COUNT.
+        """Binomial half-width for an estimated COUNT: the Wilson score
+        interval of the match fraction ``p = w / M`` over ``n_drawn``
+        rows, widened to the interval centred on ``p`` that holds it, on
+        the COUNT scale.
 
-        The match fraction is clamped away from {0, 1} by ~1/n (rule-of-
-        three flavor): an all-matching or none-matching draw must not claim
-        a ±0 bound the sample cannot support.
+        The normal (Wald) half-width ``z sqrt(p (1 - p) / n)`` misses more
+        often than ``1 - beta`` where few drawn rows match (about 5.2% at
+        beta = 0.95 with ~200 matches in ~10,000 rows: a narrower bound
+        exactly when ``p`` came out low), and claims ±0 on an all- or
+        none-matching draw.  The score interval does neither: at ``p = 0``
+        its half-width is ``M z^2 / (n + z^2)``.
         """
         if n_drawn <= 0:
             return None
+        n = float(n_drawn)
         p = min(max(w / self.data_size, 0.0), 1.0)
-        edge = 1.0 / (n_drawn + 2.0)
-        p = min(max(p, edge), 1.0 - edge)
-        return beta_z * self.data_size * math.sqrt(p * (1.0 - p) / n_drawn)
+        z2 = beta_z * beta_z
+        centre = (p + z2 / (2.0 * n)) / (1.0 + z2 / n)
+        half = (beta_z / (1.0 + z2 / n)
+                * math.sqrt(p * (1.0 - p) / n + z2 / (4.0 * n * n)))
+        return self.data_size * (half + abs(centre - p))
 
     def _compose_plain(self, q: IslaQuery, sp: SharedPass, mg: ModeGroup,
                        pass_id: int) -> QueryAnswer:
@@ -2543,11 +2588,12 @@ class MultiQueryExecutor:
                 # pilot, so a PlanCache hit and a fresh plan are stream-
                 # identical — a steady-state tick does zero Python planning.
                 plan = self._plan_cached(queries, rng, mode, route,
-                                         rate_override, sigma_guess)
+                                         rate_override, sigma_guess,
+                                         timings=times)
             else:
                 plan = self.plan(queries, rng, mode=mode, route=route,
                                  rate_override=rate_override,
-                                 sigma_guess=sigma_guess)
+                                 sigma_guess=sigma_guess, timings=times)
                 if incremental:
                     self._anchor = (plan.pilot, plan.pilot_columns)
             stores = self._stores if incremental else None

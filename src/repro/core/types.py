@@ -118,24 +118,147 @@ class RegionMoments:
 
 
 
+def _term_order(t: "Predicate") -> tuple:
+    """Canonical order of a conjunction's terms: by column, then bounds
+    (an absent bound first)."""
+    def key(x):
+        return (0, 0.0) if x is None else (1, float(x))
+    return (t.column, key(t.lo), key(t.hi), key(t.eq))
+
+
+@dataclasses.dataclass(frozen=True)
+class Measure:
+    """The value an executor aggregates: one column, or the product of
+    several (a derived measure such as ``l_extendedprice * l_discount``).
+
+    ``Measure.of`` takes what a caller configures: a column name (one
+    column, the same float64 values as reading it) or a sequence of names
+    (their product, multiplied in float64 in the order given).  Calling the
+    measure on a dict of columns evaluates it; a missing column raises
+    ``KeyError`` -- a measure never falls back silently.  Frozen and
+    hashable.
+
+    Examples
+    --------
+    >>> m = Measure.of(["price", "discount"])
+    >>> m({"price": np.array([100.0, 50.0]),
+    ...    "discount": np.array([0.05, 0.1])}).tolist()
+    [5.0, 5.0]
+    >>> Measure.of("price").derived
+    False
+    """
+
+    columns: Tuple[str, ...]
+
+    @staticmethod
+    def of(spec) -> "Measure":
+        if isinstance(spec, Measure):
+            return spec
+        cols = (spec,) if isinstance(spec, str) else tuple(spec)
+        if not cols or not all(isinstance(c, str) for c in cols):
+            raise ValueError(f"a measure is a column name or a sequence of "
+                             f"column names, got {spec!r}")
+        return Measure(cols)
+
+    @property
+    def derived(self) -> bool:
+        """True for a product of several columns."""
+        return len(self.columns) > 1
+
+    def describe(self) -> str:
+        return " * ".join(self.columns)
+
+    def __call__(self, rows: Mapping[str, np.ndarray]) -> np.ndarray:
+        """The measure's float64 values over a dict of equal-length
+        columns."""
+        missing = [c for c in self.columns if c not in rows]
+        if missing:
+            raise KeyError(f"measure column(s) {missing} not in the rows "
+                           f"(have: {sorted(rows)})")
+        out = np.asarray(rows[self.columns[0]], dtype=np.float64)
+        for c in self.columns[1:]:
+            out = out * np.asarray(rows[c], dtype=np.float64)
+        return out
+
+
 @dataclasses.dataclass(frozen=True)
 class Predicate:
-    """A WHERE clause over sampled rows: the conjunction of an optional
-    half-open range ``[lo, hi)`` and an optional equality on one column.
+    """A WHERE clause over sampled rows: the conjunction (AND) of
+    one-column terms, each an optional half-open range ``[lo, hi)`` and an
+    optional equality ``eq``.
+
+    ``Predicate(column, lo, hi, eq)`` is one term.  ``Predicate(terms=[...])``
+    is a conjunction; each term is a one-column ``Predicate`` or the
+    mapping of its fields (``{"column": "l_quantity", "hi": 24.0}``).  The
+    terms are normalised to one canonical form: nested conjunctions
+    flattened, terms with no bound dropped (unless no other is left),
+    duplicates dropped, the rest in a fixed order (by column, then
+    bounds), and a conjunction of a single term IS that one-column
+    predicate.  A conjunction's own ``column``, ``lo``, ``hi`` and ``eq``
+    are None; nothing reads them.
 
     The half-open range means adjacent range predicates tile the value axis
     without double counting.  ``eq`` is meant for categorical / integer-coded
     columns, where float equality on codes is exact.  Frozen and hashable so
-    query planners can key shared work by ``(where, group_by)``.
+    query planners can key shared work by ``(where, group_by)``; a
+    one-column predicate compares and hashes as the tuple ``(column, lo,
+    hi, eq)``, a conjunction as its terms.
     """
 
-    column: str = "value"
+    column: Optional[str] = "value"
     lo: Optional[float] = None   # value >= lo
     hi: Optional[float] = None   # value <  hi
     eq: Optional[float] = None   # value == eq
+    terms: Tuple["Predicate", ...] = ()
+
+    def __post_init__(self):
+        if not self.terms:
+            return
+        given = list(self.terms)
+        if (self.lo, self.hi, self.eq) != (None, None, None):
+            given.append(Predicate(self.column, self.lo, self.hi, self.eq))
+        flat = []
+        for t in given:
+            if isinstance(t, Mapping):
+                t = Predicate(**t)
+            elif not isinstance(t, Predicate):
+                raise TypeError(f"a conjunction's term is a Predicate or "
+                                f"the mapping of its fields, got "
+                                f"{type(t).__name__}")
+            flat.extend(t.conjuncts)
+        bounded = {c for c in flat if (c.lo, c.hi, c.eq) != (None, None, None)}
+        # Only bound-free terms: the conjunction is TRUE over their column.
+        terms = tuple(sorted(bounded, key=_term_order)) or flat[:1]
+        if len(terms) == 1:
+            one = terms[0]
+            fields = (one.column, one.lo, one.hi, one.eq, ())
+        else:
+            fields = (None, None, None, None, terms)
+        for name, v in zip(("column", "lo", "hi", "eq", "terms"), fields):
+            object.__setattr__(self, name, v)
+
+    def __hash__(self) -> int:
+        if self.terms:
+            return hash(self.terms)
+        return hash((self.column, self.lo, self.hi, self.eq))
+
+    @property
+    def conjuncts(self) -> Tuple["Predicate", ...]:
+        """The one-column terms this predicate is the AND of."""
+        return self.terms or (self,)
+
+    @property
+    def columns(self) -> Tuple[str, ...]:
+        """The columns the predicate reads, in term order."""
+        return tuple(dict.fromkeys(t.column for t in self.conjuncts))
 
     def mask(self, columns: Mapping[str, np.ndarray]) -> np.ndarray:
         """Boolean match mask over a dict of equal-length column arrays."""
+        if self.terms:
+            m = self.terms[0].mask(columns)
+            for t in self.terms[1:]:
+                m &= t.mask(columns)
+            return m
         if self.column not in columns:
             raise KeyError(
                 f"predicate column {self.column!r} not in sampled rows "
@@ -151,6 +274,8 @@ class Predicate:
         return m
 
     def describe(self) -> str:
+        if self.terms:
+            return " AND ".join(t.describe() for t in self.terms)
         parts = []
         if self.lo is not None:
             parts.append(f"{self.column} >= {self.lo:g}")
@@ -170,13 +295,19 @@ class Predicate:
         mask), while ``ZONE_PARTIAL`` only means "cannot decide from
         bounds" and falls back to the sampled-and-masked path.
 
+        A conjunction is judged per term: ``ZONE_EMPTY`` where any term is
+        provably empty, ``ZONE_FULL`` only where every term is provably
+        full, ``ZONE_PARTIAL`` otherwise.  A term on a column the bounds
+        do not cover is ``ZONE_PARTIAL`` for that term only.
+
         Parameters
         ----------
-        lo, hi : array_like
-            Inclusive per-block min / max of this predicate's column.
+        lo, hi : array_like, or mapping of str to array_like
+            Inclusive per-block min / max of this predicate's column; for
+            a conjunction, mappings from column name to those arrays.
         count : array_like, optional
             Per-block row counts; blocks with ``count == 0`` are
-            ``ZONE_EMPTY`` regardless of bounds.
+            ``ZONE_EMPTY`` under every term whose column has bounds.
 
         Returns
         -------
@@ -189,7 +320,26 @@ class Predicate:
         >>> p = Predicate(column="day", eq=2.0)
         >>> p.interval_status([0., 2., 1.], [1., 2., 3.]).tolist()
         [0, 2, 1]
+        >>> q = Predicate(terms=[{"column": "day", "eq": 2.0},
+        ...                      {"column": "qty", "hi": 24.0}])
+        >>> q.interval_status({"day": [0., 2., 2.], "qty": [1., 1., 30.]},
+        ...                   {"day": [1., 2., 2.], "qty": [9., 9., 40.]}
+        ...                   ).tolist()
+        [0, 2, 0]
         """
+        if self.terms:
+            known = [t.interval_status(lo[t.column], hi[t.column], count)
+                     for t in self.terms if t.column in lo]
+            shape = (np.shape(known[0]) if known
+                     else np.shape(count) if count is not None else ())
+            out = np.full(shape, ZONE_PARTIAL, dtype=np.int8)
+            if len(known) == len(self.terms):
+                out[np.all([v == ZONE_FULL for v in known], axis=0)] = \
+                    ZONE_FULL
+            if known:
+                out[np.any([v == ZONE_EMPTY for v in known], axis=0)] = \
+                    ZONE_EMPTY
+            return out
         lo = np.asarray(lo, dtype=np.float64)
         hi = np.asarray(hi, dtype=np.float64)
         empty = np.zeros(lo.shape, dtype=bool)
@@ -222,8 +372,10 @@ class ZoneMap:
     """Per-block summary statistics for predicate pruning.
 
     A zone map keeps, for every block, the inclusive ``[lo, hi]`` value
-    bounds of each tracked column, the block's row count, and the measure
-    column's streaming moments (count, sum, sum of squares).  From those
+    bounds of each tracked column, the block's row count, and the
+    measure's streaming moments (count, sum, sum of squares; ``measure`` as
+    ``Measure.of`` takes it, so a derived measure's moments are of the
+    product).  From those
     bounds alone the planner can *prove* which blocks a ``Predicate``
     filters out entirely (``ZONE_EMPTY``) or keeps entirely
     (``ZONE_FULL``) — the remaining ``ZONE_PARTIAL`` blocks are the only
@@ -244,9 +396,9 @@ class ZoneMap:
     [0, 2]
     """
 
-    def __init__(self, n_blocks: int, measure: str = "value"):
+    def __init__(self, n_blocks: int, measure="value"):
         self.n_blocks = int(n_blocks)
-        self.measure = measure
+        self.measure = Measure.of(measure)
         self.counts = np.zeros(self.n_blocks, dtype=np.int64)
         # column -> (lo, hi) inclusive bounds; empty blocks hold +/-inf so
         # any refresh widens them correctly.
@@ -257,7 +409,7 @@ class ZoneMap:
         self._status_cache: dict = {}
 
     @staticmethod
-    def from_tables(tables, measure: str = "value") -> "ZoneMap":
+    def from_tables(tables, measure="value") -> "ZoneMap":
         """Build a zone map from per-block column dicts (the same tables
         ``multiquery.table_sampler`` wraps)."""
         zm = ZoneMap(len(tables), measure=measure)
@@ -274,7 +426,8 @@ class ZoneMap:
     def refresh(self, block_id: int, columns: Mapping[str, np.ndarray]
                 ) -> None:
         """Fold a block's (new) rows into its zones — bounds only widen,
-        so refreshing with an append-only delta is exact."""
+        so refreshing with an append-only delta is exact.  The rows carry
+        the measure's columns (``KeyError`` otherwise)."""
         b = int(block_id)
         n = 0
         for name, col in columns.items():
@@ -286,10 +439,11 @@ class ZoneMap:
             lo, hi = self.columns[name]
             lo[b] = min(lo[b], float(col.min()))
             hi[b] = max(hi[b], float(col.max()))
-            if name == self.measure:
-                self.moments[b, 0] += col.size
-                self.moments[b, 1] += float(col.sum())
-                self.moments[b, 2] += float((col * col).sum())
+        if n:
+            vals = self.measure(columns)
+            self.moments[b, 0] += vals.size
+            self.moments[b, 1] += float(vals.sum())
+            self.moments[b, 2] += float((vals * vals).sum())
         self.counts[b] += n
         self.version += 1
         self._status_cache.clear()
@@ -299,8 +453,10 @@ class ZoneMap:
 
         ``None`` (no WHERE) is all-``ZONE_FULL``; a predicate over a
         column the map does not track is all-``ZONE_PARTIAL`` (no proof
-        available, so no pruning — never unsound).  Verdicts are cached
-        per (predicate, version).
+        available, so no pruning — never unsound).  A conjunction is
+        judged per term (``Predicate.interval_status``): a term on an
+        untracked column is ``ZONE_PARTIAL`` for that term only.  Verdicts
+        are cached per (predicate, version).
         """
         if predicate is None:
             return np.full(self.n_blocks, ZONE_FULL, dtype=np.int8)
@@ -308,7 +464,12 @@ class ZoneMap:
         hit = self._status_cache.get(key)
         if hit is not None:
             return hit
-        if predicate.column not in self.columns:
+        if predicate.terms:
+            out = predicate.interval_status(
+                {c: b[0] for c, b in self.columns.items()},
+                {c: b[1] for c, b in self.columns.items()},
+                count=self.counts)
+        elif predicate.column not in self.columns:
             out = np.full(self.n_blocks, ZONE_PARTIAL, dtype=np.int8)
         else:
             lo, hi = self.columns[predicate.column]
@@ -316,6 +477,11 @@ class ZoneMap:
         out.setflags(write=False)
         self._status_cache[key] = out
         return out
+
+    def judged(self, predicate: Optional[Predicate]) -> bool:
+        """True when ``status(predicate)`` is already cached at this
+        version (the planner's zone work for it is done)."""
+        return (predicate, self.version) in self._status_cache
 
 
 @dataclasses.dataclass(frozen=True)
@@ -513,7 +679,7 @@ class Anchor:
     def refine_for_predicate(self, pilot_columns: Mapping[str, np.ndarray],
                              where: Optional["Predicate"],
                              params: "IslaParams",
-                             measure: str = "value",
+                             measure="value",
                              min_support: int = 64) -> "Anchor":
         """Derive a per-predicate anchor from the matching pilot rows.
 
@@ -521,7 +687,9 @@ class Anchor:
         improve on it: no predicate, no pilot rows captured, the predicate
         matches *every* pilot row (the refined frame would be the global
         frame re-estimated), fewer than ``min_support`` matching rows, or
-        a degenerate (non-positive) matching sigma.
+        a degenerate (non-positive) matching sigma.  Pilot rows that lack
+        a column of the measure raise ``KeyError``: that is a fault, not
+        a reason to fall back.
 
         Parameters
         ----------
@@ -531,8 +699,9 @@ class Anchor:
             The key's WHERE clause.
         params : IslaParams
             Supplies the ``p1``/``p2`` boundary factors.
-        measure : str
-            Name of the aggregated column inside ``pilot_columns``.
+        measure : str, sequence of str or Measure
+            The aggregated value over ``pilot_columns`` (``Measure.of``:
+            one column, or the product of several).
         min_support : int
             Minimum matching pilot rows before the refined statistics are
             trusted over the global ones.
@@ -543,12 +712,13 @@ class Anchor:
             A ``source="refined"`` anchor over the matching rows, or
             ``self`` on fallback.
         """
-        if where is None or not pilot_columns or measure not in pilot_columns:
+        if where is None or not pilot_columns:
             return self
+        values = Measure.of(measure)(pilot_columns)
         m = np.asarray(where.mask(pilot_columns), dtype=bool)
         if m.size == 0 or bool(np.all(m)):
             return self
-        vals = np.asarray(pilot_columns[measure], dtype=np.float64)[m]
+        vals = values[m]
         if vals.size < max(int(min_support), 2):
             return self
         sigma = float(np.std(vals, ddof=1))
