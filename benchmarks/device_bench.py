@@ -10,9 +10,9 @@ Headlines (recorded in ``BENCH_device.json``):
  * **transfer counts** — a steady-state tick performs ZERO host<->device
    moment transfers: the whole tick runs under
    ``jax.transfer_guard("disallow")`` with only the sanctioned sample
-   uploads (``distributed.h2d``: quotas, value pane, pad mask, GROUP BY
-   pane — 4 sample-sized crossings) allowed, asserted by counting
-   ``h2d`` calls;
+   upload (``distributed.h2d``: quotas, value pane and GROUP BY pane
+   packed into one sample-sized crossing; the pad mask is derived in the
+   launch) allowed, asserted by counting ``h2d`` calls;
  * **dense fused launch** — ``kernels.isla_fused_pallas`` chains the
    Pallas Phase 1 accumulator (prior operand) into the branchless
    Phase 2 in one jit (latency probe; interpret-mode on CPU, the
@@ -187,8 +187,9 @@ def tick_speed(smoke=False):
 
 def transfer_counts(smoke=False):
     """Steady tick under transfer-guard: only the sanctioned sample
-    uploads cross host->device — 4 for the dense grouped layout run here
-    (quotas, value pane, pad mask, GROUP BY pane), all sample-sized."""
+    upload crosses host->device — one for the dense grouped layout run
+    here (quotas, value pane and GROUP BY pane in one packed buffer),
+    sample-sized."""
     import jax
 
     from repro.core import distributed as D
@@ -217,12 +218,12 @@ def transfer_counts(smoke=False):
             dev.ingest_tick(v, bi, q, params, group_ids=gi)
     finally:
         D.h2d = real_h2d
-    # Dense grouped tick ships: quotas, value pane, pad mask, GROUP BY
-    # pane — all sample-sized metadata, never moments.
-    if len(calls) != 4:
+    # Dense grouped tick ships one packed buffer: quotas, value pane,
+    # GROUP BY pane — sample-sized, never moments.
+    if len(calls) != 1:
         raise AssertionError(
-            f"steady tick made {len(calls)} h2d crossings, expected 4 "
-            "(quotas, values, pad mask, group codes)")
+            f"steady tick made {len(calls)} h2d crossings, expected 1 "
+            "(quotas, values and group codes packed together)")
     moment_bytes = int(np.asarray(dev.mom_s).nbytes
                        + np.asarray(dev.mom_l).nbytes)
     rows = [("steady_tick_h2d_crossings", 0.0, float(len(calls)))]

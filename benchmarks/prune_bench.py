@@ -14,10 +14,10 @@ Headlines (recorded in ``BENCH_prune.json``):
    BIT-IDENTICAL on every cell (active cells see the same adds, pruned
    cells are never addressed);
  * **transfer audit** — a steady pruned tick under
-   ``jax.transfer_guard("disallow")`` still makes exactly the 4
-   sanctioned sample-sized h2d crossings (compact quotas, value pane,
-   pad mask, GROUP BY pane): the cached scatter-index pair adds ZERO
-   steady-state uploads;
+   ``jax.transfer_guard("disallow")`` still makes exactly the one
+   sanctioned sample-sized h2d crossing (compact quotas, value pane and
+   GROUP BY pane packed together): the cached scatter-index pair adds
+   ZERO steady-state uploads;
  * **tick speed** — the compacted vs full dense tick at 1% active
    blocks (the pane shrinks ~B/active-fold, so should the launch).
 
@@ -159,7 +159,7 @@ def residual_parity(smoke=False):
 
 
 def transfer_audit(smoke=False):
-    """Steady pruned tick under transfer-guard: 4 sanctioned crossings.
+    """Steady pruned tick under transfer-guard: 1 sanctioned crossing.
 
     A single grouped store (the same shape ``device_bench``'s audit
     uses — the multi-store stat-slice path is host-side either way), so
@@ -198,10 +198,10 @@ def transfer_audit(smoke=False):
             tick()
     finally:
         D.h2d = real_h2d
-    if len(calls) != 4:
+    if len(calls) != 1:
         raise AssertionError(
             f"steady pruned tick made {len(calls)} h2d crossings, "
-            "expected 4 (compact quotas, values, pad mask, group codes)")
+            "expected 1 (compact quotas, values and group codes packed)")
     rows = [("steady_pruned_tick_h2d_crossings", 0.0, float(len(calls)))]
     return rows, {
         "sanctioned_h2d_per_tick": len(calls),

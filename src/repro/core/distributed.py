@@ -21,7 +21,7 @@ import contextlib
 import functools
 import threading
 import time
-from typing import Optional, Tuple
+from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -213,10 +213,12 @@ def h2d(x, dtype=None) -> jnp.ndarray:
     serving path.  Every array the steady-state tick ships to the device
     (fresh sample values and their segment tags — never moments) goes
     through here, so tests can count crossings and wrap the rest of the
-    tick in a ``jax.transfer_guard("disallow")``.  Its bytes are booked
-    under the ``h2d_bytes`` counter of the open stage's run (``count``)."""
+    tick in a ``jax.transfer_guard("disallow")``.  Each call and its bytes
+    are booked under the ``h2d_calls`` and ``h2d_bytes`` counters of the
+    open stage's run (``count``)."""
     with jax.transfer_guard("allow"):
         out = jnp.asarray(x, dtype=dtype)
+    count("h2d_calls", 1)
     count("h2d_bytes", out.nbytes)
     return out
 
@@ -683,20 +685,98 @@ def _dense_core(mom_s: jnp.ndarray, mom_l: jnp.ndarray,
     return mom_s, mom_l, totals, n_sampled, partials, rows
 
 
+class PaneLayout(NamedTuple):
+    """Where each operand of a dense tick sits in its ONE packed upload,
+    and the host packing and in-graph unpacking of that buffer.
+
+    The packed buffer is ``(pane_rows, width)`` in the stack's dtype: row
+    ``b`` holds block ``b``'s row of every pane side by side, in order the
+    value pane, each distinct predicate-mask pane, each distinct GROUP BY
+    code pane, each uint32 hash-limb pane, and then the block's quota in
+    one word; the row is zero up to whole lane tiles.  Float panes take
+    ``quota_max`` words; code and hash panes take ``quota_max`` 4-byte
+    words, stored bit for bit (``.view`` on the host,
+    ``bitcast_convert_type`` in-graph).  A block's samples and its quota
+    share its row.  The layout follows from shapes the compiled
+    programs already key on, so as a static argument it adds no
+    programs."""
+
+    pane_rows: int      # blocks in the launch (compact under pruning)
+    quota_max: int      # the pane bucket
+    n_valid: int = 0    # distinct predicate-mask panes
+    n_gid: int = 0      # distinct GROUP BY code panes
+    n_hash: int = 0     # hash-limb panes: (hi, lo) on a sketch stack
+
+    def spans(self, itemsize: int):
+        """Each pane's ``(first, stop)`` columns in order, then the quota
+        word's, for a buffer of ``itemsize``-byte words."""
+        fw, iw = self.quota_max, self.quota_max * 4 // itemsize
+        edges = [0]
+        for w in ([fw] * (1 + self.n_valid)
+                  + [iw] * (self.n_gid + self.n_hash) + [1]):
+            edges.append(edges[-1] + w)
+        return tuple(zip(edges[:-1], edges[1:]))
+
+    def shape(self, itemsize: int):
+        """Whole 128-word lane tiles: a TPU lays a narrower or ragged
+        row out column-major, and the upload would transpose it."""
+        return self.pane_rows, -(-self.spans(itemsize)[-1][1] // 128) * 128
+
+    def pack(self, dtype, quotas, values, valids=(), gids=(), hashes=()):
+        """The host buffer: each block-major stream written straight into
+        its pane in ``dtype``.  Row-major assignment through the
+        ragged-quota mask preserves stream order; numpy's cast on
+        assignment rounds as a device-dtype conversion does.  ``valids``
+        are predicate masks, ``gids`` int32 codes, ``hashes`` uint32
+        limbs, one stream each."""
+        itemsize = np.dtype(dtype).itemsize
+        buf = np.zeros(self.shape(itemsize), dtype=dtype)
+        cols = [buf[:, a:b] for a, b in self.spans(itemsize)]
+        vmask = (np.arange(self.quota_max)[None, :]
+                 < np.asarray(quotas)[:, None])
+        streams = ([(values, None)] + [(v, None) for v in valids]
+                   + [(g, np.int32) for g in gids]
+                   + [(h, np.uint32) for h in hashes])
+        for col, (src, kind) in zip(cols, streams):
+            pane = col if kind is None else col.view(kind)
+            pane[vmask] = np.asarray(src).reshape(-1)
+        cols[-1][:, 0] = quotas
+        return buf
+
+    def unpack(self, panes: jnp.ndarray):
+        """In-graph inverse of ``pack``: ``(values2d, pad_valid, quotas,
+        gid_panes, valid_panes, hash_panes)``.  The pad pane is not
+        uploaded: it is ``iota(quota_max) < quotas``, and under
+        zone-pruned compaction the quota column is already compact."""
+        dt = panes.dtype
+        n, q = self.pane_rows, self.quota_max
+        cols = [panes[:, a:b] for a, b in self.spans(dt.itemsize)]
+
+        def bits(block, to):
+            return jax.lax.bitcast_convert_type(block, to).reshape(n, q)
+
+        o, h = 1 + self.n_valid, 1 + self.n_valid + self.n_gid
+        pad_valid = (jnp.arange(q, dtype=dt)[None, :]
+                     < cols[-1]).astype(dt)
+        return (cols[0], pad_valid, cols[-1].reshape(n),
+                tuple(bits(c, jnp.int32) for c in cols[o:h]),
+                tuple(cols[1:o]),
+                tuple(bits(c, jnp.uint32) for c in cols[h:-1]))
+
+
 @functools.partial(
     jax.jit,
     static_argnames=("params", "mode", "geometry", "n_groups_list",
                      "gid_slots", "valid_slots", "key_affine",
-                     "bound_slots"),
+                     "bound_slots", "pane_layout"),
     donate_argnums=(0, 1, 2, 3))
 def fused_tick_dense(mom_s: jnp.ndarray, mom_l: jnp.ndarray,
                      totals: jnp.ndarray, n_sampled: jnp.ndarray,
-                     values2d: jnp.ndarray, pad_valid: jnp.ndarray,
-                     quotas: jnp.ndarray, gid_panes, valid_panes,
-                     bounds: jnp.ndarray, sketch0: jnp.ndarray,
-                     sizes: jnp.ndarray, inv_scale: jnp.ndarray = None,
+                     bounds: jnp.ndarray, panes: jnp.ndarray,
+                     sketch0: jnp.ndarray, sizes: jnp.ndarray,
+                     inv_scale: jnp.ndarray = None,
                      active_cells=None, *,
-                     params: IslaParams,
+                     params: IslaParams, pane_layout: PaneLayout,
                      mode: str = "calibrated", geometry=None,
                      n_groups_list=(1,), gid_slots=(-1,),
                      valid_slots=(-1,), key_affine=None,
@@ -704,8 +784,11 @@ def fused_tick_dense(mom_s: jnp.ndarray, mom_l: jnp.ndarray,
     """``fused_tick`` on the dense block-major layout (see
     ``_dense_core`` for the batched-contraction Phase 1, the static-slot
     pane sharing, and the ``active_cells`` compacted-launch contract;
-    this wrapper owns the jit + donation).  ``active_cells=None`` (an
-    empty pytree) keeps existing call sites on the identical trace."""
+    this wrapper owns the jit + donation and unpacks ``panes``, the
+    tick's one upload, per ``pane_layout``).  ``active_cells=None`` (an
+    empty pytree) keeps the uncompacted trace."""
+    values2d, pad_valid, quotas, gid_panes, valid_panes, _ = \
+        pane_layout.unpack(panes)
     return _dense_core(mom_s, mom_l, totals, n_sampled, values2d,
                        pad_valid, quotas, gid_panes, valid_panes, bounds,
                        sketch0, sizes, inv_scale, params=params, mode=mode,
@@ -858,28 +941,30 @@ def _sketch_dense_scatter(regs: jnp.ndarray, hash_hi2d: jnp.ndarray,
     jax.jit,
     static_argnames=("params", "mode", "geometry", "n_groups_list",
                      "gid_slots", "valid_slots", "key_affine",
-                     "bound_slots"),
+                     "bound_slots", "pane_layout"),
     donate_argnums=(0, 1, 2, 3, 4))
 def fused_tick_dense_sketch(mom_s: jnp.ndarray, mom_l: jnp.ndarray,
                             totals: jnp.ndarray, n_sampled: jnp.ndarray,
-                            regs: jnp.ndarray, values2d: jnp.ndarray,
-                            pad_valid: jnp.ndarray,
-                            hash_hi2d: jnp.ndarray,
-                            hash_lo2d: jnp.ndarray, quotas: jnp.ndarray,
-                            gid_panes, valid_panes, bounds: jnp.ndarray,
-                            sketch0: jnp.ndarray, sizes: jnp.ndarray,
+                            regs: jnp.ndarray, bounds: jnp.ndarray,
+                            panes: jnp.ndarray, sketch0: jnp.ndarray,
+                            sizes: jnp.ndarray,
                             inv_scale: jnp.ndarray = None,
                             active_cells=None, *, params: IslaParams,
+                            pane_layout: PaneLayout,
                             mode: str = "calibrated", geometry=None,
                             n_groups_list=(1,), gid_slots=(-1,),
                             valid_slots=(-1,), key_affine=None,
                             bound_slots=None):
     """``fused_tick_dense`` with the register plane riding the launch
     (see ``fused_tick_sketch`` for the state/return contract and
-    ``_sketch_dense_scatter`` for the pane-to-cell mapping).  Unlike the
-    moment delta — whose dense fold is a float vector add — the register
-    merge is an integer max, so the dense route keeps the tagged route's
-    bit-parity contract for the sketch plane even in fp32 serving."""
+    ``_sketch_dense_scatter`` for the pane-to-cell mapping); ``panes``
+    also carries the (hi, lo) hash-limb panes.  Unlike the moment delta
+    — whose dense fold is a float vector add — the register merge is an
+    integer max, so the dense route keeps the tagged route's bit-parity
+    contract for the sketch plane even in fp32 serving."""
+    values2d, pad_valid, quotas, gid_panes, valid_panes, (hash_hi2d,
+                                                          hash_lo2d) = \
+        pane_layout.unpack(panes)
     mom_s, mom_l, totals, n_sampled, partials, rows = _dense_core(
         mom_s, mom_l, totals, n_sampled, values2d, pad_valid, quotas,
         gid_panes, valid_panes, bounds, sketch0, sizes, inv_scale,
@@ -950,12 +1035,13 @@ def mesh_h2d(mesh, x, spec, dtype=None) -> jnp.ndarray:
     for cell-sharded operands, ``P()`` for the replicated sample stream.
     Everything the steady-state mesh tick ships to devices goes through
     here so tests can wrap the rest in ``jax.transfer_guard``.  The
-    array's global bytes are booked like ``h2d``'s.
+    call and the array's global bytes are booked like ``h2d``'s.
     """
     from jax.sharding import NamedSharding
     with jax.transfer_guard("allow"):
         out = jax.device_put(jnp.asarray(x, dtype=dtype),
                              NamedSharding(mesh, spec))
+    count("h2d_calls", 1)
     count("h2d_bytes", out.nbytes)
     return out
 

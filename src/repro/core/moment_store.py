@@ -444,9 +444,10 @@ def _bucket(m: int, floor: int = 256) -> int:
 
 def _dense_panes(values: np.ndarray, quotas: np.ndarray):
     """Pack a block-major tagged stream into (n_blocks, quota_bucket)
-    panes for the dense fused tick: row-major assignment through the
-    ragged-quota mask preserves stream order, the pad mask zeroes the
-    tail."""
+    panes for the mesh's dense fused tick (one upload each; the
+    single-device tick packs all of them with ``_packed_panes``):
+    row-major assignment through the ragged-quota mask preserves stream
+    order, the pad mask zeroes the tail."""
     quotas = np.asarray(quotas, dtype=np.int64)
     qmax = _bucket(int(quotas.max()), floor=8)
     vmask = np.arange(qmax)[None, :] < quotas[:, None]
@@ -457,42 +458,70 @@ def _dense_panes(values: np.ndarray, quotas: np.ndarray):
     return v2d, pad, vmask
 
 
-def _key_panes(vmask: np.ndarray, key_gids, key_valids):
-    """Each stacked key's GROUP BY code and predicate-mask panes for the
-    dense tick, deduped by host-array identity: ``(gid panes, gid slots,
-    mask panes, mask slots)``, slot -1 where a key has none.  One pane per
-    distinct array, and the STATIC slot indices let the fused program
-    batch keys that share a GROUP BY pane into one contraction
-    (traced-operand identity is invisible inside jit)."""
-    gid_panes, valid_panes = [], []
+def _key_slots(key_gids, key_valids):
+    """Each stacked key's GROUP BY codes and predicate mask, deduped by
+    host-array identity: ``(distinct codes, gid slots, distinct masks,
+    mask slots)``, slot -1 where a key has none.  One pane per distinct
+    array, and the STATIC slot indices let the fused program batch keys
+    that share a GROUP BY pane into one contraction (traced-operand
+    identity is invisible inside jit)."""
+    gids_out, valids_out = [], []
     gid_slots, valid_slots = [], []
     seen_g, seen_v = {}, {}
     for gids, valid in zip(key_gids, key_valids):
-        if gids is None:
-            gid_slots.append(-1)
-        elif id(gids) in seen_g:
-            gid_slots.append(seen_g[id(gids)])
-        else:
-            g2d = np.zeros(vmask.shape, dtype=np.int32)
-            g2d[vmask] = np.asarray(gids).reshape(-1)
-            seen_g[id(gids)] = len(gid_panes)
-            gid_slots.append(len(gid_panes))
-            gid_panes.append(g2d)
-        if valid is None:
-            valid_slots.append(-1)
-        elif id(valid) in seen_v:
-            valid_slots.append(seen_v[id(valid)])
-        else:
-            m2d = np.zeros(vmask.shape, dtype=np.float64)
-            m2d[vmask] = np.asarray(valid, dtype=np.float64).reshape(-1)
-            seen_v[id(valid)] = len(valid_panes)
-            valid_slots.append(len(valid_panes))
-            valid_panes.append(m2d)
-    return gid_panes, tuple(gid_slots), valid_panes, tuple(valid_slots)
+        for arr, seen, out, slots in ((gids, seen_g, gids_out, gid_slots),
+                                      (valid, seen_v, valids_out,
+                                       valid_slots)):
+            if arr is None:
+                slots.append(-1)
+                continue
+            if id(arr) not in seen:
+                seen[id(arr)] = len(out)
+                out.append(arr)
+            slots.append(seen[id(arr)])
+    return gids_out, tuple(gid_slots), valids_out, tuple(valid_slots)
+
+
+def _key_panes(vmask: np.ndarray, key_gids, key_valids):
+    """The dense tick's deduped GROUP BY code and predicate-mask panes
+    (``_key_slots``): ``(gid panes, gid slots, mask panes, mask slots)``,
+    int32 codes and float64 masks, zero in the ragged tail."""
+    gids, gid_slots, valids, valid_slots = _key_slots(key_gids, key_valids)
+    gid_panes, valid_panes = [], []
+    for g in gids:
+        g2d = np.zeros(vmask.shape, dtype=np.int32)
+        g2d[vmask] = np.asarray(g).reshape(-1)
+        gid_panes.append(g2d)
+    for valid in valids:
+        m2d = np.zeros(vmask.shape, dtype=np.float64)
+        m2d[vmask] = np.asarray(valid, dtype=np.float64).reshape(-1)
+        valid_panes.append(m2d)
+    return gid_panes, gid_slots, valid_panes, valid_slots
+
+
+def _packed_panes(pane_vals: np.ndarray, quotas: np.ndarray, key_gids,
+                  key_valids, dtype, hash_values=None):
+    """The single-device dense tick's one upload: every pane operand of
+    the deduped keys (``_key_slots``) packed per ``distributed.PaneLayout``
+    in the stack's ``dtype``.  No pad pane: the launch derives it from the
+    quota column.  ``hash_values`` (a sketch stack's RAW stream) adds the
+    (hi, lo) hash-limb panes.  Returns ``(buffer, layout, gid slots, mask
+    slots)``."""
+    from . import distributed as D
+
+    quotas = np.asarray(quotas, dtype=np.int64)
+    gids, gid_slots, valids, valid_slots = _key_slots(key_gids, key_valids)
+    hashes = (() if hash_values is None
+              else _sketch.value_limbs(hash_values))
+    layout = D.PaneLayout(quotas.size, _bucket(int(quotas.max()), floor=8),
+                          len(valids), len(gids), len(hashes))
+    buf = layout.pack(dtype, quotas, pane_vals, valids, gids, hashes)
+    return buf, layout, gid_slots, valid_slots
 
 
 def _hash_panes(values: np.ndarray, vmask: np.ndarray):
-    """The dense tick's (hi, lo) register-hash panes of the RAW values."""
+    """The mesh dense tick's (hi, lo) register-hash panes of the RAW
+    values."""
     hhi, hlo = _sketch.value_limbs(values)
     hi2d = np.zeros(vmask.shape, dtype=np.uint32)
     lo2d = np.zeros(vmask.shape, dtype=np.uint32)
@@ -1298,7 +1327,7 @@ class DeviceStack:
         uncompacted launch — the identical pre-pruning graph.  The
         device-resident scatter index pair is cached per active set, so
         steady-state ticks under an unchanged plan upload only the usual
-        four sample-sized operands.
+        packed sample panes.
         """
         if not self.block_compaction:
             return None
@@ -1463,8 +1492,8 @@ class DeviceStack:
             # Zone-pruned plans zero whole blocks' quotas; the draw
             # stream already skips those blocks, so the pane compacts to
             # the active rows and the delta scatters back through the
-            # cached index pair.  The quota row crosses in compact form
-            # too — the launch never sees the pruned axis.
+            # cached index pair.  The quotas cross in compact form too —
+            # the launch never sees the pruned axis.
             cp = self._compact_plan(quotas)
             if cp is not None:
                 pane_quotas, _, active_cells = cp
@@ -1472,55 +1501,36 @@ class DeviceStack:
                 pane_quotas, active_cells = quotas, None
             with D.stage_trace("h2d", timings):
                 with D.stage_trace("h2d.pane", timings):
-                    v2d, pad, vmask = _dense_panes(pane_vals, pane_quotas)
-                    D.count("pane_slots", v2d.size)
-                    gid_host, gid_slots, valid_host, valid_slots = \
-                        _key_panes(vmask, key_gids, key_valids)
-                    if self.has_sketch:
-                        # Hash panes from the RAW dense stream (the pane
-                        # is anchor-scaled; registers key on raw bits).
-                        hi2d, lo2d = _hash_panes(values, vmask)
+                    # Hash panes key on the RAW dense stream (the value
+                    # pane is anchor-scaled; registers key on raw bits).
+                    panes, layout, gid_slots, valid_slots = _packed_panes(
+                        pane_vals, pane_quotas, key_gids, key_valids,
+                        self.dtype, values if self.has_sketch else None)
+                    D.count("pane_slots",
+                            layout.pane_rows * layout.quota_max)
                 with D.stage_trace("h2d.put", timings):
-                    q_dev = D.h2d(pane_quotas.astype(np.float64),
-                                  self.dtype)
-                    gid_panes = tuple(D.h2d(g, jnp.int32)
-                                      for g in gid_host)
-                    valid_panes = tuple(D.h2d(m, self.dtype)
-                                        for m in valid_host)
-                    v_dev = D.h2d(v2d, self.dtype)
-                    pad_dev = D.h2d(pad, self.dtype)
-                    if self.has_sketch:
-                        hhi_dev = D.h2d(hi2d, jnp.uint32)
-                        hlo_dev = D.h2d(lo2d, jnp.uint32)
+                    panes_dev = D.h2d(panes, self.dtype)
             group_regs = None
+            static = dict(params=params, mode=mode, geometry=geometry,
+                          n_groups_list=self.n_groups_list,
+                          gid_slots=gid_slots, valid_slots=valid_slots,
+                          key_affine=key_affine,
+                          bound_slots=self._bound_slots,
+                          pane_layout=layout)
             with D.stage_trace("launch", timings):
+                operands = (self._bound_rows, panes_dev,
+                            self._sketch0_cells(), self._sizes,
+                            self._inv_scale, active_cells)
                 if self.has_sketch:
                     (mom_s, mom_l, totals, ns, regs, partials, rows,
                      group_regs) = D.fused_tick_dense_sketch(
                         mom_s, mom_l, totals, ns, self._regs_state,
-                        v_dev, pad_dev, hhi_dev, hlo_dev, q_dev,
-                        gid_panes, valid_panes,
-                        self._bound_rows, self._sketch0_cells(),
-                        self._sizes, self._inv_scale, active_cells,
-                        params=params, mode=mode, geometry=geometry,
-                        n_groups_list=self.n_groups_list,
-                        gid_slots=gid_slots, valid_slots=valid_slots,
-                        key_affine=key_affine,
-                        bound_slots=self._bound_slots)
+                        *operands, **static)
                     self._regs_state = regs
                 else:
                     mom_s, mom_l, totals, ns, partials, rows = \
-                        D.fused_tick_dense(
-                            mom_s, mom_l, totals, ns, v_dev,
-                            pad_dev, q_dev, gid_panes, valid_panes,
-                            self._bound_rows,
-                            self._sketch0_cells(), self._sizes,
-                            self._inv_scale, active_cells,
-                            params=params, mode=mode, geometry=geometry,
-                            n_groups_list=self.n_groups_list,
-                            gid_slots=gid_slots, valid_slots=valid_slots,
-                            key_affine=key_affine,
-                            bound_slots=self._bound_slots)
+                        D.fused_tick_dense(mom_s, mom_l, totals, ns,
+                                           *operands, **static)
         else:
             seg = np.asarray(seg, dtype=np.int32).reshape(-1)
             if values.shape != seg.shape:

@@ -371,9 +371,9 @@ _STAGES = ("admit", "plan", "plan.zone", "draw", "draw.stack",
            "draw.sample", "draw.measure", "draw.mask", "h2d", "h2d.pane",
            "h2d.put", "launch", "readback", "compose")
 # Counters a run books beside its stage clock (``last_stage_times.
-# counts``): value-pane slots uploaded, bytes handed to the uploads, and
-# device stores built (one launch each).
-_COUNTERS = ("pane_slots", "h2d_bytes", "store_builds")
+# counts``): value-pane slots uploaded, uploads and the bytes handed to
+# them, and device stores built (one launch each).
+_COUNTERS = ("pane_slots", "h2d_calls", "h2d_bytes", "store_builds")
 
 
 class _StagedGroup:

@@ -235,10 +235,11 @@ class IslaAdmissionLoop:
         self.subsumed = 0       # tickets served from the answer cache
         # Per-stage seconds: the loop's own ``admit`` (the self time of
         # every tick) plus each executed tick's run() stage clock; and
-        # the run's counters (pane slots and bytes uploaded, device
-        # stores built).
+        # the run's counters (pane slots, uploads and bytes uploaded,
+        # device stores built).
         self.stage_seconds: "dict[str, float]" = {}
         self.pane_slots = 0
+        self.h2d_calls = 0
         self.h2d_bytes = 0
         self.store_builds = 0
 
@@ -277,6 +278,7 @@ class IslaAdmissionLoop:
             "answers_cached": getattr(ex, "answers_cached", 0),
             "plans_prefetched": getattr(ex, "plans_prefetched", 0),
             "pane_slots": self.pane_slots,
+            "h2d_calls": self.h2d_calls,
             "h2d_bytes": self.h2d_bytes,
             "store_builds": self.store_builds,
             "stage_seconds": dict(self.stage_seconds),
@@ -394,6 +396,7 @@ class IslaAdmissionLoop:
                 self.stage_seconds[k] = self.stage_seconds.get(k, 0.0) + v
             counts = getattr(times, "counts", {})
             self.pane_slots += counts.get("pane_slots", 0)
+            self.h2d_calls += counts.get("h2d_calls", 0)
             self.h2d_bytes += counts.get("h2d_bytes", 0)
             self.store_builds += counts.get("store_builds", 0)
             seen_passes = set()

@@ -116,9 +116,10 @@ def _counting_h2d(calls):
 @pytest.mark.transfer_guard
 def test_steady_tick_zero_moment_transfers(rng, monkeypatch):
     """Acceptance: the steady-state device tick runs under
-    ``jax.transfer_guard("disallow")`` with only the sanctioned sample
-    uploads crossing (values, pad mask, quotas, GROUP BY pane — all
-    sample-sized), and the resident moments never ship."""
+    ``jax.transfer_guard("disallow")`` with ONE sanctioned sample upload
+    crossing (the packed quotas, values and GROUP BY pane — sample-sized;
+    the pad mask is derived in the launch), booked as one ``h2d_calls``,
+    and the resident moments never ship."""
     import jax
 
     from repro.core import distributed as D
@@ -134,10 +135,20 @@ def test_steady_tick_zero_moment_transfers(rng, monkeypatch):
                                                masked=False)
     with jax.transfer_guard("disallow"):
         dev.ingest_tick(vals, bids, quotas, PARAMS, group_ids=gids)
-    assert len(calls) == 4  # quotas, values, pad mask, group codes
-    # Every crossing is sample-sized (float64 host pane, <= 2x bucket
-    # padding) — nothing remotely moment-shaped ships.
-    assert max(calls) <= 8 * 2 * vals.size
+    assert len(calls) == 1  # quotas, values, group codes: one buffer
+    # The crossing is sample-sized (<= 2x bucket padding, within the
+    # bound each float64 pane was once held to) — nothing remotely
+    # moment-shaped ships.
+    assert calls[0] <= 8 * 2 * vals.size
+    # The run's counter books the same single upload.
+    clock = D.StageClock(("h2d", "h2d.pane", "h2d.put", "launch",
+                          "readback"), ("h2d_calls", "h2d_bytes"))
+    with jax.transfer_guard("disallow"):
+        dev._own_stack().tick(PARAMS, values=vals - dev.shift,
+                              quotas=quotas, dense=([gids], [None]),
+                              timings=clock)
+    assert clock.counts["h2d_calls"] == 1
+    assert clock.counts["h2d_bytes"] == calls[1] == calls[0]
     # Zero-draw warm repeat: answered from the stats cache — NO h2d,
     # no launch, not even a transfer-guard scope entered.
     calls.clear()

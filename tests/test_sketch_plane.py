@@ -330,6 +330,48 @@ def test_warm_distinct_tick_moves_zero_register_bytes(rng, monkeypatch):
     assert calls == []
 
 
+@pytest.mark.transfer_guard
+def test_warm_dense_distinct_tick_is_one_upload(rng, monkeypatch):
+    """The steady DENSE sketch tick (a block-major stream, the fp32
+    serving layout) ships its quotas, values, GROUP BY codes and both
+    hash limb panes in ONE upload under ``transfer_guard("disallow")``,
+    booked as one ``h2d_calls`` — and the register plane never crosses."""
+    from repro.core import distributed as D
+
+    n_blocks, n_groups, quota = 40, 8, 50
+    sizes = [10 ** 6] * n_blocks
+    dev = DeviceMomentStore.fresh_device(n_blocks, BOUNDS, 100.0, sizes,
+                                         n_groups=n_groups,
+                                         has_sketch=True)
+    stack = DeviceStack([dev])
+    quotas = np.full(n_blocks, quota, np.int64)
+
+    def tick(timings=None):
+        vals = np.round(rng.normal(100.0, 20.0, n_blocks * quota))
+        gids = rng.integers(0, n_groups, vals.size)
+        stack.tick(PARAMS, values=vals, quotas=quotas,
+                   dense=([gids], [None]), timings=timings)
+        return vals.size
+
+    tick()                                      # warm / compile
+    calls = []
+    monkeypatch.setattr(D, "h2d", _counting_h2d(calls))
+    clock = D.StageClock(("h2d", "h2d.pane", "h2d.put", "launch",
+                          "readback"), ("h2d_calls", "h2d_bytes"))
+    with jax.transfer_guard("disallow"):
+        n = tick(clock)
+        folded = dev.group_registers()
+    assert folded.shape == (n_groups, SK.M)
+    assert len(calls) == 1
+    assert clock.counts["h2d_calls"] == 1
+    assert clock.counts["h2d_bytes"] == calls[0]
+    # Four panes of 4-byte words (values, codes, two hash limbs) at
+    # <= 2x bucket pad, plus the quota word and the row's lane-tile pad
+    # (< 128 words a block): sample-sized, far below the register plane.
+    assert calls[0] <= 4 * 4 * 2 * n + 4 * 128 * n_blocks
+    assert calls[0] < n_blocks * n_groups * SK.M
+
+
 # ----------------------------------------- cross-process determinism
 
 _SUBPROC = r"""

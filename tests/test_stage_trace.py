@@ -255,17 +255,29 @@ def test_pipelined_stages_book_every_key():
 @pytest.mark.parametrize("route", ["device", "mesh"])
 def test_upload_counters(route):
     """A drawing tick uploads a value pane at least as large as its
-    samples, and its bytes are counted."""
+    samples, and its uploads and their bytes are counted: on one device
+    a tick that builds no store (tick 2 repeats tick 0's WHERE) ships
+    its samples in one upload."""
     loop = _loop(route)
-    for i in range(2):
+    warm_ticks = 0
+    for i in range(3):
         _, _, _, before, after = _tick(loop, i)
         drawn = after["samples_drawn"] - before["samples_drawn"]
         slots = after["pane_slots"] - before["pane_slots"]
+        calls = after["h2d_calls"] - before["h2d_calls"]
+        builds = after["store_builds"] - before["store_builds"]
         assert drawn > 0
         assert slots >= drawn
         assert after["h2d_bytes"] - before["h2d_bytes"] >= 4 * slots
+        assert calls >= 1 + builds
+        if route == "device" and builds == 0:
+            warm_ticks += 1
+            assert calls == 1
+    if route == "device":
+        assert warm_ticks == 1
     counts = loop.executor.last_stage_times.counts
-    assert set(counts) == {"pane_slots", "h2d_bytes", "store_builds"}
+    assert set(counts) == {"pane_slots", "h2d_calls", "h2d_bytes",
+                           "store_builds"}
 
 
 def test_host_route_uploads_nothing():

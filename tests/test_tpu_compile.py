@@ -36,6 +36,10 @@ DENSE_STATIC = dict(params=PARAMS, mode="calibrated", geometry=None,
                     n_groups_list=N_GROUPS, gid_slots=(-1, 0, 0, -1),
                     valid_slots=(-1, -1, 0, 0), key_affine=None,
                     bound_slots=None)
+# The tick's one packed upload: the value pane, the shared predicate and
+# GROUP BY panes and the quota column (the sketch twin adds two hash panes).
+PANES = D.PaneLayout(N_BLOCKS, QUOTA, n_valid=1, n_gid=1)
+SKETCH_PANES = PANES._replace(n_hash=2)
 
 
 @pytest.fixture(scope="module")
@@ -122,13 +126,11 @@ def test_pallas_kernel_compiles_for_v5e(name, one_chip):
     assert "tpu_custom_call" in compiled.as_text()  # Mosaic, not a fallback
 
 
-def _dense_args(spec):
-    f32 = jnp.float32
-    c, b, q, k = N_CELLS, N_BLOCKS, QUOTA, len(N_GROUPS)
+def _dense_args(spec, layout=PANES):
+    c, b, k = N_CELLS, N_BLOCKS, len(N_GROUPS)
     return (spec((c, 4)), spec((c, 4)), spec((c, 3)), spec((k * b,)),
-            spec((b, q)), spec((b, q)), spec((b,)),
-            (spec((b, q), jnp.int32),), (spec((b, q)),), spec((1, 4)),
-            spec((c,)), spec((k * b,)), spec((c,), f32))
+            spec((1, 4)), spec(layout.shape(4)), spec((c,)),
+            spec((k * b,)), spec((c,)))
 
 
 def test_fused_tick_dense_compiles_at_deployment_shape(one_chip):
@@ -136,8 +138,8 @@ def test_fused_tick_dense_compiles_at_deployment_shape(one_chip):
     keeps full fp32 precision (a default-precision f32 dot rounds its
     operands to bf16 on a TPU)."""
     args = _dense_args(lambda s, dt=jnp.float32: _spec(s, one_chip, dt))
-    text = D.fused_tick_dense.lower(*args, **DENSE_STATIC).compile(
-        ).as_text()
+    text = D.fused_tick_dense.lower(*args, **DENSE_STATIC,
+                                    pane_layout=PANES).compile().as_text()
     contraction = [ln for ln in text.splitlines()
                    if 'op_name="jit(fused_tick_dense)/dot_general"' in ln
                    and ("convolution(" in ln or " dot(" in ln)]
@@ -148,13 +150,10 @@ def test_fused_tick_dense_compiles_at_deployment_shape(one_chip):
 
 def test_fused_tick_dense_sketch_compiles_at_deployment_shape(one_chip):
     spec = (lambda s, dt=jnp.float32: _spec(s, one_chip, dt))
-    a = _dense_args(spec)
-    pane = (N_BLOCKS, QUOTA)
-    args = (a[:4] + (spec((N_CELLS, 4096), jnp.uint8), a[4], a[5],
-                     spec(pane, jnp.uint32), spec(pane, jnp.uint32), a[6])
-            + a[7:])
-    compiled = D.fused_tick_dense_sketch.lower(*args,
-                                               **DENSE_STATIC).compile()
+    a = _dense_args(spec, SKETCH_PANES)
+    args = a[:4] + (spec((N_CELLS, 4096), jnp.uint8),) + a[4:]
+    compiled = D.fused_tick_dense_sketch.lower(
+        *args, **DENSE_STATIC, pane_layout=SKETCH_PANES).compile()
     assert compiled.memory_analysis() is not None
 
 
