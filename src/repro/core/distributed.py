@@ -472,17 +472,36 @@ def _sample_bounds(bounds: jnp.ndarray, seg: jnp.ndarray):
     return bs[:, 0], bs[:, 1], bs[:, 2], bs[:, 3]
 
 
+def _solve_rows(mom_s: jnp.ndarray, mom_l: jnp.ndarray,
+                totals: jnp.ndarray, n_sampled: jnp.ndarray,
+                sketch0: jnp.ndarray, sizes: jnp.ndarray,
+                inv_scale: jnp.ndarray, *, params: IslaParams, mode: str,
+                geometry, n_groups_list):
+    """Phase 2 over the resident state and its group stat rows: the
+    common tail of every tick program, ``(partials, rows)``."""
+    thr, geometry = _scaled_solve_args(params, geometry, inv_scale)
+    partials = phase2(mom_s, mom_l, sketch0, params, mode=mode,
+                      geometry=geometry, thr=thr)
+    rows = group_row_stats(mom_s, mom_l, totals, partials, n_sampled,
+                           sizes, n_groups_list,
+                           float(params.min_region_count))
+    return partials, rows
+
+
 def _tick_core(mom_s: jnp.ndarray, mom_l: jnp.ndarray,
                totals: jnp.ndarray, n_sampled: jnp.ndarray,
                values: jnp.ndarray, seg: jnp.ndarray, quotas: jnp.ndarray,
                bounds: jnp.ndarray, sketch0: jnp.ndarray,
-               sizes: jnp.ndarray, inv_scale: jnp.ndarray, *,
-               params: IslaParams, mode: str, geometry,
+               sizes: jnp.ndarray, inv_scale: jnp.ndarray, regs=None,
+               hashes=None, *, params: IslaParams, mode: str, geometry,
                n_groups_list):
     """The tagged tick body shared by the single-device ``fused_tick``
     and the per-shard program of the mesh launch (``mesh_tick_fn``) —
     the rows come back UNREDUCED across shards (the mesh wrapper psums
-    them; single-device they already cover every cell)."""
+    them; single-device they already cover every cell).  A register
+    plane ``regs`` takes each sample's ``hashes`` limbs at its cell id
+    (pad ids drop).  Returns ``(mom_s', mom_l', totals', n_sampled',
+    regs', partials, rows)``."""
     n_cells = mom_s.shape[0]
     # One 11-column carry-prepend scatter folds the whole pass: S and L
     # region moments plus the plain totals, each column's fold order
@@ -505,31 +524,37 @@ def _tick_core(mom_s: jnp.ndarray, mom_l: jnp.ndarray,
     mom_s, mom_l = merged[:, 0:4], merged[:, 4:8]
     totals = merged[:, 8:11]
     n_sampled = n_sampled + jnp.tile(quotas, len(n_groups_list))
-    thr, geometry = _scaled_solve_args(params, geometry, inv_scale)
-    partials = phase2(mom_s, mom_l, sketch0, params, mode=mode,
-                      geometry=geometry, thr=thr)
-    rows = group_row_stats(mom_s, mom_l, totals, partials, n_sampled,
-                           sizes, n_groups_list,
-                           float(params.min_region_count))
-    return mom_s, mom_l, totals, n_sampled, partials, rows
+    if regs is not None:
+        j, rho = _sketch_encode(*hashes)
+        regs = regs.at[seg, j].max(rho, mode="drop")
+    partials, rows = _solve_rows(
+        mom_s, mom_l, totals, n_sampled, sketch0, sizes, inv_scale,
+        params=params, mode=mode, geometry=geometry,
+        n_groups_list=n_groups_list)
+    return mom_s, mom_l, totals, n_sampled, regs, partials, rows
+
+
+# The tick state: the moment quadruple and the optional register plane,
+# donated by name so the plane can ride as a keyword operand.
+_STATE = ("mom_s", "mom_l", "totals", "n_sampled", "regs")
 
 
 @functools.partial(
     jax.jit,
     static_argnames=("params", "mode", "geometry", "n_groups_list"),
-    donate_argnums=(0, 1, 2, 3))
+    donate_argnames=_STATE)
 def fused_tick(mom_s: jnp.ndarray, mom_l: jnp.ndarray,
                totals: jnp.ndarray, n_sampled: jnp.ndarray,
                values: jnp.ndarray, seg: jnp.ndarray, quotas: jnp.ndarray,
                bounds: jnp.ndarray, sketch0: jnp.ndarray,
-               sizes: jnp.ndarray, inv_scale: jnp.ndarray = None, *,
-               params: IslaParams,
+               sizes: jnp.ndarray, inv_scale: jnp.ndarray = None,
+               regs=None, hashes=None, *, params: IslaParams,
                mode: str = "calibrated", geometry=None,
                n_groups_list=(1,)):
     """One device-resident continuation round as a single fused launch.
 
-    The four leading state operands are DONATED: the tick consumes the
-    resident buffers and returns their successors, so steady state never
+    The state operands are DONATED: the tick consumes the resident
+    buffers and returns their successors, so steady state never
     re-ships moments host<->device — the fresh ``values``/``seg``/
     ``quotas`` sample upload is the only h2d crossing, and only the
     per-group stats rows and per-cell partial answers come back.
@@ -544,13 +569,20 @@ def fused_tick(mom_s: jnp.ndarray, mom_l: jnp.ndarray,
     a per-cell (+pad) table for per-key anchors, and ``inv_scale`` is the
     per-cell anchor-scale vector the stopping threshold rides.
 
-    Returns ``(mom_s', mom_l', totals', n_sampled', partials, rows)`` —
-    ``rows`` per ``group_row_stats``.
+    ``regs`` is the optional (n_cells, m) uint8 HLL register plane and
+    ``hashes`` the samples' (hi, lo) raw-bit limbs, aligned with
+    ``values`` / ``seg`` (see ``_sketch_encode``).
+
+    Returns ``(mom_s', mom_l', totals', n_sampled', regs', partials, rows,
+    group_regs)`` — ``rows`` per ``group_row_stats``, ``group_regs`` the
+    folded per-group register rows (the only register bytes that ever
+    read back); both register entries are None without a plane.
     """
-    return _tick_core(mom_s, mom_l, totals, n_sampled, values, seg,
-                      quotas, bounds, sketch0, sizes, inv_scale,
-                      params=params, mode=mode, geometry=geometry,
-                      n_groups_list=n_groups_list)
+    state = _tick_core(mom_s, mom_l, totals, n_sampled, values, seg,
+                       quotas, bounds, sketch0, sizes, inv_scale, regs,
+                       hashes, params=params, mode=mode, geometry=geometry,
+                       n_groups_list=n_groups_list)
+    return (*state, _sketch_fold(state[4], n_groups_list))
 
 
 def _dense_core(mom_s: jnp.ndarray, mom_l: jnp.ndarray,
@@ -561,7 +593,7 @@ def _dense_core(mom_s: jnp.ndarray, mom_l: jnp.ndarray,
                 sizes: jnp.ndarray, inv_scale: jnp.ndarray, *,
                 params: IslaParams, mode: str, geometry,
                 n_groups_list, gid_slots, valid_slots, key_affine,
-                bound_slots, active_cells=None):
+                bound_slots, active_cells=None, regs=None, hashes=None):
     """The dense tick body shared by the single-device
     ``fused_tick_dense`` and the per-shard program of the mesh launch
     (``mesh_tick_dense_fn``); rows come back unreduced across shards.
@@ -611,6 +643,11 @@ def _dense_core(mom_s: jnp.ndarray, mom_l: jnp.ndarray,
     uniform-anchor stack traces the identical graph as before;
     ``inv_scale`` is the per-cell anchor-scale vector the stopping
     threshold and ISLA-E ``b0`` ride.
+
+    A register plane ``regs`` takes the (hi, lo) ``hashes`` panes
+    through ``_sketch_dense_scatter``, compacted like the moments.
+    Returns ``(mom_s', mom_l', totals', n_sampled', regs', partials,
+    rows)``.
     """
     dt = mom_s.dtype
     n_keys = len(n_groups_list)
@@ -676,13 +713,17 @@ def _dense_core(mom_s: jnp.ndarray, mom_l: jnp.ndarray,
         totals = totals.at[cell_idx].add(delta[:, 8:11], mode="drop")
         n_sampled = n_sampled.at[ns_idx].add(
             jnp.tile(quotas, len(n_groups_list)), mode="drop")
-    thr, geometry = _scaled_solve_args(params, geometry, inv_scale)
-    partials = phase2(mom_s, mom_l, sketch0, params, mode=mode,
-                      geometry=geometry, thr=thr)
-    rows = group_row_stats(mom_s, mom_l, totals, partials, n_sampled,
-                           sizes, n_groups_list,
-                           float(params.min_region_count))
-    return mom_s, mom_l, totals, n_sampled, partials, rows
+    if regs is not None:
+        regs = _sketch_dense_scatter(
+            regs, *hashes, pad_valid, gid_panes, valid_panes,
+            n_groups_list=n_groups_list, gid_slots=gid_slots,
+            valid_slots=valid_slots,
+            cell_idx=None if active_cells is None else active_cells[0])
+    partials, rows = _solve_rows(
+        mom_s, mom_l, totals, n_sampled, sketch0, sizes, inv_scale,
+        params=params, mode=mode, geometry=geometry,
+        n_groups_list=n_groups_list)
+    return mom_s, mom_l, totals, n_sampled, regs, partials, rows
 
 
 class PaneLayout(NamedTuple):
@@ -769,13 +810,13 @@ class PaneLayout(NamedTuple):
     static_argnames=("params", "mode", "geometry", "n_groups_list",
                      "gid_slots", "valid_slots", "key_affine",
                      "bound_slots", "pane_layout"),
-    donate_argnums=(0, 1, 2, 3))
+    donate_argnames=_STATE)
 def fused_tick_dense(mom_s: jnp.ndarray, mom_l: jnp.ndarray,
                      totals: jnp.ndarray, n_sampled: jnp.ndarray,
                      bounds: jnp.ndarray, panes: jnp.ndarray,
                      sketch0: jnp.ndarray, sizes: jnp.ndarray,
                      inv_scale: jnp.ndarray = None,
-                     active_cells=None, *,
+                     active_cells=None, regs=None, *,
                      params: IslaParams, pane_layout: PaneLayout,
                      mode: str = "calibrated", geometry=None,
                      n_groups_list=(1,), gid_slots=(-1,),
@@ -785,17 +826,18 @@ def fused_tick_dense(mom_s: jnp.ndarray, mom_l: jnp.ndarray,
     ``_dense_core`` for the batched-contraction Phase 1, the static-slot
     pane sharing, and the ``active_cells`` compacted-launch contract;
     this wrapper owns the jit + donation and unpacks ``panes``, the
-    tick's one upload, per ``pane_layout``).  ``active_cells=None`` (an
-    empty pytree) keeps the uncompacted trace."""
-    values2d, pad_valid, quotas, gid_panes, valid_panes, _ = \
+    tick's one upload, per ``pane_layout``).  A register plane's hash
+    panes ride in ``panes``.  Returns ``fused_tick``'s shape."""
+    values2d, pad_valid, quotas, gid_panes, valid_panes, hashes = \
         pane_layout.unpack(panes)
-    return _dense_core(mom_s, mom_l, totals, n_sampled, values2d,
-                       pad_valid, quotas, gid_panes, valid_panes, bounds,
-                       sketch0, sizes, inv_scale, params=params, mode=mode,
-                       geometry=geometry, n_groups_list=n_groups_list,
-                       gid_slots=gid_slots, valid_slots=valid_slots,
-                       key_affine=key_affine, bound_slots=bound_slots,
-                       active_cells=active_cells)
+    state = _dense_core(mom_s, mom_l, totals, n_sampled, values2d,
+                        pad_valid, quotas, gid_panes, valid_panes, bounds,
+                        sketch0, sizes, inv_scale, params=params, mode=mode,
+                        geometry=geometry, n_groups_list=n_groups_list,
+                        gid_slots=gid_slots, valid_slots=valid_slots,
+                        key_affine=key_affine, bound_slots=bound_slots,
+                        active_cells=active_cells, regs=regs, hashes=hashes)
+    return (*state, _sketch_fold(state[4], n_groups_list))
 
 
 @functools.partial(
@@ -804,35 +846,33 @@ def fused_tick_dense(mom_s: jnp.ndarray, mom_l: jnp.ndarray,
 def fused_solve(mom_s: jnp.ndarray, mom_l: jnp.ndarray,
                 totals: jnp.ndarray, n_sampled: jnp.ndarray,
                 sketch0: jnp.ndarray, sizes: jnp.ndarray,
-                inv_scale: jnp.ndarray = None, *,
+                inv_scale: jnp.ndarray = None, regs=None, *,
                 params: IslaParams, mode: str = "calibrated",
                 geometry=None, n_groups_list=(1,)):
     """The zero-draw tick: re-solve resident moments without touching the
     state (a warm repeat whose deficit is <= 0).  No donation — the
     resident buffers stay live — and no h2d operand at all.
     ``inv_scale`` is the per-cell anchor-scale vector (see
-    ``fused_tick``)."""
-    thr, geometry = _scaled_solve_args(params, geometry, inv_scale)
-    partials = phase2(mom_s, mom_l, sketch0, params, mode=mode,
-                      geometry=geometry, thr=thr)
-    rows = group_row_stats(mom_s, mom_l, totals, partials, n_sampled,
-                           sizes, n_groups_list,
-                           float(params.min_region_count))
-    return partials, rows
+    ``fused_tick``).  Returns ``(partials, rows, group_regs)``: a
+    register plane is re-folded so a warm repeat serves distinct answers
+    from the same O(groups) readback."""
+    return (*_solve_rows(mom_s, mom_l, totals, n_sampled, sketch0, sizes,
+                         inv_scale, params=params, mode=mode,
+                         geometry=geometry, n_groups_list=n_groups_list),
+            _sketch_fold(regs, n_groups_list))
 
 
 # ---------------------------------------------------------------------------
-# Sketch-plane variants: the fused tick with a (n_cells, m) HLL register
-# pane riding the same donated launch.
+# The HLL register plane: optional state of every tick program.
 # ---------------------------------------------------------------------------
 #
 # COUNT DISTINCT state is a per-cell HyperLogLog register row whose merge
 # is an elementwise max — associative, commutative, idempotent — so the
 # tick-merge parity story is STRUCTURAL: any partition of a stream folds
-# to the bit-identical one-pass plane, on every route.  The variants below
-# are separate jitted functions (not flags on the moment-only launches) so
-# stacks without a sketch key keep their existing traces, donation
-# patterns and collective footprints byte-for-byte.
+# to the bit-identical one-pass plane, on every route.  An absent plane
+# is None, an empty pytree: it adds no operand, no donation and no op,
+# so a stack without a sketch traces the same graph as a moment-only
+# tick.
 #
 # Hash operands arrive as (hi, lo) uint32 limb pairs of the splitmix64'd
 # raw value bits (``sketch.value_limbs`` — computed on host, mixed
@@ -849,12 +889,14 @@ def _sketch_encode(hash_hi: jnp.ndarray, hash_lo: jnp.ndarray):
     return SK.encode_graph(*SK.splitmix64_graph(hash_hi, hash_lo))
 
 
-def _sketch_fold(regs: jnp.ndarray, n_groups_list) -> jnp.ndarray:
+def _sketch_fold(regs, n_groups_list):
     """Fold the (n_cells, m) register plane to one (store, group) register
     row each — max over every store's block axis (the register analogue of
     ``group_row_stats``: the host reads O(groups) rows, never per-cell
     registers).  Cells are (group, block)-contiguous per stacked store, so
-    the fold is a reshape-max, no scatter."""
+    the fold is a reshape-max, no scatter.  No plane folds to None."""
+    if regs is None:
+        return None
     n_b = regs.shape[0] // sum(n_groups_list)
     out = []
     o = 0
@@ -864,47 +906,18 @@ def _sketch_fold(regs: jnp.ndarray, n_groups_list) -> jnp.ndarray:
     return jnp.concatenate(out) if len(out) > 1 else out[0]
 
 
-def _mesh_fold_max(regs: jnp.ndarray, n_groups_list, ax) -> jnp.ndarray:
-    """The shard's ``_sketch_fold`` rows, max-reduced across the mesh.
+def _mesh_fold_max(regs, n_groups_list, ax):
+    """The shard's ``_sketch_fold`` rows, max-reduced across the mesh
+    (None without a plane).
 
     The collective runs on int32: a TPU packs four uint8 rows into each
     32-bit sublane word, and its all-reduce maxes those whole words — on
     a v5e a uint8 ``pmax`` kept only the top row of every four correct.
     """
+    if regs is None:
+        return None
     folded = _sketch_fold(regs, n_groups_list).astype(jnp.int32)
     return jax.lax.pmax(folded, ax).astype(jnp.uint8)
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("params", "mode", "geometry", "n_groups_list"),
-    donate_argnums=(0, 1, 2, 3, 4))
-def fused_tick_sketch(mom_s: jnp.ndarray, mom_l: jnp.ndarray,
-                      totals: jnp.ndarray, n_sampled: jnp.ndarray,
-                      regs: jnp.ndarray, values: jnp.ndarray,
-                      seg: jnp.ndarray, hash_hi: jnp.ndarray,
-                      hash_lo: jnp.ndarray, quotas: jnp.ndarray,
-                      bounds: jnp.ndarray, sketch0: jnp.ndarray,
-                      sizes: jnp.ndarray, inv_scale: jnp.ndarray = None, *,
-                      params: IslaParams, mode: str = "calibrated",
-                      geometry=None, n_groups_list=(1,)):
-    """``fused_tick`` with the HLL register plane riding the launch.
-
-    ``regs`` is the fifth donated state operand ((n_cells, m) uint8);
-    ``hash_hi`` / ``hash_lo`` are the samples' raw-bit limb pairs, aligned
-    with ``values`` / ``seg`` (bucket-pad lanes carry ``seg == n_cells``
-    and drop).  Returns ``(mom_s', mom_l', totals', n_sampled', regs',
-    partials, rows, group_regs)`` — ``group_regs`` the folded per-group
-    register rows, the only register bytes that ever read back.
-    """
-    mom_s, mom_l, totals, n_sampled, partials, rows = _tick_core(
-        mom_s, mom_l, totals, n_sampled, values, seg, quotas, bounds,
-        sketch0, sizes, inv_scale, params=params, mode=mode,
-        geometry=geometry, n_groups_list=n_groups_list)
-    j, rho = _sketch_encode(hash_hi, hash_lo)
-    regs = regs.at[seg, j].max(rho, mode="drop")
-    return (mom_s, mom_l, totals, n_sampled, regs, partials, rows,
-            _sketch_fold(regs, n_groups_list))
 
 
 def _sketch_dense_scatter(regs: jnp.ndarray, hash_hi2d: jnp.ndarray,
@@ -916,7 +929,10 @@ def _sketch_dense_scatter(regs: jnp.ndarray, hash_hi2d: jnp.ndarray,
     cell row, and invalid lanes (ragged pad / predicate miss) scatter the
     neutral rho = 0.  ``cell_idx`` routes compacted pane rows onto the
     full register plane (pads out-of-bounds -> drop), same contract as
-    ``_dense_core``'s ``active_cells``."""
+    ``_dense_core``'s ``active_cells``.  Unlike the moment delta — whose
+    dense fold is a float vector add — the register merge is an integer
+    max, so the dense route keeps the tagged route's bit-parity contract
+    for the plane even in fp32 serving."""
     j, rho0 = _sketch_encode(hash_hi2d, hash_lo2d)
     n_rows = hash_hi2d.shape[0]
     biota = jnp.arange(n_rows, dtype=jnp.int32)[:, None]
@@ -935,71 +951,6 @@ def _sketch_dense_scatter(regs: jnp.ndarray, hash_hi2d: jnp.ndarray,
         regs = regs.at[cell, j].max(rho, mode="drop")
         o += g * n_rows
     return regs
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("params", "mode", "geometry", "n_groups_list",
-                     "gid_slots", "valid_slots", "key_affine",
-                     "bound_slots", "pane_layout"),
-    donate_argnums=(0, 1, 2, 3, 4))
-def fused_tick_dense_sketch(mom_s: jnp.ndarray, mom_l: jnp.ndarray,
-                            totals: jnp.ndarray, n_sampled: jnp.ndarray,
-                            regs: jnp.ndarray, bounds: jnp.ndarray,
-                            panes: jnp.ndarray, sketch0: jnp.ndarray,
-                            sizes: jnp.ndarray,
-                            inv_scale: jnp.ndarray = None,
-                            active_cells=None, *, params: IslaParams,
-                            pane_layout: PaneLayout,
-                            mode: str = "calibrated", geometry=None,
-                            n_groups_list=(1,), gid_slots=(-1,),
-                            valid_slots=(-1,), key_affine=None,
-                            bound_slots=None):
-    """``fused_tick_dense`` with the register plane riding the launch
-    (see ``fused_tick_sketch`` for the state/return contract and
-    ``_sketch_dense_scatter`` for the pane-to-cell mapping); ``panes``
-    also carries the (hi, lo) hash-limb panes.  Unlike the moment delta
-    — whose dense fold is a float vector add — the register merge is an
-    integer max, so the dense route keeps the tagged route's bit-parity
-    contract for the sketch plane even in fp32 serving."""
-    values2d, pad_valid, quotas, gid_panes, valid_panes, (hash_hi2d,
-                                                          hash_lo2d) = \
-        pane_layout.unpack(panes)
-    mom_s, mom_l, totals, n_sampled, partials, rows = _dense_core(
-        mom_s, mom_l, totals, n_sampled, values2d, pad_valid, quotas,
-        gid_panes, valid_panes, bounds, sketch0, sizes, inv_scale,
-        params=params, mode=mode, geometry=geometry,
-        n_groups_list=n_groups_list, gid_slots=gid_slots,
-        valid_slots=valid_slots, key_affine=key_affine,
-        bound_slots=bound_slots, active_cells=active_cells)
-    regs = _sketch_dense_scatter(
-        regs, hash_hi2d, hash_lo2d, pad_valid, gid_panes, valid_panes,
-        n_groups_list=n_groups_list, gid_slots=gid_slots,
-        valid_slots=valid_slots,
-        cell_idx=None if active_cells is None else active_cells[0])
-    return (mom_s, mom_l, totals, n_sampled, regs, partials, rows,
-            _sketch_fold(regs, n_groups_list))
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("params", "mode", "geometry", "n_groups_list"))
-def fused_solve_sketch(mom_s: jnp.ndarray, mom_l: jnp.ndarray,
-                       totals: jnp.ndarray, n_sampled: jnp.ndarray,
-                       regs: jnp.ndarray, sketch0: jnp.ndarray,
-                       sizes: jnp.ndarray, inv_scale: jnp.ndarray = None,
-                       *, params: IslaParams, mode: str = "calibrated",
-                       geometry=None, n_groups_list=(1,)):
-    """``fused_solve`` for sketch stacks: the zero-draw re-solve also
-    re-folds the resident registers so a warm repeat serves distinct
-    answers from the same O(groups) readback.  No donation."""
-    thr, geometry = _scaled_solve_args(params, geometry, inv_scale)
-    partials = phase2(mom_s, mom_l, sketch0, params, mode=mode,
-                      geometry=geometry, thr=thr)
-    rows = group_row_stats(mom_s, mom_l, totals, partials, n_sampled,
-                           sizes, n_groups_list,
-                           float(params.min_region_count))
-    return partials, rows, _sketch_fold(regs, n_groups_list)
 
 
 # ---------------------------------------------------------------------------
@@ -1021,6 +972,14 @@ def fused_solve_sketch(mom_s: jnp.ndarray, mom_l: jnp.ndarray,
 # per-shard rows psum to exactly the full-table rows (up to float
 # association -- the x64 bit-parity contract for the mesh tier covers the
 # resident state and per-cell partials, not the psum'd rows).
+#
+# A register plane is sharded with the moment rows and merged shard-local
+# (samples retag to the local cell window exactly like the moment
+# scatter), so per-cell registers never cross devices: its one collective
+# is a ``pmax`` of the O(groups) FOLDED rows (``_mesh_fold_max``) —
+# bit-identical to the single-device fold, since max is associative.  The
+# optional operands (the plane, its hashes, the compacted scatter pair)
+# sit last with a ``PartitionSpec`` each; None broadcasts to no leaves.
 
 
 def cell_axis(mesh) -> str:
@@ -1051,15 +1010,16 @@ def mesh_tick_fn(mesh, params: IslaParams, mode: str, geometry,
                  n_groups_list, per_cell_bounds: bool):
     """Compiled mesh launch of the tagged fused tick.
 
-    Returns a jitted function with the ``fused_tick`` operand order
-    (state quadruple donated).  ``seg`` carries GLOBAL mesh cell ids and
-    is replicated; each shard keeps the samples whose id falls in its
-    own ``[s*L, (s+1)*L)`` window and retags the rest to its local drop
-    row, so the per-cell fold order matches the single-device launch
-    bit-for-bit in float64.  ``per_cell_bounds`` picks the hetero-anchor
-    layout: a cell-sharded (N, 4) cuts table whose +inf pad row is
-    appended per shard inside the body (uniform stacks replicate one
-    row).  Rows are psum'd across the axis and come back replicated.
+    Returns a jitted function with the ``fused_tick`` operand order and
+    return shape, ``regs`` and ``hashes`` passed positionally (None
+    without a plane); the state is donated.  ``seg`` carries GLOBAL mesh
+    cell ids and is replicated; each shard keeps the samples whose id
+    falls in its own ``[s*L, (s+1)*L)`` window and retags the rest to its
+    local drop row, so the per-cell fold order matches the single-device
+    launch bit-for-bit in float64.  ``per_cell_bounds`` picks the
+    hetero-anchor layout: a cell-sharded (N, 4) cuts table whose +inf pad
+    row is appended per shard inside the body (uniform stacks replicate
+    one row).  Rows are psum'd across the axis and come back replicated.
     """
     from jax.sharding import PartitionSpec as P
     ax = cell_axis(mesh)
@@ -1067,7 +1027,7 @@ def mesh_tick_fn(mesh, params: IslaParams, mode: str, geometry,
     bspec = P(ax, None) if per_cell_bounds else P(None, None)
 
     def body(mom_s, mom_l, totals, ns, values, seg, quotas, bounds,
-             sketch0, sizes, inv_scale):
+             sketch0, sizes, inv_scale, regs, hashes):
         n_local = mom_s.shape[0]
         lo = jax.lax.axis_index(ax).astype(seg.dtype) * n_local
         own = (seg >= lo) & (seg < lo + n_local)
@@ -1075,42 +1035,46 @@ def mesh_tick_fn(mesh, params: IslaParams, mode: str, geometry,
         if per_cell_bounds:
             bounds = jnp.concatenate(
                 [bounds, jnp.full((1, 4), jnp.inf, bounds.dtype)])
-        mom_s, mom_l, totals, ns, partials, rows = _tick_core(
+        mom_s, mom_l, totals, ns, regs, partials, rows = _tick_core(
             mom_s, mom_l, totals, ns, values, lseg, quotas, bounds,
-            sketch0, sizes, inv_scale, params=params, mode=mode,
-            geometry=geometry, n_groups_list=n_groups_list)
-        return mom_s, mom_l, totals, ns, partials, jax.lax.psum(rows, ax)
+            sketch0, sizes, inv_scale, regs, hashes, params=params,
+            mode=mode, geometry=geometry, n_groups_list=n_groups_list)
+        return (mom_s, mom_l, totals, ns, regs, partials,
+                jax.lax.psum(rows, ax),
+                _mesh_fold_max(regs, n_groups_list, ax))
 
     sharded = jax.shard_map(
         body, mesh=mesh,
         in_specs=(row, row, row, vec, rep, rep, vec, bspec, vec, vec,
-                  vec),
-        out_specs=(row, row, row, vec, vec, P(None, None)))
-    return jax.jit(sharded, donate_argnums=(0, 1, 2, 3))
+                  vec, row, rep),
+        out_specs=(row, row, row, vec, row, vec, P(None, None),
+                   P(None, None)))
+    return jax.jit(sharded, donate_argnums=(0, 1, 2, 3, 11))
 
 
 @functools.lru_cache(maxsize=64)
 def mesh_tick_dense_fn(mesh, params: IslaParams, mode: str, geometry,
                        n_groups_list, gid_slots, valid_slots, key_affine,
-                       bound_slots, n_gid_panes: int, n_valid_panes: int,
-                       compacted: bool = False):
+                       bound_slots, n_gid_panes: int, n_valid_panes: int):
     """Compiled mesh launch of the dense fused tick.
 
     The block axis IS the sharded axis in the dense layout: the value
-    pane, pad mask, quotas and GROUP BY / predicate panes are all
-    block-major, so every operand shards as ``P(ax, ...)`` and the body
-    is ``_dense_core`` verbatim on the local slice — no retagging at
-    all.  Group ids stay global (every shard holds all groups; only
-    blocks split).  ``n_gid_panes`` / ``n_valid_panes`` fix the static
-    pytree arity of the shared pane tuples.
+    pane, pad mask, quotas, GROUP BY / predicate panes and a plane's
+    hash panes are all block-major, so every operand shards as
+    ``P(ax, ...)`` and the body is ``_dense_core`` verbatim on the local
+    slice — no retagging at all.  Group ids stay global (every shard
+    holds all groups; only blocks split).  ``n_gid_panes`` /
+    ``n_valid_panes`` fix the static pytree arity of the shared pane
+    tuples.  The trailing operands are ``active_cells``, ``regs`` and
+    ``hashes``, each None when absent.
 
-    ``compacted=True`` is the shard-aware zone-pruned launch: the pane
-    operands cover each shard's ACTIVE blocks only (every shard padded
-    to the same bucketed active count, so block runs stay contiguous and
-    the global pane layout remains shard-major), and two extra ``P(ax)``
-    index vectors — local cell / ledger scatter targets per shard, pads
-    out-of-bounds — route the compacted delta onto the resident shards
-    (see ``_dense_core``'s ``active_cells``).
+    ``active_cells`` makes it the shard-aware zone-pruned launch: the
+    pane operands cover each shard's ACTIVE blocks only (every shard
+    padded to the same bucketed active count, so block runs stay
+    contiguous and the global pane layout remains shard-major), and the
+    two ``P(ax)`` index vectors — local cell / ledger scatter targets
+    per shard, pads out-of-bounds — route the compacted delta onto the
+    resident shards (see ``_dense_core``).
     """
     from jax.sharding import PartitionSpec as P
     ax = cell_axis(mesh)
@@ -1118,176 +1082,53 @@ def mesh_tick_dense_fn(mesh, params: IslaParams, mode: str, geometry,
 
     def body(mom_s, mom_l, totals, ns, values2d, pad_valid, quotas,
              gid_panes, valid_panes, bounds, sketch0, sizes, inv_scale,
-             active_cells=None):
-        mom_s, mom_l, totals, ns, partials, rows = _dense_core(
+             active_cells, regs, hashes):
+        mom_s, mom_l, totals, ns, regs, partials, rows = _dense_core(
             mom_s, mom_l, totals, ns, values2d, pad_valid, quotas,
             gid_panes, valid_panes, bounds, sketch0, sizes, inv_scale,
             params=params, mode=mode, geometry=geometry,
             n_groups_list=n_groups_list, gid_slots=gid_slots,
             valid_slots=valid_slots, key_affine=key_affine,
-            bound_slots=bound_slots, active_cells=active_cells)
-        return mom_s, mom_l, totals, ns, partials, jax.lax.psum(rows, ax)
+            bound_slots=bound_slots, active_cells=active_cells,
+            regs=regs, hashes=hashes)
+        return (mom_s, mom_l, totals, ns, regs, partials,
+                jax.lax.psum(rows, ax),
+                _mesh_fold_max(regs, n_groups_list, ax))
 
-    specs = (row, row, row, vec, row, row, vec,
-             (vec,) * n_gid_panes, (row,) * n_valid_panes,
-             P(None, None), vec, vec, vec)
-    if compacted:
-        specs = specs + ((vec, vec),)
     sharded = jax.shard_map(
         body, mesh=mesh,
-        in_specs=specs,
-        out_specs=(row, row, row, vec, vec, P(None, None)))
-    return jax.jit(sharded, donate_argnums=(0, 1, 2, 3))
+        in_specs=(row, row, row, vec, row, row, vec,
+                  (vec,) * n_gid_panes, (row,) * n_valid_panes,
+                  P(None, None), vec, vec, vec, vec, row, row),
+        out_specs=(row, row, row, vec, row, vec, P(None, None),
+                   P(None, None)))
+    return jax.jit(sharded, donate_argnums=(0, 1, 2, 3, 14))
 
 
 @functools.lru_cache(maxsize=64)
 def mesh_solve_fn(mesh, params: IslaParams, mode: str, geometry,
                   n_groups_list):
-    """Compiled mesh launch of the zero-draw re-solve (``fused_solve``).
+    """Compiled mesh launch of the zero-draw re-solve (``fused_solve``,
+    ``regs`` positional and None without a plane).
 
     No donation — the resident shards stay live — and the only
-    collective is the stat-row psum.
+    collectives are the stat-row psum and a plane's folded-row pmax.
     """
     from jax.sharding import PartitionSpec as P
     ax = cell_axis(mesh)
     row, vec = P(ax, None), P(ax)
 
-    def body(mom_s, mom_l, totals, ns, sketch0, sizes, inv_scale):
-        thr, geo = _scaled_solve_args(params, geometry, inv_scale)
-        partials = phase2(mom_s, mom_l, sketch0, params, mode=mode,
-                          geometry=geo, thr=thr)
-        rows = group_row_stats(mom_s, mom_l, totals, partials, ns,
-                               sizes, n_groups_list,
-                               float(params.min_region_count))
-        return partials, jax.lax.psum(rows, ax)
-
-    sharded = jax.shard_map(
-        body, mesh=mesh,
-        in_specs=(row, row, row, vec, vec, vec, vec),
-        out_specs=(vec, P(None, None)))
-    return jax.jit(sharded)
-
-
-@functools.lru_cache(maxsize=64)
-def mesh_tick_sketch_fn(mesh, params: IslaParams, mode: str, geometry,
-                        n_groups_list, per_cell_bounds: bool):
-    """``mesh_tick_fn`` with the register plane sharded alongside the
-    moment rows (``P(ax, None)`` — each shard owns its block run's
-    registers, resident across ticks).
-
-    The register merge is SHARD-LOCAL: samples retag to the local cell
-    window exactly like the moment scatter, so per-cell registers never
-    cross devices.  The only new collective is a ``pmax`` of the O(groups)
-    FOLDED register rows — each shard folds its local block run, the max
-    across shards is the full fold (max is associative/commutative, so
-    the mesh fold is bit-identical to the single-device fold by
-    construction, not by float luck).
-    """
-    from jax.sharding import PartitionSpec as P
-    ax = cell_axis(mesh)
-    row, vec, rep = P(ax, None), P(ax), P()
-    bspec = P(ax, None) if per_cell_bounds else P(None, None)
-
-    def body(mom_s, mom_l, totals, ns, regs, values, seg, hash_hi,
-             hash_lo, quotas, bounds, sketch0, sizes, inv_scale):
-        n_local = mom_s.shape[0]
-        lo = jax.lax.axis_index(ax).astype(seg.dtype) * n_local
-        own = (seg >= lo) & (seg < lo + n_local)
-        lseg = jnp.where(own, seg - lo, n_local).astype(seg.dtype)
-        if per_cell_bounds:
-            bounds = jnp.concatenate(
-                [bounds, jnp.full((1, 4), jnp.inf, bounds.dtype)])
-        mom_s, mom_l, totals, ns, partials, rows = _tick_core(
-            mom_s, mom_l, totals, ns, values, lseg, quotas, bounds,
-            sketch0, sizes, inv_scale, params=params, mode=mode,
-            geometry=geometry, n_groups_list=n_groups_list)
-        j, rho = _sketch_encode(hash_hi, hash_lo)
-        regs = regs.at[lseg, j].max(rho, mode="drop")
-        group_regs = _mesh_fold_max(regs, n_groups_list, ax)
-        return (mom_s, mom_l, totals, ns, regs, partials,
-                jax.lax.psum(rows, ax), group_regs)
-
-    sharded = jax.shard_map(
-        body, mesh=mesh,
-        in_specs=(row, row, row, vec, row, rep, rep, rep, rep, vec,
-                  bspec, vec, vec, vec),
-        out_specs=(row, row, row, vec, row, vec, P(None, None),
-                   P(None, None)))
-    return jax.jit(sharded, donate_argnums=(0, 1, 2, 3, 4))
-
-
-@functools.lru_cache(maxsize=64)
-def mesh_tick_dense_sketch_fn(mesh, params: IslaParams, mode: str,
-                              geometry, n_groups_list, gid_slots,
-                              valid_slots, key_affine, bound_slots,
-                              n_gid_panes: int, n_valid_panes: int,
-                              compacted: bool = False):
-    """``mesh_tick_dense_fn`` with the register plane riding the launch.
-
-    The hash panes shard block-major like the value pane, so each shard's
-    ``_sketch_dense_scatter`` addresses only its local register rows; the
-    folded-row ``pmax`` is the single register collective (see
-    ``mesh_tick_sketch_fn``).
-    """
-    from jax.sharding import PartitionSpec as P
-    ax = cell_axis(mesh)
-    row, vec = P(ax, None), P(ax)
-
-    def body(mom_s, mom_l, totals, ns, regs, values2d, pad_valid,
-             hash_hi2d, hash_lo2d, quotas, gid_panes, valid_panes,
-             bounds, sketch0, sizes, inv_scale, active_cells=None):
-        mom_s, mom_l, totals, ns, partials, rows = _dense_core(
-            mom_s, mom_l, totals, ns, values2d, pad_valid, quotas,
-            gid_panes, valid_panes, bounds, sketch0, sizes, inv_scale,
+    def body(mom_s, mom_l, totals, ns, sketch0, sizes, inv_scale, regs):
+        partials, rows = _solve_rows(
+            mom_s, mom_l, totals, ns, sketch0, sizes, inv_scale,
             params=params, mode=mode, geometry=geometry,
-            n_groups_list=n_groups_list, gid_slots=gid_slots,
-            valid_slots=valid_slots, key_affine=key_affine,
-            bound_slots=bound_slots, active_cells=active_cells)
-        regs = _sketch_dense_scatter(
-            regs, hash_hi2d, hash_lo2d, pad_valid, gid_panes,
-            valid_panes, n_groups_list=n_groups_list,
-            gid_slots=gid_slots, valid_slots=valid_slots,
-            cell_idx=None if active_cells is None else active_cells[0])
-        group_regs = _mesh_fold_max(regs, n_groups_list, ax)
-        return (mom_s, mom_l, totals, ns, regs, partials,
-                jax.lax.psum(rows, ax), group_regs)
-
-    specs = (row, row, row, vec, row, row, row, row, row, vec,
-             (vec,) * n_gid_panes, (row,) * n_valid_panes,
-             P(None, None), vec, vec, vec)
-    if compacted:
-        specs = specs + ((vec, vec),)
-    sharded = jax.shard_map(
-        body, mesh=mesh,
-        in_specs=specs,
-        out_specs=(row, row, row, vec, row, vec, P(None, None),
-                   P(None, None)))
-    return jax.jit(sharded, donate_argnums=(0, 1, 2, 3, 4))
-
-
-@functools.lru_cache(maxsize=64)
-def mesh_solve_sketch_fn(mesh, params: IslaParams, mode: str, geometry,
-                         n_groups_list):
-    """``mesh_solve_fn`` for sketch stacks: the warm re-solve also
-    re-folds each shard's resident registers and pmaxes the O(groups)
-    rows.  No donation."""
-    from jax.sharding import PartitionSpec as P
-    ax = cell_axis(mesh)
-    row, vec = P(ax, None), P(ax)
-
-    def body(mom_s, mom_l, totals, ns, regs, sketch0, sizes, inv_scale):
-        thr, geo = _scaled_solve_args(params, geometry, inv_scale)
-        partials = phase2(mom_s, mom_l, sketch0, params, mode=mode,
-                          geometry=geo, thr=thr)
-        rows = group_row_stats(mom_s, mom_l, totals, partials, ns,
-                               sizes, n_groups_list,
-                               float(params.min_region_count))
-        group_regs = _mesh_fold_max(regs, n_groups_list, ax)
-        return partials, jax.lax.psum(rows, ax), group_regs
+            n_groups_list=n_groups_list)
+        return (partials, jax.lax.psum(rows, ax),
+                _mesh_fold_max(regs, n_groups_list, ax))
 
     sharded = jax.shard_map(
         body, mesh=mesh,
-        in_specs=(row, row, row, vec, row, vec, vec, vec),
+        in_specs=(row, row, row, vec, vec, vec, vec, row),
         out_specs=(vec, P(None, None), P(None, None)))
     return jax.jit(sharded)
 

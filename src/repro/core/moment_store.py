@@ -1167,10 +1167,10 @@ class DeviceStack:
                 jnp.concatenate([st._mom_l for st in self.stores]),
                 jnp.concatenate([st._totals for st in self.stores]),
                 jnp.concatenate([st._ns_dev for st in self.stores]))
-        # Sketch plane: any sketch member lifts the whole stack onto the
-        # sketch launch twins (non-sketch members ride with inert
-        # all-zero register rows — max against zero is a no-op, and the
-        # twin keeps the moment-only stacks' traces untouched).
+        # Sketch plane: any sketch member gives the whole stack a
+        # register plane, optional state of every launch (non-sketch
+        # members ride with inert all-zero register rows — max against
+        # zero is a no-op); without one it is None and adds nothing.
         self.has_sketch = any(st.has_sketch for st in self.stores)
         if self.has_sketch:
             if len(self.stores) == 1:
@@ -1273,11 +1273,7 @@ class DeviceStack:
         out = []
         for k, st in enumerate(self.stores):
             r0, r1 = int(self.row_offsets[k]), int(self.row_offsets[k + 1])
-            if len(self.stores) == 1:
-                st._partials = partials
-            else:
-                o0, o1 = int(self.offsets[k]), int(self.offsets[k + 1])
-                st._partials = _PartialsSlice(partials, o0, o1)
+            st._partials = self._partials_view(partials, k)
             st._rows = (_RowsView(holder, r0, r1) if defer
                         else rows_np[r0:r1] if len(self.stores) > 1
                         else rows_np)
@@ -1287,6 +1283,14 @@ class DeviceStack:
             st._stats_cfg = cfg
             out.append((st._partials, st._rows_src))
         return out
+
+    def _partials_view(self, partials, k: int):
+        """Store ``k``'s lazy view of a launch's stacked per-cell
+        partials (a one-store stack hands the array itself)."""
+        if len(self.stores) == 1:
+            return partials
+        return _PartialsSlice(partials, int(self.offsets[k]),
+                              int(self.offsets[k + 1]))
 
     # fp32 accumulators lose integer exactness at 2^24; warn with margin
     # so an eternal serving loop cannot silently stop accumulating.
@@ -1398,9 +1402,9 @@ class DeviceStack:
          * tagged — ``values`` (each store's OWN scaled shifted frame —
            ``(raw + store.shift) / store.scale`` per key slice, float64
            host, matched samples only) aligned with ``seg`` (stacked cell
-           ids from ``DeviceMomentStore.build_seg`` with this stack's
-           offsets); the carry-prepend scatter, bit-identical to the host
-           fold when the store runs float64 (scale 1.0).
+           ids from ``key_seg``, in this stack's placement); the
+           carry-prepend scatter, bit-identical to the host fold when the
+           store runs float64 (scale 1.0).
          * dense — ``values`` is the FULL block-major chunk stream of RAW
            (unshifted) measure values and ``dense=(key_gids, key_valids)``
            carries per-store (m,) GROUP BY codes / predicate masks (None
@@ -1413,10 +1417,11 @@ class DeviceStack:
         resident moments are re-solved (served from the stats cache when
         nothing changed — zero launches, zero transfers).
 
-        Returns ``[(partials, rows), ...]`` per store — device partial
-        answers and the numpy group-stat rows, both in EACH STORE'S scaled
-        shifted units (``DeviceMomentStore.partials_host`` / the
-        executor's composer un-scale per store).
+        Returns ``[(partials, rows), ...]`` per store — lazy partial
+        answers (``_partials_view``) and the numpy group-stat rows, both
+        in EACH STORE'S scaled shifted units
+        (``DeviceMomentStore.partials_host`` / the executor's composer
+        un-scale per store).
 
         ``timings`` (optional dict) books the ``h2d`` stage (its parts
         ``h2d.pane`` and ``h2d.put``), ``launch`` and ``readback``
@@ -1426,18 +1431,20 @@ class DeviceStack:
         views that block on first access, letting the host stage the next
         mode-group while this one computes.  At most two launches stay
         in flight (classic double-buffer depth).
+
+        The launches are three hooks — ``_launch_solve``,
+        ``_launch_dense`` and ``_launch_tagged`` — each building and
+        uploading its panes and returning ``fused_tick``'s shape; a
+        placement (``MeshDeviceStack``) overrides only those.
         """
-        import jax.numpy as jnp
-
-        from . import distributed as D
-
         if geometry is not None:
             # kappa is dimensionless; b0 lives on the value axis — the
             # launch rescales it per cell via the inv_scale vector.
             geometry = (float(geometry[0]), float(geometry[1]))
         if self._released:
-            raise ValueError("stack was released (a store joined another "
-                             "stack); build a fresh DeviceStack")
+            raise ValueError(
+                "stack was released (a store joined another stack); "
+                f"build a fresh {type(self).__name__}")
         cfg = (params, mode, geometry)
         n_draw = 0 if quotas is None else int(np.sum(quotas))
         if values is None or n_draw == 0:
@@ -1447,22 +1454,7 @@ class DeviceStack:
                 # going through the property here would force the sync.
                 return [(st._partials, st._rows_src)
                         for st in self.stores]
-            mom_s, mom_l, totals, ns = self._state
-            group_regs = None
-            with D.stage_trace("launch", timings):
-                if self.has_sketch:
-                    partials, rows, group_regs = D.fused_solve_sketch(
-                        mom_s, mom_l, totals, ns, self._regs_state,
-                        self._sketch0_cells(), self._sizes,
-                        self._inv_scale, params=params, mode=mode,
-                        geometry=geometry,
-                        n_groups_list=self.n_groups_list)
-                else:
-                    partials, rows = D.fused_solve(
-                        mom_s, mom_l, totals, ns, self._sketch0_cells(),
-                        self._sizes, self._inv_scale, params=params,
-                        mode=mode, geometry=geometry,
-                        n_groups_list=self.n_groups_list)
+            partials, rows, group_regs = self._launch_solve(cfg, timings)
             return self._install_stats(partials, rows, cfg,
                                        defer=defer_stats, timings=timings,
                                        group_regs=group_regs)
@@ -1473,12 +1465,10 @@ class DeviceStack:
             raise ValueError(f"quotas must be ({self.n_blocks},), got "
                              f"{quotas.shape}")
         self._check_fp32_headroom(quotas)
-        mom_s, mom_l, totals, ns = self._state
-        # All h2d crossings below are the tick's fresh samples and their
-        # tags — moments never cross (the per-store tiling of the quota
-        # row happens inside the launch).
+        # All h2d crossings in the hooks are the tick's fresh samples and
+        # their tags — moments never cross (the per-store tiling of the
+        # quota row happens inside the launch).
         if dense is not None:
-            key_gids, key_valids = dense
             if self._uniform:
                 # One shared anchor: prepare the pane in its frame on the
                 # host (float64 — the pre-refinement numerics) and let the
@@ -1499,80 +1489,23 @@ class DeviceStack:
                 pane_quotas, _, active_cells = cp
             else:
                 pane_quotas, active_cells = quotas, None
-            with D.stage_trace("h2d", timings):
-                with D.stage_trace("h2d.pane", timings):
-                    # Hash panes key on the RAW dense stream (the value
-                    # pane is anchor-scaled; registers key on raw bits).
-                    panes, layout, gid_slots, valid_slots = _packed_panes(
-                        pane_vals, pane_quotas, key_gids, key_valids,
-                        self.dtype, values if self.has_sketch else None)
-                    D.count("pane_slots",
-                            layout.pane_rows * layout.quota_max)
-                with D.stage_trace("h2d.put", timings):
-                    panes_dev = D.h2d(panes, self.dtype)
-            group_regs = None
-            static = dict(params=params, mode=mode, geometry=geometry,
-                          n_groups_list=self.n_groups_list,
-                          gid_slots=gid_slots, valid_slots=valid_slots,
-                          key_affine=key_affine,
-                          bound_slots=self._bound_slots,
-                          pane_layout=layout)
-            with D.stage_trace("launch", timings):
-                operands = (self._bound_rows, panes_dev,
-                            self._sketch0_cells(), self._sizes,
-                            self._inv_scale, active_cells)
-                if self.has_sketch:
-                    (mom_s, mom_l, totals, ns, regs, partials, rows,
-                     group_regs) = D.fused_tick_dense_sketch(
-                        mom_s, mom_l, totals, ns, self._regs_state,
-                        *operands, **static)
-                    self._regs_state = regs
-                else:
-                    mom_s, mom_l, totals, ns, partials, rows = \
-                        D.fused_tick_dense(mom_s, mom_l, totals, ns,
-                                           *operands, **static)
+            out = self._launch_dense(cfg, values, pane_vals, pane_quotas,
+                                     active_cells, dense, key_affine,
+                                     timings)
         else:
             seg = np.asarray(seg, dtype=np.int32).reshape(-1)
             if values.shape != seg.shape:
                 raise ValueError("values and seg must align")
-            if self.has_sketch and hash_limbs is None:
+            if not self.has_sketch:
+                hash_limbs = None
+            elif hash_limbs is None:
                 raise ValueError(
                     "sketch stack tagged tick needs hash_limbs "
                     "(sketch.value_limbs of the raw values)")
-            with D.stage_trace("h2d", timings):
-                with D.stage_trace("h2d.pane", timings):
-                    v_pad, s_pad, h_pads = _tagged_panes(
-                        values, seg, self.n_cells,
-                        hash_limbs if self.has_sketch else None)
-                    D.count("pane_slots", v_pad.size)
-                with D.stage_trace("h2d.put", timings):
-                    q_dev = D.h2d(quotas.astype(np.float64), self.dtype)
-                    v_dev = D.h2d(v_pad, self.dtype)
-                    s_dev = D.h2d(s_pad, jnp.int32)
-                    if self.has_sketch:
-                        hhi_dev = D.h2d(h_pads[0], jnp.uint32)
-                        hlo_dev = D.h2d(h_pads[1], jnp.uint32)
-            group_regs = None
-            with D.stage_trace("launch", timings):
-                if self.has_sketch:
-                    (mom_s, mom_l, totals, ns, regs, partials, rows,
-                     group_regs) = D.fused_tick_sketch(
-                        mom_s, mom_l, totals, ns, self._regs_state,
-                        v_dev, s_dev, hhi_dev, hlo_dev, q_dev,
-                        self._bounds, self._sketch0_cells(), self._sizes,
-                        self._inv_scale, params=params, mode=mode,
-                        geometry=geometry,
-                        n_groups_list=self.n_groups_list)
-                    self._regs_state = regs
-                else:
-                    mom_s, mom_l, totals, ns, partials, rows = \
-                        D.fused_tick(
-                            mom_s, mom_l, totals, ns, v_dev,
-                            s_dev, q_dev, self._bounds,
-                            self._sketch0_cells(), self._sizes,
-                            self._inv_scale,
-                            params=params, mode=mode, geometry=geometry,
-                            n_groups_list=self.n_groups_list)
+            out = self._launch_tagged(cfg, values, seg, quotas, hash_limbs,
+                                      timings)
+        (mom_s, mom_l, totals, ns, self._regs_state, partials, rows,
+         group_regs) = out
         self._state = (mom_s, mom_l, totals, ns)
         for st in self.stores:
             st.n_sampled = st.n_sampled + quotas
@@ -1581,6 +1514,73 @@ class DeviceStack:
         return self._install_stats(partials, rows, cfg,
                                    defer=defer_stats, timings=timings,
                                    group_regs=group_regs)
+
+    def _launch_solve(self, cfg, timings):
+        """The zero-draw re-solve: ``(partials, rows, group_regs)``."""
+        from . import distributed as D
+
+        params, mode, geometry = cfg
+        with D.stage_trace("launch", timings):
+            return D.fused_solve(
+                *self._state, self._sketch0_cells(), self._sizes,
+                self._inv_scale, self._regs_state, params=params,
+                mode=mode, geometry=geometry,
+                n_groups_list=self.n_groups_list)
+
+    def _launch_dense(self, cfg, values, pane_vals, quotas, active_cells,
+                      dense, key_affine, timings):
+        """The dense tick: every pane packed into one upload
+        (``_packed_panes``), then ``fused_tick_dense``."""
+        from . import distributed as D
+
+        params, mode, geometry = cfg
+        with D.stage_trace("h2d", timings):
+            with D.stage_trace("h2d.pane", timings):
+                # Hash panes key on the RAW dense stream (the value pane
+                # is anchor-scaled; registers key on raw bits).
+                panes, layout, gid_slots, valid_slots = _packed_panes(
+                    pane_vals, quotas, *dense, self.dtype,
+                    values if self.has_sketch else None)
+                D.count("pane_slots", layout.pane_rows * layout.quota_max)
+            with D.stage_trace("h2d.put", timings):
+                panes_dev = D.h2d(panes, self.dtype)
+        with D.stage_trace("launch", timings):
+            return D.fused_tick_dense(
+                *self._state, self._bound_rows, panes_dev,
+                self._sketch0_cells(), self._sizes, self._inv_scale,
+                active_cells, regs=self._regs_state, params=params,
+                mode=mode, geometry=geometry,
+                n_groups_list=self.n_groups_list, gid_slots=gid_slots,
+                valid_slots=valid_slots, key_affine=key_affine,
+                bound_slots=self._bound_slots, pane_layout=layout)
+
+    def _launch_tagged(self, cfg, values, seg, quotas, hash_limbs, timings):
+        """The tagged tick: bucket-padded sample streams, then
+        ``fused_tick``."""
+        import jax.numpy as jnp
+
+        from . import distributed as D
+
+        params, mode, geometry = cfg
+        with D.stage_trace("h2d", timings):
+            with D.stage_trace("h2d.pane", timings):
+                v_pad, s_pad, h_pads = _tagged_panes(values, seg,
+                                                     self.n_cells,
+                                                     hash_limbs)
+                D.count("pane_slots", v_pad.size)
+            with D.stage_trace("h2d.put", timings):
+                q_dev = D.h2d(quotas.astype(np.float64), self.dtype)
+                v_dev = D.h2d(v_pad, self.dtype)
+                s_dev = D.h2d(s_pad, jnp.int32)
+                hashes = (None if h_pads is None else
+                          tuple(D.h2d(h, jnp.uint32) for h in h_pads))
+        with D.stage_trace("launch", timings):
+            return D.fused_tick(
+                *self._state, v_dev, s_dev, q_dev, self._bounds,
+                self._sketch0_cells(), self._sizes, self._inv_scale,
+                regs=self._regs_state, hashes=hashes, params=params,
+                mode=mode, geometry=geometry,
+                n_groups_list=self.n_groups_list)
 
 
 class _MeshPartialsView:
@@ -1648,6 +1648,9 @@ class MeshDeviceStack(DeviceStack):
         row = PartitionSpec(ax, None)
         vec = PartitionSpec(ax)
         rep2 = PartitionSpec(None, None)
+        # The tick's upload placements: cell-sharded rows and vectors,
+        # and the replicated sample stream.
+        self._specs = (row, vec, PartitionSpec())
         S = 1
         for n in mesh.devices.shape:
             S *= int(n)
@@ -1776,34 +1779,8 @@ class MeshDeviceStack(DeviceStack):
         self._inflight.clear()
         self._released = True
 
-    def _install_stats(self, partials, rows, cfg, defer=False,
-                       timings=None, group_regs=None):
-        from . import distributed as D
-
-        if defer:
-            holder = _LazyRows(D.d2h_async(rows), timings)
-            self._inflight.append(rows)
-            while len(self._inflight) > 2:  # double-buffer depth
-                self._inflight.popleft().block_until_ready()
-        else:
-            with D.stage_trace("readback", timings):
-                rows_np = np.asarray(rows, dtype=np.float64)  # d2h: stats
-        gr_holder = None
-        if group_regs is not None:
-            gr_holder = _LazyRows(D.d2h_async(group_regs), timings,
-                                  dtype=np.uint8)
-        out = []
-        for k, st in enumerate(self.stores):
-            r0, r1 = int(self.row_offsets[k]), int(self.row_offsets[k + 1])
-            st._partials = _MeshPartialsView(partials, self._cell_maps[k])
-            st._rows = (_RowsView(holder, r0, r1) if defer
-                        else rows_np[r0:r1])
-            if gr_holder is not None and st.has_sketch:
-                st._group_regs = _RowsView(gr_holder, r0, r1)
-            st._stats_valid = True
-            st._stats_cfg = cfg
-            out.append((st._partials, st._rows_src))
-        return out
+    def _partials_view(self, partials, k: int):
+        return _MeshPartialsView(partials, self._cell_maps[k])
 
     # -- the tick ----------------------------------------------------------
 
@@ -1866,192 +1843,101 @@ class MeshDeviceStack(DeviceStack):
             self._active_cache[ck] = pair
         return q_c, active, pair
 
-    def tick(self, params: IslaParams, mode: str = "calibrated",
-             geometry=None, values: Optional[np.ndarray] = None,
-             seg: Optional[np.ndarray] = None,
-             quotas: Optional[np.ndarray] = None,
-             dense=None, count_round: bool = True, timings=None,
-             defer_stats: bool = False, hash_limbs=None):
-        """``DeviceStack.tick`` on the mesh layout — identical payload
-        contract except tagged ``seg`` carries MESH cell ids (from
-        ``key_seg``), and each store's returned partials are lazy
-        mesh->store gather views (``_MeshPartialsView``).  Sketch stacks
-        keep register rows shard-local (merge by max needs no psum);
-        only the O(groups) FOLDED rows cross shards, via one pmax
-        alongside the stat-row psum."""
+    def _launch_solve(self, cfg, timings):
+        from . import distributed as D
+
+        with D.stage_trace("launch", timings):
+            solve = D.mesh_solve_fn(self.mesh, *cfg, self.n_groups_list)
+            return solve(*self._state, self._sketch0_cells(), self._sizes,
+                         self._inv_scale, self._regs_state)
+
+    def _launch_dense(self, cfg, values, pane_vals, quotas, active_cells,
+                      dense, key_affine, timings):
+        """One upload a pane, each sharded by block rows.  A compact pane
+        is already shard-major (S * amax rows), so ``block_pad`` pads only
+        the uncompacted pane out to S * B_local rows."""
         import jax.numpy as jnp
-        from jax.sharding import PartitionSpec
 
         from . import distributed as D
 
-        if geometry is not None:
-            geometry = (float(geometry[0]), float(geometry[1]))
-        if self._released:
-            raise ValueError("stack was released (a store joined another "
-                             "stack); build a fresh MeshDeviceStack")
-        ax = D.cell_axis(self.mesh)
-        row = PartitionSpec(ax, None)
-        vec = PartitionSpec(ax)
-        rep = PartitionSpec()
-        cfg = (params, mode, geometry)
-        n_draw = 0 if quotas is None else int(np.sum(quotas))
-        if values is None or n_draw == 0:
-            if all(st._stats_valid and st._stats_cfg == cfg
-                   for st in self.stores):
-                return [(st._partials, st._rows_src)
-                        for st in self.stores]
-            group_regs = None
-            with D.stage_trace("launch", timings):
-                if self.has_sketch:
-                    solve = D.mesh_solve_sketch_fn(
-                        self.mesh, params, mode, geometry,
-                        self.n_groups_list)
-                    partials, rows, group_regs = solve(
-                        *self._state, self._regs_state,
-                        self._sketch0_cells(), self._sizes,
-                        self._inv_scale)
-                else:
-                    solve = D.mesh_solve_fn(self.mesh, params, mode,
-                                            geometry, self.n_groups_list)
-                    partials, rows = solve(*self._state,
-                                           self._sketch0_cells(),
-                                           self._sizes, self._inv_scale)
-            return self._install_stats(partials, rows, cfg,
-                                       defer=defer_stats, timings=timings,
-                                       group_regs=group_regs)
+        row, vec, _ = self._specs
+        with D.stage_trace("h2d", timings):
+            with D.stage_trace("h2d.pane", timings):
+                v2d, pad, vmask = _dense_panes(pane_vals, quotas)
+                pane_rows = (self.n_shards * self.blocks_local
+                             if active_cells is None else v2d.shape[0])
+                q_pad = np.zeros(pane_rows, dtype=np.float64)
+                q_pad[:quotas.size] = quotas
 
-        values = np.asarray(values, dtype=np.float64).reshape(-1)
-        quotas = np.asarray(quotas, dtype=np.int64).reshape(-1)
-        if quotas.shape != (self.n_blocks,):
-            raise ValueError(f"quotas must be ({self.n_blocks},), got "
-                             f"{quotas.shape}")
-        self._check_fp32_headroom(quotas)
-        S, bl = self.n_shards, self.blocks_local
-        if dense is not None:
-            key_gids, key_valids = dense
-            if self._uniform:
-                st0 = self.stores[0]
-                pane_vals = (values + st0.shift) / st0.scale
-                key_affine = ((1.0, 0.0),) * len(self.stores)
-            else:
-                pane_vals = values / self._ref_scale
-                key_affine = self._key_affine
-            # Zone-pruned plans compact to each shard's active run; the
-            # compact pane is already shard-major (S * amax rows), so it
-            # uploads as-is and block_pad degenerates to identity.
-            cp = self._compact_plan(quotas)
-            if cp is not None:
-                pane_quotas, _, active_cells = cp
-            else:
-                pane_quotas, active_cells = quotas, None
-            with D.stage_trace("h2d", timings):
-                with D.stage_trace("h2d.pane", timings):
-                    v2d, pad, vmask = _dense_panes(pane_vals, pane_quotas)
-                    pane_rows = ((S * bl) if active_cells is None
-                                 else v2d.shape[0])
-                    q_pad = np.zeros(pane_rows, dtype=np.float64)
-                    q_pad[:pane_quotas.size] = pane_quotas
+                def block_pad(a):
+                    if a.shape[0] == pane_rows:
+                        return a
+                    out = np.zeros((pane_rows, a.shape[1]), dtype=a.dtype)
+                    out[:a.shape[0]] = a
+                    return out
 
-                    def block_pad(a):
-                        if a.shape[0] == pane_rows:
-                            return a
-                        out = np.zeros((pane_rows, a.shape[1]),
-                                       dtype=a.dtype)
-                        out[:a.shape[0]] = a
-                        return out
+                gid_host, gid_slots, valid_host, valid_slots = \
+                    _key_panes(vmask, *dense)
+                gid_host = [block_pad(g) for g in gid_host]
+                valid_host = [block_pad(m) for m in valid_host]
+                panes = [block_pad(v2d), block_pad(pad)]
+                hash_host = ([block_pad(h) for h in _hash_panes(values,
+                                                                vmask)]
+                             if self.has_sketch else None)
+                D.count("pane_slots", panes[0].size)
+            with D.stage_trace("h2d.put", timings):
+                q_dev = D.mesh_h2d(self.mesh, q_pad, vec, self.dtype)
+                gid_panes = tuple(D.mesh_h2d(self.mesh, g, row, jnp.int32)
+                                  for g in gid_host)
+                valid_panes = tuple(
+                    D.mesh_h2d(self.mesh, m, row, self.dtype)
+                    for m in valid_host)
+                v_dev, pad_dev = (D.mesh_h2d(self.mesh, p, row, self.dtype)
+                                  for p in panes)
+                hashes = (None if hash_host is None else tuple(
+                    D.mesh_h2d(self.mesh, h, row, jnp.uint32)
+                    for h in hash_host))
+        with D.stage_trace("launch", timings):
+            fn = D.mesh_tick_dense_fn(
+                self.mesh, *cfg, self.n_groups_list, gid_slots,
+                valid_slots, key_affine, self._bound_slots,
+                len(gid_panes), len(valid_panes))
+            return fn(*self._state, v_dev, pad_dev, q_dev, gid_panes,
+                      valid_panes, self._bound_rows, self._sketch0_cells(),
+                      self._sizes, self._inv_scale, active_cells,
+                      self._regs_state, hashes)
 
-                    gid_host, gid_slots, valid_host, valid_slots = \
-                        _key_panes(vmask, key_gids, key_valids)
-                    gid_host = [block_pad(g) for g in gid_host]
-                    valid_host = [block_pad(m) for m in valid_host]
-                    panes = [block_pad(v2d), block_pad(pad)]
-                    if self.has_sketch:
-                        panes += map(block_pad, _hash_panes(values, vmask))
-                    D.count("pane_slots", panes[0].size)
-                with D.stage_trace("h2d.put", timings):
-                    q_dev = D.mesh_h2d(self.mesh, q_pad, vec, self.dtype)
-                    gid_panes = tuple(
-                        D.mesh_h2d(self.mesh, g, row, jnp.int32)
-                        for g in gid_host)
-                    valid_panes = tuple(
-                        D.mesh_h2d(self.mesh, m, row, self.dtype)
-                        for m in valid_host)
-                    pane_devs = [
-                        D.mesh_h2d(self.mesh, p, row,
-                                   self.dtype if i < 2 else jnp.uint32)
-                        for i, p in enumerate(panes)]
-                fn = (D.mesh_tick_dense_sketch_fn if self.has_sketch
-                      else D.mesh_tick_dense_fn)(
-                    self.mesh, params, mode, geometry, self.n_groups_list,
-                    gid_slots, valid_slots, key_affine,
-                    self._bound_slots, len(gid_panes), len(valid_panes),
-                    compacted=active_cells is not None)
-                regs = (self._regs_state,) if self.has_sketch else ()
-                args = (*self._state, *regs, *pane_devs, q_dev, gid_panes,
-                        valid_panes, self._bound_rows, self._sketch0_cells(),
-                        self._sizes, self._inv_scale)
-                if active_cells is not None:
-                    args = args + (active_cells,)
-            with D.stage_trace("launch", timings):
-                out = fn(*args)
-        else:
-            seg = np.asarray(seg, dtype=np.int32).reshape(-1)
-            if values.shape != seg.shape:
-                raise ValueError("values and seg must align")
-            if self.has_sketch and hash_limbs is None:
-                raise ValueError(
-                    "sketch stack tagged tick needs hash_limbs "
-                    "(sketch.value_limbs of the raw values)")
-            with D.stage_trace("h2d", timings):
-                with D.stage_trace("h2d.pane", timings):
-                    # Pad/drop id: past every shard's window, so each
-                    # shard retags it onto its local drop row.
-                    v_pad, s_pad, h_pads = _tagged_panes(
-                        values, seg, self.n_cells_mesh,
-                        hash_limbs if self.has_sketch else None)
-                    q_pad = np.zeros(S * bl, dtype=np.float64)
-                    q_pad[:self.n_blocks] = quotas
-                    D.count("pane_slots", v_pad.size)
-                with D.stage_trace("h2d.put", timings):
-                    q_dev = D.mesh_h2d(self.mesh, q_pad, vec, self.dtype)
-                    v_dev = D.mesh_h2d(self.mesh, v_pad, rep, self.dtype)
-                    s_dev = D.mesh_h2d(self.mesh, s_pad, rep, jnp.int32)
-                    if self.has_sketch:
-                        hhi_dev = D.mesh_h2d(self.mesh, h_pads[0], rep,
-                                             jnp.uint32)
-                        hlo_dev = D.mesh_h2d(self.mesh, h_pads[1], rep,
-                                             jnp.uint32)
-            with D.stage_trace("launch", timings):
-                if self.has_sketch:
-                    fn = D.mesh_tick_sketch_fn(
-                        self.mesh, params, mode, geometry,
-                        self.n_groups_list, not self._uniform)
-                    out = fn(*self._state, self._regs_state, v_dev,
-                             s_dev, hhi_dev, hlo_dev, q_dev,
-                             self._bounds, self._sketch0_cells(),
-                             self._sizes, self._inv_scale)
-                else:
-                    fn = D.mesh_tick_fn(self.mesh, params, mode, geometry,
-                                        self.n_groups_list,
-                                        not self._uniform)
-                    out = fn(*self._state, v_dev, s_dev,
-                             q_dev, self._bounds, self._sketch0_cells(),
-                             self._sizes, self._inv_scale)
-        group_regs = None
-        if self.has_sketch:
-            (mom_s, mom_l, totals, ns, regs, partials, rows,
-             group_regs) = out
-            self._regs_state = regs
-        else:
-            mom_s, mom_l, totals, ns, partials, rows = out
-        self._state = (mom_s, mom_l, totals, ns)
-        for st in self.stores:
-            st.n_sampled = st.n_sampled + quotas
-            if count_round:
-                st.rounds += 1
-        return self._install_stats(partials, rows, cfg,
-                                   defer=defer_stats, timings=timings,
-                                   group_regs=group_regs)
+    def _launch_tagged(self, cfg, values, seg, quotas, hash_limbs, timings):
+        """The sample streams upload replicated; ``seg`` holds MESH cell
+        ids (``key_seg``)."""
+        import jax.numpy as jnp
+
+        from . import distributed as D
+
+        _, vec, rep = self._specs
+        with D.stage_trace("h2d", timings):
+            with D.stage_trace("h2d.pane", timings):
+                # Pad/drop id: past every shard's window, so each shard
+                # retags it onto its local drop row.
+                v_pad, s_pad, h_pads = _tagged_panes(
+                    values, seg, self.n_cells_mesh, hash_limbs)
+                q_pad = np.zeros(self.n_shards * self.blocks_local,
+                                 dtype=np.float64)
+                q_pad[:self.n_blocks] = quotas
+                D.count("pane_slots", v_pad.size)
+            with D.stage_trace("h2d.put", timings):
+                q_dev = D.mesh_h2d(self.mesh, q_pad, vec, self.dtype)
+                v_dev = D.mesh_h2d(self.mesh, v_pad, rep, self.dtype)
+                s_dev = D.mesh_h2d(self.mesh, s_pad, rep, jnp.int32)
+                hashes = (None if h_pads is None else tuple(
+                    D.mesh_h2d(self.mesh, h, rep, jnp.uint32)
+                    for h in h_pads))
+        with D.stage_trace("launch", timings):
+            fn = D.mesh_tick_fn(self.mesh, *cfg, self.n_groups_list,
+                                not self._uniform)
+            return fn(*self._state, v_dev, s_dev, q_dev, self._bounds,
+                      self._sketch0_cells(), self._sizes, self._inv_scale,
+                      self._regs_state, hashes)
 
 
 def proportional_allocate(amounts: np.ndarray, budget: int) -> np.ndarray:

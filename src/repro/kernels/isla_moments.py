@@ -395,7 +395,8 @@ def isla_fused_sketch_pallas(values3d: jnp.ndarray, bounds: jnp.ndarray,
     """``isla_fused_pallas`` with the register pane riding the launch:
     Phase 1 moments, the HLL register merge, and the branchless Phase 2
     solve chained in ONE jit over the same donated accumulators — the
-    kernel-route twin of ``distributed.fused_tick_dense_sketch``.
+    kernel-route twin of ``distributed.fused_tick_dense`` with its
+    register plane (``regs``).
 
     ``prior`` (n_cells, 2, 4) and ``prior_regs`` (n_cells, 32, 128) are
     both consumed and replaced.  The hash panes carry the RAW measure

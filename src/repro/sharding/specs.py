@@ -232,13 +232,15 @@ ISLA_CELL_AXIS = "cells"
 
 def isla_cell_specs(mesh: Mesh) -> Dict[str, P]:
     """PartitionSpecs for the ISLA mesh tier (``core.moment_store.
-    MeshDeviceStack`` / ``core.distributed.mesh_tick_fn``), keyed by
-    operand family:
+    MeshDeviceStack`` / ``core.distributed.mesh_tick_fn``,
+    ``mesh_tick_dense_fn`` and ``mesh_solve_fn``), keyed by operand
+    family:
 
       cells      (N,)   per-cell vectors (ledger, sketch0, inv_scale,
                         quota rows) — sharded on the cell axis
       cell_rows  (N, k) per-cell matrices (moments, totals, per-cell
-                        cuts, dense block panes) — sharded on dim 0
+                        cuts, an optional HLL register plane, dense
+                        block panes) — sharded on dim 0
       replicated (...)  sample streams / tags / small anchor tables —
                         every shard holds a copy
       stat_rows  (G, 9) psum'd group-stat rows — replicated output

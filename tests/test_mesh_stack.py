@@ -248,7 +248,7 @@ def test_mesh_tick_collectives_are_stat_rows_only(rng):
                        np.zeros(msh.n_shards * msh.blocks_local),
                        P(ISLA_CELL_AXIS), msh.dtype),
             msh._bounds, msh._sketch0_cells(), msh._sizes,
-            msh._inv_scale)
+            msh._inv_scale, None, None)
     footprint = D.collective_footprint(fn.lower(*args).compile().as_text())
     assert footprint, "expected at least the stat-row psum"
     cap = G * 9  # one store, G group-stat rows of 9 columns

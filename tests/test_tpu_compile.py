@@ -5,9 +5,9 @@ backend, so it cannot see what Mosaic refuses: a block not tiled (8, 128),
 a scalar kept in vector memory, an op the TPU cannot legalize, a kernel
 that outgrows its scoped VMEM.  These tests hand the real compiler the
 production shapes: every ``*_pallas`` kernel at tm=512, the dense fused
-tick (and its sketch twin) at the serving tier's 1000 blocks x 16 groups,
-and the mesh tick on four described chips.  Nothing runs, so they say
-nothing about results or times.
+tick (with and without its register plane) at the serving tier's 1000
+blocks x 16 groups, and the mesh tick on four described chips.  Nothing
+runs, so they say nothing about results or times.
 
 The topology is described inside a fixture, never at import: only one
 process may load the TPU library, and every test worker imports this file.
@@ -37,7 +37,7 @@ DENSE_STATIC = dict(params=PARAMS, mode="calibrated", geometry=None,
                     valid_slots=(-1, -1, 0, 0), key_affine=None,
                     bound_slots=None)
 # The tick's one packed upload: the value pane, the shared predicate and
-# GROUP BY panes and the quota column (the sketch twin adds two hash panes).
+# GROUP BY panes and the quota column (a register plane adds two hash panes).
 PANES = D.PaneLayout(N_BLOCKS, QUOTA, n_valid=1, n_gid=1)
 SKETCH_PANES = PANES._replace(n_hash=2)
 
@@ -133,28 +133,27 @@ def _dense_args(spec, layout=PANES):
             spec((k * b,)), spec((c,)))
 
 
-def test_fused_tick_dense_compiles_at_deployment_shape(one_chip):
+@pytest.mark.parametrize("has_sketch", [False, True])
+def test_fused_tick_dense_compiles_at_deployment_shape(one_chip, has_sketch):
     """The served hot path at 1000 x 16, and its Phase 1 contraction
     keeps full fp32 precision (a default-precision f32 dot rounds its
-    operands to bf16 on a TPU)."""
-    args = _dense_args(lambda s, dt=jnp.float32: _spec(s, one_chip, dt))
-    text = D.fused_tick_dense.lower(*args, **DENSE_STATIC,
-                                    pane_layout=PANES).compile().as_text()
+    operands to bf16 on a TPU).  The register plane is optional state:
+    with it the launch carries a uint8 plane and its scatter-max, and
+    without it the program has neither."""
+    spec = (lambda s, dt=jnp.float32: _spec(s, one_chip, dt))
+    layout = SKETCH_PANES if has_sketch else PANES
+    regs = spec((N_CELLS, 4096), jnp.uint8) if has_sketch else None
+    text = D.fused_tick_dense.lower(
+        *_dense_args(spec, layout), regs=regs, **DENSE_STATIC,
+        pane_layout=layout).compile().as_text()
     contraction = [ln for ln in text.splitlines()
                    if 'op_name="jit(fused_tick_dense)/dot_general"' in ln
                    and ("convolution(" in ln or " dot(" in ln)]
     assert contraction
     assert all("operand_precision={highest,highest}" in ln
                for ln in contraction)
-
-
-def test_fused_tick_dense_sketch_compiles_at_deployment_shape(one_chip):
-    spec = (lambda s, dt=jnp.float32: _spec(s, one_chip, dt))
-    a = _dense_args(spec, SKETCH_PANES)
-    args = a[:4] + (spec((N_CELLS, 4096), jnp.uint8),) + a[4:]
-    compiled = D.fused_tick_dense_sketch.lower(
-        *args, **DENSE_STATIC, pane_layout=SKETCH_PANES).compile()
-    assert compiled.memory_analysis() is not None
+    assert ("u8[" in text) == has_sketch
+    assert ("scatter-max" in text) == has_sketch
 
 
 @pytest.mark.parametrize("has_sketch", [False, True])
@@ -197,7 +196,7 @@ def test_mesh_tick_dense_compiles_on_four_chips_with_one_psum(topo):
     cross-device collective is the psum of the O(groups) stat rows."""
     mesh, _, args = _mesh_dense_args(topo)
     fn = D.mesh_tick_dense_fn(*_mesh_static(mesh))
-    compiled = fn.lower(*args).compile()
+    compiled = fn.lower(*args, None, None, None).compile()
     stat_rows = sum(N_GROUPS) * 9
     assert D.collective_footprint(compiled.as_text()) == [
         ("all-reduce", stat_rows)]
@@ -209,10 +208,9 @@ def test_mesh_tick_dense_sketch_folds_registers_in_int32(topo):
     (four register rows per word), which is not a per-byte max."""
     mesh, row, a = _mesh_dense_args(topo)
     pane = (N_BLOCKS, QUOTA)
-    args = (a[:4] + (_spec((N_CELLS, 4096), row, jnp.uint8), a[4], a[5],
-                     _spec(pane, row, jnp.uint32),
-                     _spec(pane, row, jnp.uint32), a[6]) + a[7:])
-    fn = D.mesh_tick_dense_sketch_fn(*_mesh_static(mesh))
+    args = a + (None, _spec((N_CELLS, 4096), row, jnp.uint8),
+                (_spec(pane, row, jnp.uint32), _spec(pane, row, jnp.uint32)))
+    fn = D.mesh_tick_dense_fn(*_mesh_static(mesh))
     text = fn.lower(*args).compile().as_text()
     reduces = [ln for ln in text.splitlines() if " all-reduce(" in ln]
     assert reduces and not any(t in ln.split("all-reduce(")[0]
